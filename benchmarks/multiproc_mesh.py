@@ -33,12 +33,17 @@ ITERS = 5
 
 
 def run_sweep(widths=DEFAULT_WORKERS) -> dict:
+    import jax
+    import numpy as np
+
     import bench
 
-    # probe once for the whole sweep (workers are pinned to CPU × 1
-    # device by the supervisor regardless of what the root runs on)
-    bench._ensure_backend()
-    import numpy as np
+    # the supervisor pins every worker to one CPU device, and the sweep
+    # asserts their results byte-identical to the root's engine: a CPU
+    # harness end to end
+    if jax.default_backend() != "cpu":
+        raise SystemExit("multiproc_mesh is a CPU harness (workers are "
+                         "pinned to the CPU); set JAX_PLATFORMS=cpu")
 
     from filodb_tpu.coordinator.mesh_cluster import MeshClusterRuntime
     from filodb_tpu.parallel.mesh_engine import (

@@ -19,7 +19,7 @@ and frozen into the kernel, an outright correctness bug.
 A function counts as a kernel when decorated ``@jax.jit`` / ``@jit`` /
 ``@partial(jax.jit, ...)``, when passed to ``pl.pallas_call``, when
 wrapped in call form (``jit(fn)`` / ``jax.jit(fn)`` or
-``shard_map(fn, ...)`` / ``_shard_map(fn, ...)`` — the factory idiom
+``jax.shard_map(fn, ...)`` — the factory idiom
 ``parallel/dist_query.py`` builds its SPMD programs with), or when
 lexically nested inside a kernel.
 
@@ -93,7 +93,7 @@ def _pallas_kernel_names(tree: ast.Module) -> set[str]:
 
 def _wrapped_kernel_names(tree: ast.Module) -> set[str]:
     """Function names made kernels by call-form wrapping: the callee of
-    ``shard_map(f, ...)`` / ``_shard_map(f, ...)`` and call-form
+    ``shard_map(f, ...)`` / ``jax.shard_map(f, ...)`` and call-form
     ``jit(f)`` / ``jax.jit(f)`` (the ``parallel/dist_query.py`` factory
     idiom, which the decorator check cannot see)."""
     names: set[str] = set()
@@ -103,7 +103,7 @@ def _wrapped_kernel_names(tree: ast.Module) -> set[str]:
         fn = node.func
         fname = fn.attr if isinstance(fn, ast.Attribute) else (
             fn.id if isinstance(fn, ast.Name) else None)
-        if fname in ("shard_map", "_shard_map"):
+        if fname == "shard_map":
             cands = list(node.args[:1]) + [kw.value for kw in node.keywords
                                            if kw.arg == "f"]
         elif fname == "jit":

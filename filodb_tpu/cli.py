@@ -693,6 +693,10 @@ def main(argv=None):
     p.add_argument("hexkey", help="serialized part-key bytes, hex")
 
     args = ap.parse_args(argv)
+    if args.host is None:
+        # embedded mode may run device programs (promql, validate)
+        from filodb_tpu import startup
+        startup.configure_jax()
     return {"init": cmd_init, "list": cmd_list, "status": cmd_status,
             "lag": cmd_lag, "tiers": cmd_tiers, "meshstat": cmd_meshstat,
             "shardmap": cmd_shardmap, "replicacheck": cmd_replicacheck,

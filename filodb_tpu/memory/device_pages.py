@@ -201,8 +201,8 @@ def decode_f32_page_jax(bases, shifts, widths, words):
 # ---------------------------------------------------------------------------
 # pallas decode kernels
 #
-# Mosaic (real-TPU) lowering constraints shape the design (validated on a
-# live v5e, tools/tpu_pallas_check.py):
+# Mosaic (real-TPU) lowering constraints shape the design (both kernels are
+# compiled for a described v5e by tests/test_chip_compile.py):
 #   - rank-1 blocks and (1, N) tiles don't lower → grid steps cover ROWS=8
 #     blocks at a time with (8, 128)-tiled VMEM blocks (native sublane×lane
 #     tile for 32-bit types);

@@ -1,6 +1,7 @@
 """ctypes bindings to the C++ native runtime (``native/filodb_native.cpp``).
 
-Builds the shared library on demand (cached by source mtime) and exposes:
+Builds the shared library on demand (rebuilt whenever the hash of its sources
+differs from the one recorded beside the library) and exposes:
 - fast NibblePack pack/unpack, zigzag, XOR-double prep — byte-identical to
   the numpy reference implementations; used by the ingest/flush hot path.
 - the block arena (reference ``BlockManager`` semantics).
@@ -11,6 +12,8 @@ Falls back gracefully (``HAVE_NATIVE = False``) when no compiler is present.
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import logging
 import os
 import subprocess
@@ -23,21 +26,62 @@ log = logging.getLogger(__name__)
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
 _SO_PATH = os.path.join(_NATIVE_DIR, "libfilodb_native.so")
-_SRC_PATH = os.path.join(_NATIVE_DIR, "filodb_native.cpp")
+_SRC_PATHS = (os.path.join(_NATIVE_DIR, "filodb_native.cpp"),
+              os.path.join(_NATIVE_DIR, "Makefile"))
+# hash of the sources the library beside it was built from, written only
+# after a complete build
+_HASH_PATH = _SO_PATH + ".sha256"
 
 _lib = None
 _lock = threading.Lock()
 HAVE_NATIVE = False
 
 
-def _build() -> bool:
+def _source_hash() -> str:
+    h = hashlib.sha256()
+    for path in _SRC_PATHS:
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _built_hash() -> str | None:
     try:
-        subprocess.run(["make", "-C", _NATIVE_DIR, "-s"], check=True,
-                       capture_output=True, timeout=120)
+        with open(_HASH_PATH) as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
+def _ensure_built() -> bool:
+    """Make the library on disk the one the sources here produce. Staleness
+    is decided by content, not mtime: a copied tree (a checkout, an rsync,
+    the chip tool) keeps a git-ignored library whose mtime says nothing
+    about which source it came from. Builds are serialized across processes
+    by a file lock so that concurrent importers (pytest-xdist workers on a
+    fresh checkout) wait for one complete library instead of loading a
+    half-written one."""
+    want = _source_hash()
+
+    def current() -> bool:
+        return os.path.exists(_SO_PATH) and _built_hash() == want
+
+    if current():
         return True
-    except Exception as e:  # pragma: no cover - toolchain missing
-        log.warning("native build failed, using numpy codecs: %s", e)
-        return False
+    with open(_SO_PATH + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if current():
+            return True  # another process built it while we waited
+        try:
+            # -B: make itself goes by mtime
+            subprocess.run(["make", "-B", "-C", _NATIVE_DIR, "-s"],
+                           check=True, capture_output=True, timeout=120)
+        except (OSError, subprocess.SubprocessError) as e:
+            log.warning("native build failed, using numpy codecs: %s", e)
+            return False
+        with open(_HASH_PATH, "w") as f:
+            f.write(want + "\n")
+        return True
 
 
 def _load():
@@ -45,10 +89,8 @@ def _load():
     with _lock:
         if _lib is not None:
             return _lib
-        if (not os.path.exists(_SO_PATH)
-                or os.path.getmtime(_SO_PATH) < os.path.getmtime(_SRC_PATH)):
-            if not _build():
-                return None
+        if not _ensure_built():
+            return None
         try:
             lib = ctypes.CDLL(_SO_PATH)
         except OSError as e:  # pragma: no cover

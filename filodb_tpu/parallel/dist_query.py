@@ -28,15 +28,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # newer jax exports shard_map at top level (check_vma kwarg)
-    _shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental namespace, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
-
 from filodb_tpu.query.engine.kernels import fdtype
 
 
@@ -372,7 +363,7 @@ def make_distributed_range_agg(mesh: Mesh, fn: str, num_groups: int,
 
         in_specs, args = _mesh_call(ts, vals, valid, group_ids, steps,
                                     window, raw)
-        return _shard_map(
+        return jax.shard_map(
             kernel, mesh=mesh, in_specs=in_specs,
             out_specs=P("shard", None) if agg is None else P(None, None),
             check_vma=False,
@@ -428,7 +419,7 @@ def make_mesh_prepare(mesh: Mesh, kind: str):
 
         out_specs = P("shard", "time") if kind == "counter" \
             else (P("shard", "time"),) * 3
-        return _shard_map(
+        return jax.shard_map(
             kernel, mesh=mesh,
             in_specs=(P("shard", "time"), P("shard", "time")),
             out_specs=out_specs, check_vma=False,
@@ -452,7 +443,7 @@ def make_mesh_bounds(mesh: Mesh):
             lo, hi = _window_bounds(ts_l, steps_r, window_r)
             return lo.astype(jnp.int32), hi.astype(jnp.int32)
 
-        return _shard_map(
+        return jax.shard_map(
             kernel, mesh=mesh,
             in_specs=(P("shard", "time"), P(None), P()),
             out_specs=(P("shard", "time"), P("shard", "time")),
@@ -501,7 +492,7 @@ def make_mesh_eval_delta(mesh: Mesh, fn: str, counter: bool | None = None):
             if extra is not None:
                 in_specs += (P("shard", "time"),)
                 args += (extra,)
-        return _shard_map(
+        return jax.shard_map(
             kernel, mesh=mesh, in_specs=in_specs,
             out_specs=P("shard", None), check_vma=False,
         )(*args)
@@ -529,7 +520,7 @@ def make_mesh_eval_simple(mesh: Mesh, fn: str):
             return combine(gathered)
 
         in_specs = (P("shard", "time"),) * 8 + (P(None), P())
-        return _shard_map(
+        return jax.shard_map(
             kernel, mesh=mesh, in_specs=in_specs,
             out_specs=P("shard", None), check_vma=False,
         )(ts, vals, valid, csum, cnt, csum2, lo, hi, steps, window)
@@ -547,7 +538,7 @@ def make_mesh_group_reduce(mesh: Mesh, num_groups: int, agg: str):
         def kernel(res_l, gid_l):
             return _group_reduce(res_l, gid_l, num_groups, agg)
 
-        return _shard_map(
+        return jax.shard_map(
             kernel, mesh=mesh,
             in_specs=(P("shard", None), P("shard")),
             out_specs=P(None, None), check_vma=False,
@@ -584,7 +575,7 @@ def make_distributed_sum_rate(mesh: Mesh, num_groups: int):
 
         in_specs, args = _mesh_call(ts, vals, valid, group_ids, steps,
                                     window, raw)
-        return _shard_map(
+        return jax.shard_map(
             kernel, mesh=mesh, in_specs=in_specs,
             out_specs=P(None, None),
             check_vma=False,
@@ -745,7 +736,7 @@ def make_distributed_sum_rate_ring(mesh: Mesh, num_groups: int):
 
         in_specs, args = _mesh_call(ts, vals, valid, group_ids, steps,
                                     window, raw)
-        return _shard_map(
+        return jax.shard_map(
             kernel, mesh=mesh, in_specs=in_specs,
             out_specs=P(None, None),
             check_vma=False,

@@ -40,8 +40,12 @@ def fdtype():
 
 
 def _valid_mask(ts, counts):
+    """Prefix validity mask [P, S], materialized: left fusible, the TPU
+    compiler folds the iota-vs-counts compare into every scan that reads it
+    (cumsum, cummax, cummin) and takes ~25 s for each one at [8192, 2048]
+    on a v5e, against 2-4 s for the same scan over an opaque mask."""
     S = ts.shape[1]
-    return jnp.arange(S)[None, :] < counts[:, None]
+    return lax.optimization_barrier(jnp.arange(S)[None, :] < counts[:, None])
 
 
 def _eprefix(x):
@@ -154,7 +158,7 @@ def range_eval(fn: str, ts, vals, counts, steps, window, extra=0.0,
     delta to the f32 cast).
     """
     return _range_impl(fn, ts, vals, _valid_mask(ts, counts), steps, window,
-                       extra, counter, pre_corrected, raw)
+                       extra, counter, pre_corrected, raw, counts=counts)
 
 
 @partial(jax.jit, static_argnames=("fn", "counter", "pre_corrected"))
@@ -169,7 +173,10 @@ def range_eval_masked(fn: str, ts, vals, valid, steps, window, extra=0.0,
 
 
 def _range_impl(fn: str, ts, vals, valid, steps, window, extra, counter,
-                pre_corrected: bool = False, raw=None):
+                pre_corrected: bool = False, raw=None, counts=None):
+    """``counts`` [P] is given when ``valid`` is the prefix mask
+    ``arange(S) < counts`` (no interior gaps): the prev/next-valid index
+    maps then have closed forms and need no scan."""
     dt = fdtype()
     vals = vals.astype(dt)
     v = jnp.where(valid, vals, 0.0)
@@ -188,8 +195,13 @@ def _range_impl(fn: str, ts, vals, valid, steps, window, extra, counter,
               "last_over_time", "last_sample", "timestamp", "changes",
               "resets", "irate", "idelta", "rate", "increase", "delta"):
         sidx = jnp.arange(S, dtype=jnp.int32)[None, :]
-        pv = lax.cummax(jnp.where(valid, sidx, -1), axis=1)
-        nv = lax.cummin(jnp.where(valid, sidx, S), axis=1, reverse=True)
+        if counts is None:
+            pv = lax.cummax(jnp.where(valid, sidx, -1), axis=1)
+            nv = lax.cummin(jnp.where(valid, sidx, S), axis=1, reverse=True)
+        else:
+            cnt = counts.astype(jnp.int32)[:, None]
+            pv = jnp.minimum(sidx, cnt - 1)
+            nv = jnp.where(sidx < cnt, sidx, S)
         # first/last VALID sample index within [lo, hi)
         first_idx = jnp.clip(_gather(nv, jnp.minimum(lo, S - 1)), 0, S - 1)
         last_idx = jnp.clip(_gather(pv, jnp.maximum(hi - 1, 0)), 0, S - 1)

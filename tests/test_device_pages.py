@@ -100,36 +100,3 @@ class TestF32Pallas:
         b = np.asarray(decode_f32_page_pallas(bases, shifts, widths, words,
                                               interpret=True))
         np.testing.assert_array_equal(a, b)
-
-
-class TestPallasWindowedSum:
-    def test_matches_xla_kernel(self):
-        import jax.numpy as jnp
-        from filodb_tpu.query.engine import kernels
-        from filodb_tpu.query.engine.batch import TS_PAD
-        from filodb_tpu.query.engine.pallas_kernels import windowed_sum_pallas
-
-        rng = np.random.default_rng(7)
-        P, S = 4, 256
-        ts = np.full((P, S), TS_PAD, np.int32)
-        vals = np.zeros((P, S), np.float32)
-        counts = np.zeros(P, np.int32)
-        for p in range(P):
-            n = int(rng.integers(S // 2, S))
-            ts[p, :n] = np.cumsum(rng.integers(5_000, 15_000, n))
-            vals[p, :n] = rng.normal(50, 10, n)
-            counts[p] = n
-        steps = np.arange(300_000, 1_200_000, 90_000, dtype=np.int32)
-        window = np.int32(300_000)
-        ref = np.asarray(kernels.range_eval(
-            "sum_over_time", jnp.asarray(ts),
-            jnp.asarray(vals.astype(np.float64)), jnp.asarray(counts),
-            jnp.asarray(steps), jnp.asarray(window)))
-        out = np.asarray(windowed_sum_pallas(
-            jnp.asarray(ts), jnp.asarray(vals), jnp.asarray(steps),
-            jnp.asarray(window), interpret=True))
-        # pallas returns 0.0 (not NaN) for empty windows; compare where ref
-        # has samples, and zeros elsewhere
-        has = ~np.isnan(ref)
-        np.testing.assert_allclose(out[has], ref[has], rtol=1e-5)
-        assert (out[~has] == 0.0).all()

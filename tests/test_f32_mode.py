@@ -159,7 +159,7 @@ def _run_big_counter(mode):
 
 
 def test_f32_counter_precision_rebased():
-    """VERDICT r4 acceptance: counters >= 1e9 with per-window deltas ~10.
+    """Counters >= 1e9 with per-window deltas ~10.
     The f32 device path (exec kernels AND the mesh engine) must match the
     f64 host path to rtol 1e-5 — without per-series f64 rebasing the f32
     cast returns garbage (window deltas collapse to 0 or +/-256)."""

@@ -590,19 +590,18 @@ class TestHotPath:
 
     def test_shard_map_wrapped_kernel_in_parallel(self, tmp_path):
         """The dist_query factory idiom: an undecorated closure becomes a
-        kernel by being the first argument of shard_map/_shard_map — and
+        kernel by being the first argument of jax.shard_map — and
         parallel/ is in scope alongside query/engine/."""
         out = run_pass(tmp_path, hotpath, {
             "filodb_tpu/parallel/d.py": """
             import jax
-            from jax.experimental.shard_map import shard_map
 
             def make_step(mesh):
                 def step(ts, vals):
                     def kernel(ts_l, vals_l):
                         return vals_l.sum() + float(ts_l.shape)
-                    return _shard_map(kernel, mesh=mesh, in_specs=(),
-                                      out_specs=())(ts, vals)
+                    return jax.shard_map(kernel, mesh=mesh, in_specs=(),
+                                         out_specs=())(ts, vals)
                 return jax.jit(step)
             """})
         assert codes(out) == ["HP301"]

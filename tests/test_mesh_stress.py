@@ -1,4 +1,4 @@
-"""Mesh engine under shard imbalance and ingest churn (VERDICT r3 #9).
+"""Mesh engine under shard imbalance and ingest churn.
 
 The round-3 dryrun only exercised 30 balanced, static series; these tests
 stress the two production realities it skipped:

@@ -5,6 +5,8 @@ NibblePack.scala, BlockManager.scala); these tests pin byte-identical output
 against the pure-python reference implementation.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -101,3 +103,43 @@ class TestArena:
             arena.alloc_block(owner=3)
         assert arena.stats["allocated_blocks"] == 6
         arena.close()
+
+
+class TestBuiltFromTheSourcesOnDisk:
+    """The library is rebuilt when its sources' content changes, whatever
+    the mtimes say: a copied tree keeps a git-ignored ``.so`` whose mtime
+    means nothing."""
+
+    @pytest.fixture
+    def sandbox(self, tmp_path, monkeypatch):
+        import shutil
+        for name in ("filodb_native.cpp", "Makefile"):
+            shutil.copy(os.path.join(native._NATIVE_DIR, name), tmp_path)
+        so = str(tmp_path / "libfilodb_native.so")
+        monkeypatch.setattr(native, "_NATIVE_DIR", str(tmp_path))
+        monkeypatch.setattr(native, "_SO_PATH", so)
+        monkeypatch.setattr(native, "_HASH_PATH", so + ".sha256")
+        monkeypatch.setattr(native, "_SRC_PATHS", (
+            str(tmp_path / "filodb_native.cpp"), str(tmp_path / "Makefile")))
+        return tmp_path
+
+    def test_content_decides_not_mtime(self, sandbox):
+        so = sandbox / "libfilodb_native.so"
+        assert native._ensure_built() and so.exists()
+        assert native._built_hash() == native._source_hash()
+        built = so.stat().st_mtime_ns
+        # a source that merely LOOKS newer leaves the library alone
+        os.utime(sandbox / "filodb_native.cpp")
+        assert native._ensure_built() and so.stat().st_mtime_ns == built
+        # a library that looks newer than a CHANGED source is still rebuilt
+        with open(sandbox / "filodb_native.cpp", "a") as f:
+            f.write("\n// changed\n")
+        os.utime(so, (2**31, 2**31))
+        assert native._ensure_built()
+        assert so.stat().st_mtime_ns != 2**31 * 10**9
+        assert native._built_hash() == native._source_hash()
+
+    def test_failed_build_reports_false(self, sandbox):
+        (sandbox / "filodb_native.cpp").write_text("this is not C++")
+        assert native._ensure_built() is False
+        assert native._built_hash() is None

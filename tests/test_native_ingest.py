@@ -100,7 +100,7 @@ class TestNativeParity:
         hkeys = histogram_series(2)
         stream = list(to_bytes_stream(histogram_stream(hkeys, 30, batch=1)))
         _, shard = build(True, stream)
-        # hist containers take the native lane (VERDICT r3 #3a): partitions
+        # hist containers take the native lane: partitions
         # are native-backed and read back full histogram columns
         assert shard.stats.rows_ingested.value == 60
         assert type(shard.partitions[0]).__name__ == "NativeBackedPartition"

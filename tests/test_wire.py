@@ -3,7 +3,7 @@
 Reference counterpart: the Kryo serializer registration
 (``client/Serializer.scala:23-64``) — a closed class registry. Unlike Kryo
 -over-Akka, the transport also enforces a shared-secret handshake and frame
-caps (VERDICT r1 hardening items).
+caps.
 """
 
 import struct
