@@ -333,7 +333,8 @@ def cmd_slowlog(args):
         if e.get("query"):
             head += f"  {e['query']}"
         print(head)
-        for k in ("dataset", "group", "phase", "op"):
+        for k in ("dataset", "group", "phase", "op", "members",
+                  "t0_unix_ns"):
             if e.get(k):
                 print(f"    {k}={e[k]}")
         stats = e.get("stats") or {}
@@ -343,8 +344,11 @@ def cmd_slowlog(args):
         for s in e.get("spans", []):
             tags = " ".join(f"{k}={v}"
                             for k, v in sorted((s.get("tags") or {}).items()))
+            # +start: offset from the entry's t0 (absent from a peer that
+            # runs an older release)
+            at = f"+{s['start_ms']:.3f} " if "start_ms" in s else ""
             print(f"    {'  ' * s.get('depth', 0)}"
-                  f"{s['name']} {s.get('duration_ms', 0):.3f}ms"
+                  f"{s['name']} {at}{s.get('duration_ms', 0):.3f}ms"
                   + (f" [{tags}]" if tags else ""))
 
 

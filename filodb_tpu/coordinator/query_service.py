@@ -29,6 +29,13 @@ from filodb_tpu.utils.governor import (
 from filodb_tpu.utils.metrics import Histogram, get_counter
 from filodb_tpu.utils.resilience import Deadline
 from filodb_tpu.utils.resilience import config as resilience_config
+from filodb_tpu.utils.tracing import (
+    config as tracing_config,
+    record_slow,
+    span,
+    traced_batch,
+    traced_query,
+)
 
 query_latency = Histogram("query_latency_seconds")
 partial_results = get_counter("filodb_partial_results")
@@ -156,7 +163,6 @@ class QueryService:
     def query_range(self, promql: str, start_sec: int, step_sec: int,
                     end_sec: int, qcontext: QueryContext | None = None
                     ) -> QueryResult:
-        from filodb_tpu.utils.tracing import span, traced_query
         qcontext = qcontext or QueryContext()
         params = TimeStepParams(start_sec, step_sec, end_sec)
         # traced_query: joins an active trace (debug endpoint, rules tick)
@@ -190,9 +196,14 @@ class QueryService:
 
         With ``return_errors=True`` a failing query yields its exception at
         its own position instead of poisoning the whole batch — one bad
-        query costs only itself, not an O(n) sequential re-run."""
-        import numpy as np
+        query costs only itself, not an O(n) sequential re-run.
 
+        A batch of more than one member is head-sampled once and, when
+        sampled, leaves one ``query-batch`` trace (``tracing.traced_batch``):
+        ``parse``, the members' own ``cache`` spans, ``mesh-execute`` with
+        the engine's phase spans, the fall-through members' own
+        ``plan-materialize``/``exec-dispatch``, ``batch-fetch``,
+        ``finish``."""
         t0 = time.perf_counter()
         n = len(queries)
         if n == 1:
@@ -208,17 +219,26 @@ class QueryService:
                 if not return_errors:
                     raise
                 return [e]
+        with traced_batch(members=n, dataset=self.dataset):
+            return self._run_batch(queries, return_errors, t0)
+
+    def _run_batch(self, queries, return_errors: bool, t0: float) -> list:
+        """``query_range_many`` for more than one member."""
+        import numpy as np
+
+        n = len(queries)
         plans: list = [None] * n
         outcomes: list = [None] * n  # QueryResult | Exception per query
-        for i, q in enumerate(queries):
-            promql, start_sec, step_sec, end_sec = q
-            params = TimeStepParams(start_sec, step_sec, end_sec)
-            try:
-                plans[i] = self._parse_cached(promql, params)
-            except Exception as e:  # noqa: BLE001
-                if not return_errors:
-                    raise
-                outcomes[i] = e
+        with span("parse", members=n):
+            for i, q in enumerate(queries):
+                promql, start_sec, step_sec, end_sec = q
+                params = TimeStepParams(start_sec, step_sec, end_sec)
+                try:
+                    plans[i] = self._parse_cached(promql, params)
+                except Exception as e:  # noqa: BLE001
+                    if not return_errors:
+                        raise
+                    outcomes[i] = e
 
         if self.result_cache is not None:
             for i, plan in enumerate(plans):
@@ -252,7 +272,8 @@ class QueryService:
             # The whole batch takes ONE admission slot: it runs as one
             # device program, and per-item gating would stall the batcher.
             def run_on_mesh(idxs):
-                with governor().admit(cost=EXPENSIVE):
+                with span("mesh-execute", members=len(idxs)), \
+                        governor().admit(cost=EXPENSIVE):
                     return self.mesh_engine.execute_many(
                         [plans[i] for i in idxs], self.memstore,
                         self.dataset, [stats_list[i] for i in idxs])
@@ -301,58 +322,60 @@ class QueryService:
         # into one device array per shape and fetch each stack once: one
         # stacked transfer instead of a blocking fetch per query.
         import jax.numpy as jnp
-        by_shape: dict[tuple, list[int]] = {}
-        for i in pending:
-            r = outcomes[i]
-            if isinstance(r, Exception):
-                continue
-            v = r.result.values
-            if not isinstance(v, np.ndarray):
-                by_shape.setdefault((v.shape, str(v.dtype)), []).append(i)
+
         from filodb_tpu.query.exec.plan import ExecPlan
-        for idxs in by_shape.values():
-            try:
-                stacked = np.asarray(jnp.stack([outcomes[i].result.values
-                                                for i in idxs]))
-            except Exception as e:  # noqa: BLE001
-                if not return_errors:
-                    raise
-                for i in idxs:
-                    outcomes[i] = e
-                continue
-            for j, i in enumerate(idxs):
-                outcomes[i].result.values = stacked[j]
-                deferred.add(i)
+        with span("batch-fetch"):
+            by_shape: dict[tuple, list[int]] = {}
+            for i in pending:
+                r = outcomes[i]
+                if isinstance(r, Exception):
+                    continue
+                v = r.result.values
+                if not isinstance(v, np.ndarray):
+                    by_shape.setdefault((v.shape, str(v.dtype)),
+                                        []).append(i)
+            for idxs in by_shape.values():
+                try:
+                    stacked = np.asarray(jnp.stack([outcomes[i].result.values
+                                                    for i in idxs]))
+                except Exception as e:  # noqa: BLE001
+                    if not return_errors:
+                        raise
+                    for i in idxs:
+                        outcomes[i] = e
+                    continue
+                for j, i in enumerate(idxs):
+                    outcomes[i].result.values = stacked[j]
+                    deferred.add(i)
         # limits + stats AFTER materialization, so deferred compaction has
         # dropped empty series first (enforcing on the pre-compaction count
         # rejected queries the sequential path accepted) — uniformly for
         # mesh AND exec-path results whose fetch was deferred to this batch
         wall = time.perf_counter() - t0
-        for i in sorted(deferred):
-            try:
-                data = outcomes[i].result.materialize()
-                qcontext = QueryContext()
-                ExecPlan._enforce_limits(data, qcontext)
-            except Exception as e:  # noqa: BLE001
-                if not return_errors:
-                    raise
-                outcomes[i] = e
-                continue
-            outcomes[i].stats.result_series = data.num_series
-            # batched execution: the whole pass's wall time is every
-            # member's latency (they completed together)
-            outcomes[i].stats.wall_time_s = wall
-            if not outcomes[i].query_id:
-                outcomes[i].query_id = qcontext.query_id
+        with span("finish", members=len(deferred)):
+            for i in sorted(deferred):
+                try:
+                    data = outcomes[i].result.materialize()
+                    qcontext = QueryContext()
+                    ExecPlan._enforce_limits(data, qcontext)
+                except Exception as e:  # noqa: BLE001
+                    if not return_errors:
+                        raise
+                    outcomes[i] = e
+                    continue
+                outcomes[i].stats.result_series = data.num_series
+                # batched execution: the whole pass's wall time is every
+                # member's latency (they completed together)
+                outcomes[i].stats.wall_time_s = wall
+                if not outcomes[i].query_id:
+                    outcomes[i].query_id = qcontext.query_id
         # tail capture for the batched path: members of a slow batch land in
-        # the flight recorder with stats (batched queries are not span-traced
-        # — the whole batch runs as one device program)
-        from filodb_tpu.utils.tracing import config as tracing_config
+        # the flight recorder with stats (the spans are the batch's, in its
+        # one query-batch entry — the whole batch runs as one device program)
         thr = tracing_config().slow_query_threshold_ms
         if deferred and thr > 0 and wall * 1000.0 > thr:
             import dataclasses as _dc
 
-            from filodb_tpu.utils.tracing import record_slow
             for i in sorted(deferred):
                 r = outcomes[i]
                 if isinstance(r, QueryResult):
@@ -378,7 +401,6 @@ class QueryService:
 
     def query_instant(self, promql: str, time_sec: int,
                       qcontext: QueryContext | None = None) -> QueryResult:
-        from filodb_tpu.utils.tracing import traced_query
         qcontext = qcontext or QueryContext()
         params = TimeStepParams(time_sec, 0, time_sec)
         plan = parse_query(promql, params, self.lookback_ms)
@@ -456,7 +478,6 @@ class QueryService:
                 # raises QueryRejected out of the scope (PR 1/4: overload
                 # propagates, unavailability degrades).
                 from filodb_tpu.query.model import QueryStats
-                from filodb_tpu.utils.tracing import span
                 stats = QueryStats()
                 stats.admission_wait_s += admission_wait_s
                 with query_latency.time(), span("mesh-proc-execute"):
@@ -470,7 +491,6 @@ class QueryService:
                     and self._planner_mem_only(plan) \
                     and self.mesh_engine.supports(plan):
                 from filodb_tpu.query.model import QueryStats
-                from filodb_tpu.utils.tracing import span
                 stats = QueryStats()
                 stats.admission_wait_s += admission_wait_s
                 with query_latency.time(), span("mesh-execute"):
@@ -485,7 +505,6 @@ class QueryService:
                     return self._finish_device_result(data, stats,
                                                       qcontext, pp, cost,
                                                       t0)
-            from filodb_tpu.utils.tracing import span
             with span("plan-materialize"):
                 exec_plan = self.planner.materialize(plan, qcontext)
             ctx = ExecContext(self.memstore, self.dataset, qcontext,
@@ -524,25 +543,28 @@ class QueryService:
         mesh and multi-process mesh): materialize first so deferred
         compaction applies, then the same resource guards as the exec
         path (real counts), then settle the adaptive cost model."""
-        data.materialize()
         from filodb_tpu.query.exec.plan import (
             ExecPlan,
             apply_result_budget,
         )
-        ExecPlan._enforce_limits(data, qcontext)
-        # result-bytes budget on the materialized matrix (the mesh has no
-        # incremental scan hooks, so the boundary check is where it
-        # degrades gracefully)
-        shim = _BudgetCtx(pp.budget)
-        data = apply_result_budget(data, shim)
-        stats.wall_time_s = time.perf_counter() - t0
-        stats.result_series = data.num_series
-        from filodb_tpu.coordinator import adaptive_planner
-        adaptive_planner.settle_query(
-            self.dataset, qcontext, stats.wall_time_s, cost)
-        return self._attach_recovery_warnings(
-            QueryResult(data, stats, qcontext.query_id,
-                        partial=shim.partial, warnings=shim.warnings))
+        with span("finish") as sp:
+            data.materialize()
+            ExecPlan._enforce_limits(data, qcontext)
+            # result-bytes budget on the materialized matrix (the mesh has
+            # no incremental scan hooks, so the boundary check is where it
+            # degrades gracefully)
+            shim = _BudgetCtx(pp.budget)
+            data = apply_result_budget(data, shim)
+            stats.wall_time_s = time.perf_counter() - t0
+            stats.result_series = data.num_series
+            from filodb_tpu.coordinator import adaptive_planner
+            adaptive_planner.settle_query(
+                self.dataset, qcontext, stats.wall_time_s, cost)
+            if sp is not None:
+                sp.tags["series"] = stats.result_series
+            return self._attach_recovery_warnings(
+                QueryResult(data, stats, qcontext.query_id,
+                            partial=shim.partial, warnings=shim.warnings))
 
     def _recovery_warnings(self) -> list[str]:
         """One warning per queryable-but-catching-up shard (recovery replay,

@@ -31,6 +31,7 @@ from filodb_tpu.http.server import (
     JSON_CT,
     HttpDispatcher,
     ResponseCache,
+    render_seconds,
     response_cache_key,
     retry_after_headers,
     service_version,
@@ -385,9 +386,10 @@ class FastHttpServer:
 
     @staticmethod
     def _render(req: _HotReq, result) -> bytes:
-        if req.kind == "range":
-            return promjson.matrix_json_str(result).encode()
-        return promjson.vector_json_str(result).encode()
+        with render_seconds.time():
+            if req.kind == "range":
+                return promjson.matrix_json_str(result).encode()
+            return promjson.vector_json_str(result).encode()
 
     def _run_single(self, req: _HotReq) -> tuple[int, dict, bytes]:
         ct = {"Content-Type": JSON_CT}

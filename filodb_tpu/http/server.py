@@ -38,12 +38,18 @@ from filodb_tpu.promql.parser import ParseError, TimeStepParams, parse_query
 from filodb_tpu.query import federation as _federation  # noqa: F401
 from filodb_tpu.query.model import QueryLimitExceeded
 from filodb_tpu.utils.governor import QueryRejected
-from filodb_tpu.utils.metrics import render_prometheus
+from filodb_tpu.utils.metrics import Histogram, render_prometheus
 from filodb_tpu.utils.resilience import DeadlineExceeded
 
 log = logging.getLogger(__name__)
 
 JSON_CT = "application/json"
+
+# rendering a query result into Prom JSON happens after the query's trace
+# has closed, so it has no span; both fronts observe it here instead
+render_seconds = Histogram(
+    "filodb_http_render_seconds",
+    help="query result to Prom JSON text, a hot query request")
 
 
 def retry_after_headers(after_s: float | None = None) -> dict:
@@ -432,9 +438,10 @@ class HttpDispatcher:
                 if body is not None:
                     return 200, {"Content-Type": JSON_CT}, body
         r = self.app.batched(svc).query_range(*params)
-        rendered = promjson.matrix_json_str(r, full_stats=full_stats) \
-            if kind == "range" \
-            else promjson.vector_json_str(r, with_stats=full_stats)
+        with render_seconds.time():
+            rendered = promjson.matrix_json_str(r, full_stats=full_stats) \
+                if kind == "range" \
+                else promjson.vector_json_str(r, with_stats=full_stats)
         out = self._json(200, rendered)
         if cache is not None:
             cache.put(key, version, out[2])

@@ -30,7 +30,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from filodb_tpu.query.engine.kernels import fdtype
 
+# Stable ``jax.named_scope`` names on the bodies of the device programs, so
+# that a profile names an operation by what it does and not by the
+# compiler's ``while.13``: ``prepare/correct``, ``prepare/prefix``,
+# ``bounds/search``, ``eval/<fn>``, ``reduce/<agg>``. Metadata only. The
+# fused programs evaluate bounds and correction inside ``eval/<fn>``, so
+# there the names nest (``eval/max_over_time/bounds/search``).
 
+
+@jax.named_scope("bounds/search")
 def _window_bounds(ts, steps, window):
     def bounds(tsp):
         hi = jnp.searchsorted(tsp, steps, side="right")
@@ -40,6 +48,7 @@ def _window_bounds(ts, steps, window):
     return jax.vmap(bounds)(ts)
 
 
+@jax.named_scope("prepare/correct")
 def _counter_correct(v, valid):
     """Block-local counter-reset correction (monotonized values): the
     cumulative sum of every dropped previous value is added back, exactly
@@ -178,6 +187,7 @@ def _combine_time_partials(parts, steps, window, mode: str = "rate",
     return jnp.where(n_tot >= 2, out, jnp.nan)
 
 
+@jax.named_scope("prepare/prefix")
 def _simple_prefixes(vals, counts_mask):
     """Exclusive prefix sums (value, count, value²) [P_l, S_l+1] — the
     per-batch state that makes every window sum an O(1) pair of gathers."""
@@ -335,6 +345,7 @@ def make_distributed_range_agg(mesh: Mesh, fn: str, num_groups: int,
     per-series [P, K] matrix (raw selectors / un-aggregated range functions),
     sharded over the shard axis."""
 
+    @jax.named_scope(f"eval/{fn}")
     def per_series(ts_l, vals_l, valid_l, steps_r, window_r, raw_l=None):
         if fn in COUNTER_FNS:
             mode, counter = COUNTER_FNS[fn]
@@ -359,7 +370,8 @@ def make_distributed_range_agg(mesh: Mesh, fn: str, num_groups: int,
                              rest[0] if rest else None)
             if agg is None:
                 return res
-            return _group_reduce(res, gid_l, num_groups, agg)
+            with jax.named_scope(f"reduce/{agg}"):
+                return _group_reduce(res, gid_l, num_groups, agg)
 
         in_specs, args = _mesh_call(ts, vals, valid, group_ids, steps,
                                     window, raw)
@@ -479,12 +491,13 @@ def make_mesh_eval_delta(mesh: Mesh, fn: str, counter: bool | None = None):
                    window_r, *rest):
             cv_l = rest[0] if cv is not None else None
             raw_l = rest[-1] if raw is not None else None
-            parts = _rate_partials_from_bounds(ts_l, vals_l, valid_l,
-                                               lo_l, hi_l, cv=cv_l,
-                                               raw=raw_l)
-            gathered = lax.all_gather(parts, "time")  # [dt, P_l, K, 7]
-            return _combine_time_partials(gathered, steps_r, window_r,
-                                          mode=mode, counter=counter)
+            with jax.named_scope(f"eval/{fn}"):
+                parts = _rate_partials_from_bounds(ts_l, vals_l, valid_l,
+                                                   lo_l, hi_l, cv=cv_l,
+                                                   raw=raw_l)
+                gathered = lax.all_gather(parts, "time")  # [dt, P_l, K, 7]
+                return _combine_time_partials(gathered, steps_r, window_r,
+                                              mode=mode, counter=counter)
 
         in_specs = (P("shard", "time"),) * 5 + (P(None), P())
         args = (ts, vals, valid, lo, hi, steps, window)
@@ -513,11 +526,12 @@ def make_mesh_eval_simple(mesh: Mesh, fn: str):
     def ev(ts, vals, valid, csum, cnt, csum2, lo, hi, steps, window):
         def kernel(ts_l, vals_l, valid_l, cs_l, cn_l, cs2_l, lo_l, hi_l,
                    steps_r, window_r):
-            parts = _simple_partials_from_bounds(
-                ts_l, vals_l, valid_l, cs_l, cn_l, cs2_l, lo_l, hi_l,
-                with_minmax=False)
-            gathered = lax.all_gather(parts, "time")  # [dt, P_l, K, 7]
-            return combine(gathered)
+            with jax.named_scope(f"eval/{fn}"):
+                parts = _simple_partials_from_bounds(
+                    ts_l, vals_l, valid_l, cs_l, cn_l, cs2_l, lo_l, hi_l,
+                    with_minmax=False)
+                gathered = lax.all_gather(parts, "time")  # [dt, P_l, K, 7]
+                return combine(gathered)
 
         in_specs = (P("shard", "time"),) * 8 + (P(None), P())
         return jax.shard_map(
@@ -536,7 +550,8 @@ def make_mesh_group_reduce(mesh: Mesh, num_groups: int, agg: str):
 
     def step(series_vals, group_ids):
         def kernel(res_l, gid_l):
-            return _group_reduce(res_l, gid_l, num_groups, agg)
+            with jax.named_scope(f"reduce/{agg}"):
+                return _group_reduce(res_l, gid_l, num_groups, agg)
 
         return jax.shard_map(
             kernel, mesh=mesh,

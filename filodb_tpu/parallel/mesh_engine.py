@@ -48,6 +48,7 @@ from filodb_tpu.parallel.dist_query import (
 from filodb_tpu.query import logical as lp
 from filodb_tpu.query.model import QueryStats, RangeVectorKey, StepMatrix
 from filodb_tpu.utils.metrics import GaugeFn, get_counter
+from filodb_tpu.utils.tracing import span, tag
 
 log = logging.getLogger(__name__)
 
@@ -400,9 +401,15 @@ class MeshQueryEngine:
         step grids may differ) in ONE mesh program. Returns one StepMatrix
         (or None) per entry. ``stats`` is one QueryStats (single query) or a
         list aligned with ``lows`` — every query in the group scanned the
-        whole shared batch, so each gets the full scan counts."""
+        whole shared batch, so each gets the full scan counts.
+
+        Phase spans, in order, tiling the caller's ``mesh-execute``:
+        ``mesh-lookup``, ``decode``, ``mesh-group``, ``mesh-pad``,
+        ``mesh-place`` (a batch-cache hit opens none of these five),
+        ``mesh-dispatch``, ``mesh-fetch``, ``mesh-assemble``."""
         stats_objs = stats if isinstance(stats, list) \
             else ([stats] if stats is not None else [])
+        from filodb_tpu.core.memstore.odp import page_partitions
         from filodb_tpu.parallel.dist_query import (
             make_distributed_range_agg,
             make_distributed_sum_rate_ring,
@@ -471,35 +478,44 @@ class MeshQueryEngine:
             placed = None
             parts = []
             extra_by_obj: dict[int, list] = {}
-            for shard in shards:
-                sparts = []
-                for pid in shard.lookup_partitions(list(low0.filters),
-                                                   chunk_start, chunk_end):
-                    p = shard.partition(pid)
-                    if p is not None:
-                        sparts.append(p)
-                # on-demand paging: cold chunks (flushed + evicted-from-RAM,
-                # or pre-restart data recovered only to the column store) are
-                # merged exactly like the exec path does (plan.py) — keyed by
-                # object identity because part_ids repeat across shards
-                if sparts and shard.config.demand_paging_enabled:
-                    from filodb_tpu.core.memstore.odp import page_partitions
-                    extra = page_partitions(shard, sparts, chunk_start,
-                                            chunk_end, shard.odp_cache)
-                    if extra:
-                        for p in sparts:
-                            ec = extra.get(p.part_id)
-                            if ec:
-                                extra_by_obj[id(p)] = ec
-                parts.extend(sparts)
+            with span("mesh-lookup", shards=len(shards)) as sp:
+                for shard in shards:
+                    sparts = []
+                    for pid in shard.lookup_partitions(
+                            list(low0.filters), chunk_start, chunk_end):
+                        p = shard.partition(pid)
+                        if p is not None:
+                            sparts.append(p)
+                    # on-demand paging: cold chunks (flushed + evicted-from-
+                    # RAM, or pre-restart data recovered only to the column
+                    # store) are merged exactly like the exec path does
+                    # (plan.py) — keyed by object identity because part_ids
+                    # repeat across shards
+                    if sparts and shard.config.demand_paging_enabled:
+                        extra = page_partitions(shard, sparts, chunk_start,
+                                                chunk_end, shard.odp_cache)
+                        if extra:
+                            for p in sparts:
+                                ec = extra.get(p.part_id)
+                                if ec:
+                                    extra_by_obj[id(p)] = ec
+                    parts.extend(sparts)
+                if sp is not None:
+                    sp.tags.update(partitions=len(parts),
+                                   paged=len(extra_by_obj))
             if not parts:
                 self._cache_put(ckey, (version, None, [], None, [], None,
                                        False))
                 return [StepMatrix.empty(steps_array(lo.start, lo.step,
                                                      lo.end))
                         for lo in lows]
-            batch = build_batch(parts, chunk_start, chunk_end,
-                                extra_by_obj=extra_by_obj or None)
+            with span("decode", partitions=len(parts)) as sp:
+                batch = build_batch(parts, chunk_start, chunk_end,
+                                    extra_by_obj=extra_by_obj or None)
+                samples = int(batch.counts.sum())
+                if sp is not None:
+                    sp.tags.update(samples=samples,
+                                   shape=list(batch.vals.shape))
             # counter-ness of the scanned value column (same source the
             # exec path reads): decides delta's reset-correction semantics
             sdata = parts[0].schema.data
@@ -509,22 +525,25 @@ class MeshQueryEngine:
                 return [None] * len(lows)
             for st in stats_objs:
                 st.series_scanned += len(parts)
-                st.samples_scanned += int(batch.counts.sum())
+                st.samples_scanned += samples
             # label grouping (first-occurrence order, like
             # AggregateMapReduce). The metric label is dropped first — the
             # exec path drops it in range-function output keys before
             # grouping, so `by (_metric_)` must group on nothing there too.
-            keys = [p.part_key.range_vector_key for p in parts]
-            if low0.agg is None:
-                gids = np.zeros(len(keys), np.int32)
-                out_keys = []
-            else:
-                gkeys = [self._group_key(k, low0) for k in keys]
-                uniq: dict[RangeVectorKey, int] = {}
-                gids = np.empty(len(gkeys), np.int32)
-                for i, gk in enumerate(gkeys):
-                    gids[i] = uniq.setdefault(gk, len(uniq))
-                out_keys = list(uniq.keys())
+            with span("mesh-group") as sp:
+                keys = [p.part_key.range_vector_key for p in parts]
+                if low0.agg is None:
+                    gids = np.zeros(len(keys), np.int32)
+                    out_keys = []
+                else:
+                    gkeys = [self._group_key(k, low0) for k in keys]
+                    uniq: dict[RangeVectorKey, int] = {}
+                    gids = np.empty(len(gkeys), np.int32)
+                    for i, gk in enumerate(gkeys):
+                        gids[i] = uniq.setdefault(gk, len(uniq))
+                    out_keys = list(uniq.keys())
+                if sp is not None:
+                    sp.tags["groups"] = len(out_keys)
         # histogram batches flatten buckets into the series axis: every
         # (series, bucket) pair becomes one scalar row, group ids become
         # g*B + b, and the same associative kernels/combines apply. The
@@ -553,164 +572,185 @@ class MeshQueryEngine:
             all_steps.append(rel)
 
         if placed is None:
-            gids_full = np.zeros(batch.ts.shape[0], np.int32)
-            gids_full[: len(gids)] = gids
-            raw_vals = None
-            if lane == "raw":
-                mesh_vals = batch.vals
-            elif lane == "split" and _device_correction_ok(batch.vals):
-                # raw values go straight to the device; the counter
-                # correction is fused into the cached prepare program
-                # (make_mesh_prepare), so no host pre-pass runs at all
-                mesh_vals = batch.vals
-            else:
-                counter = fn in ("rate", "increase") or delta_counter
-                mesh_vals = batch.delta_host(counter=counter)
-                if fn in ("rate", "increase"):
-                    # rate/increase also need the raw values for the
-                    # extrapolate-to-zero clamp (heuristic-only reference;
-                    # delta never clamps, even when reset-corrected)
-                    raw_vals = batch.vals
-            bt_ts, bt_counts = batch.ts, batch.counts
-            if B > 1:
-                Pp_, S_ = bt_ts.shape
-                mesh_vals = np.ascontiguousarray(
-                    mesh_vals.transpose(0, 2, 1)).reshape(Pp_ * B, S_)
+            with span("mesh-pad", lane=lane) as sp:
+                gids_full = np.zeros(batch.ts.shape[0], np.int32)
+                gids_full[: len(gids)] = gids
+                raw_vals = None
+                if lane == "raw":
+                    mesh_vals = batch.vals
+                elif lane == "split" and _device_correction_ok(batch.vals):
+                    # raw values go straight to the device; the counter
+                    # correction is fused into the cached prepare program
+                    # (make_mesh_prepare), so no host pre-pass runs at all
+                    mesh_vals = batch.vals
+                else:
+                    counter = fn in ("rate", "increase") or delta_counter
+                    mesh_vals = batch.delta_host(counter=counter)
+                    if fn in ("rate", "increase"):
+                        # rate/increase also need the raw values for the
+                        # extrapolate-to-zero clamp (heuristic-only reference;
+                        # delta never clamps, even when reset-corrected)
+                        raw_vals = batch.vals
+                bt_ts, bt_counts = batch.ts, batch.counts
+                if B > 1:
+                    Pp_, S_ = bt_ts.shape
+                    mesh_vals = np.ascontiguousarray(
+                        mesh_vals.transpose(0, 2, 1)).reshape(Pp_ * B, S_)
+                    if raw_vals is not None:
+                        raw_vals = np.ascontiguousarray(
+                            raw_vals.transpose(0, 2, 1)).reshape(Pp_ * B, S_)
+                    bt_ts = np.repeat(bt_ts, B, axis=0)
+                    bt_counts = np.repeat(bt_counts, B)
+                    gids_full = (gids_full[:, None] * B + np.arange(
+                        B, dtype=np.int32)[None, :]).reshape(-1)
+                ts_p, vals_p, valid, gid_p = pad_for_mesh(
+                    bt_ts, mesh_vals, bt_counts, gids_full, mesh)
+                raw_p = None
                 if raw_vals is not None:
-                    raw_vals = np.ascontiguousarray(
-                        raw_vals.transpose(0, 2, 1)).reshape(Pp_ * B, S_)
-                bt_ts = np.repeat(bt_ts, B, axis=0)
-                bt_counts = np.repeat(bt_counts, B)
-                gids_full = (gids_full[:, None] * B + np.arange(
-                    B, dtype=np.int32)[None, :]).reshape(-1)
-            ts_p, vals_p, valid, gid_p = pad_for_mesh(
-                bt_ts, mesh_vals, bt_counts, gids_full, mesh)
-            raw_p = None
-            if raw_vals is not None:
-                raw_p = np.zeros(vals_p.shape, vals_p.dtype)
-                raw_p[: raw_vals.shape[0], : raw_vals.shape[1]] = \
-                    np.nan_to_num(raw_vals, nan=0.0)
-            placed = shard_batch_arrays(mesh, ts_p, vals_p, valid, gid_p,
-                                        raw_p)
+                    raw_p = np.zeros(vals_p.shape, vals_p.dtype)
+                    raw_p[: raw_vals.shape[0], : raw_vals.shape[1]] = \
+                        np.nan_to_num(raw_vals, nan=0.0)
+                if sp is not None:
+                    sp.tags["shape"] = list(vals_p.shape)
+            with span("mesh-place") as sp:
+                placed = shard_batch_arrays(mesh, ts_p, vals_p, valid,
+                                            gid_p, raw_p)
+                if sp is not None:
+                    sp.tags["bytes"] = sum(
+                        a.nbytes for a in (ts_p, vals_p, valid, gid_p,
+                                           raw_p) if a is not None)
             self._cache_put(ckey, (version, batch, keys, gids, out_keys,
                                    placed, is_counter))
 
-        agg = low0.agg
-        if use_split:
-            # per-query work is ONLY the group reduce; window evaluation
-            # is served from the eval cache (see the chunk loop below)
-            step_fn = None if agg is None else self._get_fn(
-                ("split-reduce", agg, Gp),
-                lambda: make_mesh_group_reduce(mesh, Gp, agg))
-        elif self.variant == "ring" and fn == "rate" and agg == "sum":
-            step_fn = self._get_fn(
-                (fn, agg, Gp if agg else None, self.variant),
-                lambda: make_distributed_sum_rate_ring(mesh, Gp))
-        else:
-            step_fn = self._get_fn(
-                (fn, agg, Gp if agg else None, self.variant),
-                lambda: make_distributed_range_agg(mesh, fn, Gp, agg))
-        _M_DISPATCH["split" if use_split else "fused"].inc()
-
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        # replicated small operands are PINNED to the mesh's devices: the
-        # default backend may be a different platform (e.g. a host-lane CPU
-        # mesh inside a TPU process), and a default-placed operand would
-        # drag cross-backend transfers into every call
-        repl = NamedSharding(mesh, PartitionSpec())
-        win_d = jax.device_put(np.int32(low0.window), repl)
-        ts_d, vals_d, valid_d, gid_d = placed[:4]
-        raw_d = placed[4] if len(placed) > 4 else None
-
-        # split pipeline: prepared per-version device arrays (correction /
-        # prefixes), reused by every query over this batch version
-        split_cv = None
-        split_prefix = None
-        if use_split:
-            if fn in ("rate", "increase") or delta_counter:
-                split_cv = self._prepared(dkey, version, "counter", mesh,
-                                          vals_d, valid_d)
-            elif fn != "delta":
-                split_prefix = self._prepared(dkey, version, "prefix", mesh,
-                                              vals_d, valid_d)
-
-        # Fixed call shapes: compile storms would otherwise follow the batch
-        # size (every distinct ΣKp is a fresh program). Queries grouped by
-        # Kp run in chunks of exactly 1 or GROUP (grids repeated to fill),
-        # so each (signature, Kp) compiles at most twice ever.
-        GROUP = 8
-        by_kp: dict[int, list[int]] = {}
-        for i, (Kp, _, _) in enumerate(spans):
-            by_kp.setdefault(Kp, []).append(i)
-        results: list = [None] * len(lows)
-        nrows = (G if agg else len(keys)) * B
-        # phase 1: dispatch every chunk's device program (async — results
-        # stay lazy on device so compute overlaps across chunks)
-        calls: list[tuple] = []
-        for Kp, idxs in by_kp.items():
-            pos = 0
-            while pos < len(idxs):
-                chunk = idxs[pos : pos + GROUP]
-                pos += GROUP
-                size = 1 if len(chunk) == 1 else GROUP
-                grids = [all_steps[i] for i in chunk]
-                grids += [grids[-1]] * (size - len(chunk))
-                blob = np.concatenate(grids)
-                gkey = blob.tobytes()
-                grid_d = self._grid_cache.get(gkey)
-                if grid_d is None:
-                    if len(self._grid_cache) >= self._grid_cache_cap:
-                        self._grid_cache.pop(next(iter(self._grid_cache)))
-                    grid_d = self._grid_cache[gkey] = jax.device_put(
-                        blob, repl)
-                if use_split:
-                    ev_d = self._series_eval_cached(
-                        dkey, version, low0.window, gkey, fn, mesh, ts_d,
-                        vals_d, valid_d, grid_d, win_d, split_cv,
-                        split_prefix, raw_d, delta_counter)
-                    out = ev_d if step_fn is None else step_fn(ev_d, gid_d)
-                elif raw_d is not None:
-                    out = step_fn(ts_d, vals_d, valid_d, gid_d, grid_d,
-                                  win_d, raw_d)
-                else:
-                    out = step_fn(ts_d, vals_d, valid_d, gid_d, grid_d,
-                                  win_d)
-                calls.append((out, chunk, Kp))
-        # phase 2: coalesced device→host fetch — one transfer per distinct
-        # output shape (per-query slicing on device would cost a dispatch +
-        # a blocking fetch each)
-        by_shape: dict[tuple, list[int]] = {}
-        for ci, (out, _, _) in enumerate(calls):
-            by_shape.setdefault(out.shape, []).append(ci)
-        fetched: dict[int, np.ndarray] = {}
-        for cis in by_shape.values():
-            if len(cis) == 1:
-                fetched[cis[0]] = np.asarray(calls[cis[0]][0])
+        with span("mesh-dispatch",
+                  form="split" if use_split else "fused") as sp:
+            agg = low0.agg
+            if use_split:
+                # per-query work is ONLY the group reduce; window evaluation
+                # is served from the eval cache (see the chunk loop below)
+                step_fn = None if agg is None else self._get_fn(
+                    ("split-reduce", agg, Gp),
+                    lambda: make_mesh_group_reduce(mesh, Gp, agg))
+            elif self.variant == "ring" and fn == "rate" and agg == "sum":
+                step_fn = self._get_fn(
+                    (fn, agg, Gp if agg else None, self.variant),
+                    lambda: make_distributed_sum_rate_ring(mesh, Gp))
             else:
-                stacked = np.asarray(jnp.stack(
-                    [calls[ci][0] for ci in cis]))
-                for j, ci in enumerate(cis):
-                    fetched[ci] = stacked[j]
-        for ci, (_, chunk, Kp) in enumerate(calls):
-            out_np = fetched[ci]
-            for j, i in enumerate(chunk):
-                lo = lows[i]
-                _, K, steps_ms = spans[i]
-                vals = out_np[:nrows, j * Kp : j * Kp + K]
-                if B > 1:  # un-flatten buckets: [n*B, K] -> [n, K, B]
-                    vals = np.ascontiguousarray(
-                        vals.reshape(-1, B, K).transpose(0, 2, 1))
-                if agg is None:
-                    rkeys = keys if lo.keep_metric \
-                        else [k.drop_metric() for k in keys]
+                step_fn = self._get_fn(
+                    (fn, agg, Gp if agg else None, self.variant),
+                    lambda: make_distributed_range_agg(mesh, fn, Gp, agg))
+            _M_DISPATCH["split" if use_split else "fused"].inc()
+
+            import jax
+            import jax.numpy as jnp
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            # replicated small operands are PINNED to the mesh's devices:
+            # the default backend may be a different platform (e.g. a
+            # host-lane CPU mesh inside a TPU process), and a default-placed
+            # operand would drag cross-backend transfers into every call
+            repl = NamedSharding(mesh, PartitionSpec())
+            win_d = jax.device_put(np.int32(low0.window), repl)
+            ts_d, vals_d, valid_d, gid_d = placed[:4]
+            raw_d = placed[4] if len(placed) > 4 else None
+
+            # split pipeline: prepared per-version device arrays
+            # (correction / prefixes), reused by every query over this
+            # batch version
+            split_cv = None
+            split_prefix = None
+            if use_split:
+                if fn in ("rate", "increase") or delta_counter:
+                    split_cv = self._prepared(dkey, version, "counter", mesh,
+                                              vals_d, valid_d)
+                elif fn != "delta":
+                    split_prefix = self._prepared(dkey, version, "prefix",
+                                                  mesh, vals_d, valid_d)
+
+            # Fixed call shapes: compile storms would otherwise follow the
+            # batch size (every distinct ΣKp is a fresh program). Queries
+            # grouped by Kp run in chunks of exactly 1 or GROUP (grids
+            # repeated to fill), so each (signature, Kp) compiles at most
+            # twice ever.
+            GROUP = 8
+            by_kp: dict[int, list[int]] = {}
+            for i, (Kp, _, _) in enumerate(spans):
+                by_kp.setdefault(Kp, []).append(i)
+            results: list = [None] * len(lows)
+            nrows = (G if agg else len(keys)) * B
+            # phase 1: dispatch every chunk's device program (async —
+            # results stay lazy on device so compute overlaps across chunks)
+            calls: list[tuple] = []
+            for Kp, idxs in by_kp.items():
+                pos = 0
+                while pos < len(idxs):
+                    chunk = idxs[pos : pos + GROUP]
+                    pos += GROUP
+                    size = 1 if len(chunk) == 1 else GROUP
+                    grids = [all_steps[i] for i in chunk]
+                    grids += [grids[-1]] * (size - len(chunk))
+                    blob = np.concatenate(grids)
+                    gkey = blob.tobytes()
+                    grid_d = self._grid_cache.get(gkey)
+                    if grid_d is None:
+                        if len(self._grid_cache) >= self._grid_cache_cap:
+                            self._grid_cache.pop(
+                                next(iter(self._grid_cache)))
+                        grid_d = self._grid_cache[gkey] = jax.device_put(
+                            blob, repl)
+                    if use_split:
+                        ev_d = self._series_eval_cached(
+                            dkey, version, low0.window, gkey, fn, mesh, ts_d,
+                            vals_d, valid_d, grid_d, win_d, split_cv,
+                            split_prefix, raw_d, delta_counter)
+                        out = ev_d if step_fn is None \
+                            else step_fn(ev_d, gid_d)
+                    elif raw_d is not None:
+                        out = step_fn(ts_d, vals_d, valid_d, gid_d, grid_d,
+                                      win_d, raw_d)
+                    else:
+                        out = step_fn(ts_d, vals_d, valid_d, gid_d, grid_d,
+                                      win_d)
+                    calls.append((out, chunk, Kp))
+            if sp is not None:
+                sp.tags["programs"] = len(calls)
+        with span("mesh-fetch") as sp:
+            # phase 2: coalesced device→host fetch — one transfer per
+            # distinct output shape (per-query slicing on device would cost
+            # a dispatch + a blocking fetch each)
+            by_shape: dict[tuple, list[int]] = {}
+            for ci, (out, _, _) in enumerate(calls):
+                by_shape.setdefault(out.shape, []).append(ci)
+            fetched: dict[int, np.ndarray] = {}
+            for cis in by_shape.values():
+                if len(cis) == 1:
+                    fetched[cis[0]] = np.asarray(calls[cis[0]][0])
                 else:
-                    rkeys = out_keys
-                m = StepMatrix(list(rkeys), vals, steps_ms,
-                               batch.les if B > 1 else None)
-                results[i] = self._apply_post(m, lo)
+                    stacked = np.asarray(jnp.stack(
+                        [calls[ci][0] for ci in cis]))
+                    for j, ci in enumerate(cis):
+                        fetched[ci] = stacked[j]
+            if sp is not None:
+                sp.tags["bytes"] = sum(a.nbytes
+                                       for a in fetched.values())
+        with span("mesh-assemble", rows=nrows):
+            for ci, (_, chunk, Kp) in enumerate(calls):
+                out_np = fetched[ci]
+                for j, i in enumerate(chunk):
+                    lo = lows[i]
+                    _, K, steps_ms = spans[i]
+                    vals = out_np[:nrows, j * Kp : j * Kp + K]
+                    if B > 1:  # un-flatten buckets: [n*B, K] -> [n, K, B]
+                        vals = np.ascontiguousarray(
+                            vals.reshape(-1, B, K).transpose(0, 2, 1))
+                    if agg is None:
+                        rkeys = keys if lo.keep_metric \
+                            else [k.drop_metric() for k in keys]
+                    else:
+                        rkeys = out_keys
+                    m = StepMatrix(list(rkeys), vals, steps_ms,
+                                   batch.les if B > 1 else None)
+                    results[i] = self._apply_post(m, lo)
         return results
 
     def _cache_put(self, ckey, entry):
@@ -776,6 +816,7 @@ class MeshQueryEngine:
         runs only the group reduce."""
         ekey = (dkey, version, window, grid_bytes, fn)
         hit = self._eval_cache.get(ekey)
+        tag("eval_cache", "miss" if hit is None else "hit")
         if hit is not None:
             _M_EVAL["hit"].inc()
             return hit

@@ -20,6 +20,7 @@ import numpy as np
 
 from filodb_tpu.core.memstore.partition import TimeSeriesPartition
 from filodb_tpu.memory.codecs import HistogramColumn
+from filodb_tpu.utils.tracing import span
 
 TS_PAD = np.iinfo(np.int32).max
 
@@ -140,41 +141,48 @@ def build_batch(partitions: list[TimeSeriesPartition], start: int, end: int,
     per_ts: list[np.ndarray] = []
     per_vals: list = []
     les = None
-    for p in partitions:
-        extra = extra_by_obj.get(id(p)) if extra_by_obj else None
-        if extra is None and extra_chunks:
-            extra = extra_chunks.get(p.part_id)
-        ts, vals = p.read_samples(start, end, value_col, extra_chunks=extra)
-        if isinstance(vals, HistogramColumn):
-            les = vals.les if les is None or len(vals.les) > len(les) else les
-            rows = vals.rows.astype(np.float64)
-            per_ts.append(ts)
-            per_vals.append(rows)
-        else:
-            valid = ~np.isnan(vals)
-            per_ts.append(ts[valid])
-            per_vals.append(vals[valid])
-
-    P = len(partitions)
-    maxS = max((len(t) for t in per_ts), default=0)
-    S = _next_pow2(maxS) if pad_samples else max(maxS, 1)
-    Pp = _next_pow2(P) if pad_series else max(P, 1)
-    ts_arr = np.full((Pp, S), TS_PAD, np.int32)
-    if les is not None:
-        B = len(les)
-        vals_arr = np.zeros((Pp, S, B), np.float64)
-    else:
-        vals_arr = np.full((Pp, S), np.nan, np.float64)
-    counts = np.zeros(Pp, np.int32)
-    for i, (t, v) in enumerate(zip(per_ts, per_vals)):
-        n = len(t)
-        counts[i] = n
-        if n:
-            ts_arr[i, :n] = (t - start).astype(np.int32)
-            if les is not None and v.shape[-1] != vals_arr.shape[-1]:
-                vals_arr[i, :n, : v.shape[-1]] = v  # smaller historic scheme
+    with span("batch-read", partitions=len(partitions)):
+        for p in partitions:
+            extra = extra_by_obj.get(id(p)) if extra_by_obj else None
+            if extra is None and extra_chunks:
+                extra = extra_chunks.get(p.part_id)
+            ts, vals = p.read_samples(start, end, value_col,
+                                      extra_chunks=extra)
+            if isinstance(vals, HistogramColumn):
+                les = vals.les if les is None or len(vals.les) > len(les) \
+                    else les
+                rows = vals.rows.astype(np.float64)
+                per_ts.append(ts)
+                per_vals.append(rows)
             else:
-                vals_arr[i, :n] = v
+                valid = ~np.isnan(vals)
+                per_ts.append(ts[valid])
+                per_vals.append(vals[valid])
+
+    with span("batch-stack") as sp:
+        P = len(partitions)
+        maxS = max((len(t) for t in per_ts), default=0)
+        S = _next_pow2(maxS) if pad_samples else max(maxS, 1)
+        Pp = _next_pow2(P) if pad_series else max(P, 1)
+        ts_arr = np.full((Pp, S), TS_PAD, np.int32)
+        if les is not None:
+            B = len(les)
+            vals_arr = np.zeros((Pp, S, B), np.float64)
+        else:
+            vals_arr = np.full((Pp, S), np.nan, np.float64)
+        counts = np.zeros(Pp, np.int32)
+        for i, (t, v) in enumerate(zip(per_ts, per_vals)):
+            n = len(t)
+            counts[i] = n
+            if n:
+                ts_arr[i, :n] = (t - start).astype(np.int32)
+                if les is not None and v.shape[-1] != vals_arr.shape[-1]:
+                    # smaller historic scheme
+                    vals_arr[i, :n, : v.shape[-1]] = v
+                else:
+                    vals_arr[i, :n] = v
+        if sp is not None:
+            sp.tags["shape"] = list(vals_arr.shape)
     return SeriesBatch(start, ts_arr, vals_arr, counts,
                        [p.part_id for p in partitions], les)
 

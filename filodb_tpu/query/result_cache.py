@@ -366,7 +366,7 @@ class ResultCache:
 
         extent_ms = self.config.extent_steps * step
         t0 = time.perf_counter()
-        parts: list[tuple[int, int, StepMatrix]] = []
+        found: list[tuple[int, int, int, StepMatrix]] = []
         stats = QueryStats()
         hits = misses = 0
         with span("cache", extents=len(extents)) as sp:
@@ -418,12 +418,15 @@ class ResultCache:
                     # fold the full expanded counters (incl. per-tier
                     # federation buckets), not just the scan totals
                     stats.merge_counts(r.stats)
-                parts.append((es, ee, _slice_steps(m, fs, step, es, ee)))
+                found.append((es, ee, fs, m))
             cache_hits.inc(hits)
             cache_misses.inc(misses)
             if 0 < hits < len(extents):
                 cache_partial_hits.inc()
-            merged = _merge_extents(parts, step)
+            with span("cache-merge"):
+                merged = _merge_extents(
+                    [(es, ee, _slice_steps(m, fs, step, es, ee))
+                     for es, ee, fs, m in found], step)
             if sp is not None:
                 sp.tags.update(hits=hits, misses=misses,
                                bytes=self._bytes)

@@ -21,6 +21,12 @@ in-process objects that cross the wire as plain span dicts:
   ``/promql/{ds}/api/v1/debug/slow_queries`` on both HTTP fronts and via
   ``filo-cli slowlog``. ``/promql/{ds}/api/v1/debug/trace`` runs one query
   fully traced and records it in the same ring.
+- :func:`traced_batch` does the same once for a whole
+  ``query_range_many`` device batch: one ``query-batch`` entry.
+- Every entry carries one clock pair — ``t0_unix_ns``, the wall clock at
+  the ``perf_counter`` reading its durations start from — and every span
+  one offset from it, ``start_ms``; so spans can be laid beside anything
+  else that is on the wall clock (a device profile).
 - :func:`traced_operation` reuses the machinery for background work (rules
   ticks, objectstore uploads, migration phases); slow operations land in
   the same recorder.
@@ -103,8 +109,11 @@ class Span:
     span_id: int = 0
     parent_id: int = 0
 
-    def as_dict(self) -> dict:
+    def as_dict(self, t0_s: float) -> dict:
+        """``start_ms`` is the span's start as an offset from ``t0_s``, the
+        ``perf_counter`` reading at which its query (or trace) started."""
         d = {"name": self.name, "depth": self.depth,
+             "start_ms": round((self.start_s - t0_s) * 1000, 3),
              "duration_ms": round(self.duration_s * 1000, 3),
              "span_id": self.span_id, "parent_id": self.parent_id}
         if self.tags:
@@ -115,13 +124,16 @@ class Span:
 @dataclass
 class Trace:
     spans: list[Span] = field(default_factory=list)
-    _depth: int = 0  # legacy field; per-thread depth now lives in _local
+    # the one clock pair of a trace: span starts are offsets from ``t0_s``
+    # (perf_counter), which was read at ``t0_unix_ns`` on the wall clock
+    t0_s: float = field(default_factory=time.perf_counter)
+    t0_unix_ns: int = field(default_factory=time.time_ns)
     _lock: threading.Lock = field(default_factory=threading.Lock,
                                   repr=False, compare=False)
 
     def as_dicts(self) -> list[dict]:
         with self._lock:
-            return [s.as_dict() for s in self.spans]
+            return [s.as_dict(self.t0_s) for s in self.spans]
 
     def find(self, name: str) -> list[Span]:
         with self._lock:
@@ -215,12 +227,17 @@ def graft_spans(span_dicts: list, parent: Span | None = None,
     shipped in ``QueryResult.spans``) to the current trace under ``parent``.
     Top-level remote spans get ``extra_tags`` (e.g. ``node="host:port"``).
     Span ids are remapped to this process's id space so parent links stay
-    unambiguous when several peers graft concurrently."""
+    unambiguous when several peers graft concurrently. A remote span's
+    ``start_ms`` (an offset from the remote trace's start) is kept, rebased
+    on the start of ``parent`` — the local ``dispatch`` span — which
+    ignores the wire's latency: the remote tree sits up to one network
+    hop too early inside its dispatch span."""
     trace = getattr(_local, "trace", None)
     if trace is None or not span_dicts:
         return
     base_depth = parent.depth + 1 if parent is not None else 0
     base_parent = parent.span_id if parent is not None else 0
+    base_start = parent.start_s if parent is not None else trace.t0_s
     remap: dict[int, int] = {}
     spans = []
     for d in span_dicts:
@@ -235,7 +252,8 @@ def graft_spans(span_dicts: list, parent: Span | None = None,
         if not pid:
             pid = base_parent
             tags.update(extra_tags)
-        spans.append(Span(d["name"], 0.0,
+        spans.append(Span(d["name"],
+                          base_start + float(d.get("start_ms", 0.0)) / 1000,
                           duration_s=float(d.get("duration_ms", 0.0)) / 1000,
                           depth=base_depth + int(d.get("depth", 0)),
                           tags=tags, span_id=sid, parent_id=pid))
@@ -247,7 +265,12 @@ def graft_spans(span_dicts: list, parent: Span | None = None,
 # per-stage histograms derived from spans
 
 _STAGES = ("parse", "plan-materialize", "exec-dispatch", "dispatch",
-           "mesh-execute", "scan", "decode", "reduce", "odp-page", "cache")
+           "mesh-execute", "scan", "decode", "reduce", "odp-page", "cache",
+           # the phases of a mesh-engine extent (parallel/mesh_engine.py,
+           # query/engine/batch.py) and the tails above it
+           "mesh-lookup", "batch-read", "batch-stack", "mesh-group",
+           "mesh-pad", "mesh-place", "mesh-dispatch", "mesh-fetch",
+           "mesh-assemble", "finish", "cache-merge", "batch-fetch")
 _stage_hists = {}
 for _s in _STAGES:
     _stage_hists[_s] = Histogram("filodb_query_stage_seconds",
@@ -357,7 +380,7 @@ def _stats_dict(result) -> dict:
         return {}
 
 
-def _finish_query(rec, trace, start_idx, t0, sampled, info) -> None:
+def _finish_query(rec, trace, start_idx, t0, t0_ns, sampled, info) -> None:
     cfg = _config
     duration_ms = (time.perf_counter() - t0) * 1000
     section = []
@@ -368,11 +391,11 @@ def _finish_query(rec, trace, start_idx, t0, sampled, info) -> None:
     if cfg.slow_query_threshold_ms <= 0 \
             or duration_ms <= cfg.slow_query_threshold_ms:
         return
-    entry = {"kind": "query", "when": time.time(),
+    entry = {"kind": "query", "when": time.time(), "t0_unix_ns": t0_ns,
              "duration_ms": round(duration_ms, 3), "sampled": sampled}
     entry.update(info)
     entry["stats"] = _stats_dict(rec.result)
-    entry["spans"] = [s.as_dict() for s in section]
+    entry["spans"] = [s.as_dict(t0) for s in section]
     _recorder.record(entry)
     _recorded.inc()
 
@@ -390,7 +413,7 @@ def traced_query(qcontext, **info):
     trees for every slow query)."""
     from filodb_tpu.query.model import TraceContext
     rec = _QueryRecord()
-    t0 = time.perf_counter()
+    t0, t0_ns = time.perf_counter(), time.time_ns()
     outer = getattr(_local, "trace", None)
     if outer is not None:
         if getattr(qcontext, "trace", None) is None:
@@ -400,7 +423,7 @@ def traced_query(qcontext, **info):
         try:
             yield rec
         finally:
-            _finish_query(rec, outer, start_idx, t0, True, info)
+            _finish_query(rec, outer, start_idx, t0, t0_ns, True, info)
         return
     if should_sample(qcontext.query_id):
         _sampled.inc()
@@ -410,12 +433,12 @@ def traced_query(qcontext, **info):
             try:
                 yield rec
             finally:
-                _finish_query(rec, trace, 0, t0, True, info)
+                _finish_query(rec, trace, 0, t0, t0_ns, True, info)
     else:
         try:
             yield rec
         finally:
-            _finish_query(rec, None, 0, t0, False, info)
+            _finish_query(rec, None, 0, t0, t0_ns, False, info)
 
 
 def record_slow(kind: str, duration_ms: float, spans: list | None = None,
@@ -437,6 +460,31 @@ def record_slow(kind: str, duration_ms: float, spans: list | None = None,
 
 
 @contextmanager
+def traced_batch(members: int, **info):
+    """One trace for a multi-query device batch (``query_range_many``): the
+    batch is head-sampled once, runs under one trace, and leaves ONE
+    ``query-batch`` flight-recorder entry — its wall time is every
+    member's latency, so there are no per-member spans. Unsampled (or
+    inside an already-active trace, whose spans it joins) it costs the
+    one ``should_sample`` call."""
+    if getattr(_local, "trace", None) is not None \
+            or not should_sample(f"batch-{next(_span_ids)}"):
+        yield
+        return
+    _sampled.inc()
+    with start_trace() as trace:
+        try:
+            yield
+        finally:
+            observe_stage_times(trace.spans)
+            record_slow("query-batch",
+                        (time.perf_counter() - trace.t0_s) * 1000,
+                        spans=trace.as_dicts(),
+                        t0_unix_ns=trace.t0_unix_ns, members=members,
+                        **info)
+
+
+@contextmanager
 def traced_operation(kind: str, **tags):
     """Trace a background operation (rules tick, gateway drain, shard
     ingest, flush, objectstore upload, migration phase). Operations are
@@ -449,11 +497,10 @@ def traced_operation(kind: str, **tags):
         with span(kind, **tags) as s:
             yield s
         return
-    t0 = time.perf_counter()
     with start_trace() as trace:
         with span(kind, **tags) as s:
             yield s
-    duration_ms = (time.perf_counter() - t0) * 1000
+    duration_ms = (time.perf_counter() - trace.t0_s) * 1000
     cfg = _config
     if kind in _INGEST_KINDS:
         recorder, threshold, counter = (
@@ -464,6 +511,7 @@ def traced_operation(kind: str, **tags):
             _recorder, cfg.slow_query_threshold_ms, _recorded)
     if threshold > 0 and duration_ms > threshold:
         entry = {"kind": kind, "when": time.time(),
+                 "t0_unix_ns": trace.t0_unix_ns,
                  "duration_ms": round(duration_ms, 3), "sampled": True}
         entry.update(tags)
         entry["spans"] = trace.as_dicts()

@@ -199,6 +199,21 @@ TRACING_NAMES = [
     "filodb_query_stage_seconds_sum",
     "filodb_queries_sampled_total",
     "filodb_slow_queries_recorded_total",
+    # render has no span (it runs after the query's trace has closed):
+    # both HTTP fronts observe it into one histogram (http/server.py)
+    "filodb_http_render_seconds_bucket",
+    "filodb_http_render_seconds_count",
+    "filodb_http_render_seconds_sum",
+]
+
+# the stage label's whitelist (utils/tracing._STAGES): the mesh engine's
+# phase spans and the tails above it joined it with the spans themselves
+TRACING_STAGES = [
+    "parse", "plan-materialize", "exec-dispatch", "dispatch",
+    "mesh-execute", "scan", "decode", "reduce", "odp-page", "cache",
+    "mesh-lookup", "batch-read", "batch-stack", "mesh-group", "mesh-pad",
+    "mesh-place", "mesh-dispatch", "mesh-fetch", "mesh-assemble", "finish",
+    "cache-merge", "batch-fetch",
 ]
 
 
@@ -447,6 +462,11 @@ class TestMetricsScrape:
         # import time (stage labels are a bounded whitelist)
         missing_tr = [n for n in TRACING_NAMES if n not in names_present]
         assert not missing_tr, f"missing tracing metrics: {missing_tr}"
+        missing_st = [
+            st for st in TRACING_STAGES
+            if f'filodb_query_stage_seconds_count{{stage="{st}"}}'
+            not in text]
+        assert not missing_st, f"missing stage histograms: {missing_st}"
 
         # ingest-path freshness + selfmon families: the import-time ones
         # render unconditionally; the per-shard lag gauges register at
