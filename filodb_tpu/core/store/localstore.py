@@ -23,6 +23,7 @@ from __future__ import annotations
 import os
 import sqlite3
 import threading
+import time
 
 from filodb_tpu.core.partkey import PartKey
 from filodb_tpu.core.store.api import ColumnStore, MetaStore, PartKeyRecord
@@ -65,7 +66,23 @@ class _Db:
                 # interleave chunk and checkpoint writes, so lock waits
                 # must block-and-retry instead of raising immediately
                 c.execute("PRAGMA busy_timeout=10000")
-                c.execute("PRAGMA journal_mode=WAL")
+                # ... except here: the switch of a fresh file to WAL wants
+                # an exclusive lock and sqlite raises at once, without the
+                # busy handler, when the other store's connection is
+                # mid-write (parallel group flushes open both at the same
+                # moment) — so this one statement retries by hand. The
+                # sleep is under _lock by design: whoever waits for the
+                # lock waits for this very connection
+                deadline = time.monotonic() + 10.0
+                while True:
+                    try:
+                        c.execute("PRAGMA journal_mode=WAL")
+                        break
+                    except sqlite3.OperationalError as e:
+                        if "locked" not in str(e) \
+                                or time.monotonic() > deadline:
+                            raise
+                        time.sleep(0.005)  # filolint: disable=LD101
                 c.execute("PRAGMA synchronous=NORMAL")
                 c.execute("""CREATE TABLE IF NOT EXISTS chunks (
                     partition BLOB, chunkid INTEGER, start_time INTEGER,

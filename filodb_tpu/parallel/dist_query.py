@@ -24,7 +24,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -610,29 +609,6 @@ def shard_batch_arrays(mesh: Mesh, ts, vals, valid, group_ids, raw=None):
     if raw is not None:
         placed += (jax.device_put(raw, s2),)
     return placed
-
-
-def pad_for_mesh(ts, vals, counts, group_ids, mesh: Mesh):
-    """Pad P to a multiple of mesh 'shard' size and S to 'time' size;
-    returns padded arrays + a validity mask (replaces counts, which don't
-    shard along the time axis)."""
-    ds = mesh.shape["shard"]
-    dtm = mesh.shape["time"]
-    P_, S_ = ts.shape
-    Pp = -(-P_ // ds) * ds
-    Sp = -(-S_ // dtm) * dtm
-    ts_p = np.full((Pp, Sp), np.iinfo(np.int32).max, np.int32)
-    vals_p = np.zeros((Pp, Sp), vals.dtype)
-    valid = np.zeros((Pp, Sp), bool)
-    ts_p[:P_, :S_] = ts
-    vals_p[:P_, :S_] = np.nan_to_num(vals, nan=0.0)
-    valid[:P_, :S_] = np.arange(S_)[None, :] < counts[:, None]
-    gid_p = np.zeros(Pp, np.int32)
-    gid_p[:P_] = group_ids
-    if Pp > P_:
-        # padding series join group 0 but contribute nothing (no valid samples)
-        pass
-    return ts_p, vals_p, valid, gid_p
 
 
 def make_distributed_sum_rate_ring(mesh: Mesh, num_groups: int):

@@ -114,6 +114,19 @@ def _device_correction_ok(vals: np.ndarray) -> bool:
     finite = vals[np.isfinite(vals)]
     return finite.size == 0 or float(np.abs(finite).max()) < F32_SAFE_MAX
 
+
+def _placed_values(vals: np.ndarray) -> np.ndarray:
+    """The array that is placed for an f64 host value array: ONE allocation
+    in the device's float dtype (numpy's ``astype`` rounds as
+    ``device_put`` would), then the NaN that marks padding set to 0 — the
+    kernels mask by validity, not by NaN. Always a copy: ``vals`` may be the
+    cached batch's own."""
+    from filodb_tpu.query.engine.batch import device_float
+
+    out = vals.astype(device_float())
+    np.putmask(out, np.isnan(out), 0)
+    return out
+
 # range functions with associative mesh combines (dist_query kernels)
 MESH_FNS = ("rate", "increase", "delta", "sum_over_time", "count_over_time",
             "avg_over_time", "min_over_time", "max_over_time",
@@ -404,16 +417,22 @@ class MeshQueryEngine:
         whole shared batch, so each gets the full scan counts.
 
         Phase spans, in order, tiling the caller's ``mesh-execute``:
-        ``mesh-lookup``, ``decode``, ``mesh-group``, ``mesh-pad``,
-        ``mesh-place`` (a batch-cache hit opens none of these five),
-        ``mesh-dispatch``, ``mesh-fetch``, ``mesh-assemble``."""
+        ``mesh-lookup`` (posting lists, paging), ``decode`` (``build_batch``:
+        every sample written once into arrays of the placed shape — and, on
+        the ``raw`` lane, the placed dtype), ``mesh-group`` (keys and group
+        ids at the placed length), ``mesh-pad`` (no padding any more: the
+        lane choice, the delta lanes' host f64 pass and the one converted
+        copy it needs — tag ``copied_bytes``, 0 on the ``raw`` lane — the
+        histogram flatten, the validity mask), ``mesh-place`` (the put: the
+        batch's own ``ts``/``vals`` on the ``raw`` lane); a batch-cache hit
+        opens none of these five. Then ``mesh-dispatch``, ``mesh-fetch``,
+        ``mesh-assemble``."""
         stats_objs = stats if isinstance(stats, list) \
             else ([stats] if stats is not None else [])
         from filodb_tpu.core.memstore.odp import page_partitions
         from filodb_tpu.parallel.dist_query import (
             make_distributed_range_agg,
             make_distributed_sum_rate_ring,
-            pad_for_mesh,
             shard_batch_arrays,
         )
         from filodb_tpu.query.engine.batch import build_batch
@@ -510,8 +529,13 @@ class MeshQueryEngine:
                                                      lo.end))
                         for lo in lows]
             with span("decode", partitions=len(parts)) as sp:
-                batch = build_batch(parts, chunk_start, chunk_end,
-                                    extra_by_obj=extra_by_obj or None)
+                # the batch is built at the shape that is placed, and on
+                # the raw lane (no host f64 pass follows) in the dtype too
+                batch = build_batch(
+                    parts, chunk_start, chunk_end,
+                    extra_by_obj=extra_by_obj or None,
+                    mesh_multiples=(mesh.shape["shard"], mesh.shape["time"]),
+                    host_f64=lane != "raw")
                 samples = int(batch.counts.sum())
                 if sp is not None:
                     sp.tags.update(samples=samples,
@@ -532,13 +556,14 @@ class MeshQueryEngine:
             # grouping, so `by (_metric_)` must group on nothing there too.
             with span("mesh-group") as sp:
                 keys = [p.part_key.range_vector_key for p in parts]
+                # one id a padded row: padding series join group 0 and
+                # contribute nothing (no valid samples)
+                gids = np.zeros(batch.ts.shape[0], np.int32)
                 if low0.agg is None:
-                    gids = np.zeros(len(keys), np.int32)
                     out_keys = []
                 else:
                     gkeys = [self._group_key(k, low0) for k in keys]
                     uniq: dict[RangeVectorKey, int] = {}
-                    gids = np.empty(len(gkeys), np.int32)
                     for i, gk in enumerate(gkeys):
                         gids[i] = uniq.setdefault(gk, len(uniq))
                     out_keys = list(uniq.keys())
@@ -573,45 +598,51 @@ class MeshQueryEngine:
 
         if placed is None:
             with span("mesh-pad", lane=lane) as sp:
-                gids_full = np.zeros(batch.ts.shape[0], np.int32)
-                gids_full[: len(gids)] = gids
+                ts_p, counts_p, gid_p = batch.ts, batch.counts, gids
                 raw_vals = None
-                if lane == "raw":
-                    mesh_vals = batch.vals
-                elif lane == "split" and _device_correction_ok(batch.vals):
-                    # raw values go straight to the device; the counter
-                    # correction is fused into the cached prepare program
-                    # (make_mesh_prepare), so no host pre-pass runs at all
-                    mesh_vals = batch.vals
+                if lane == "raw" or (lane == "split"
+                                     and _device_correction_ok(batch.vals)):
+                    # raw values go straight to the device; on the split
+                    # lane the counter correction is fused into the cached
+                    # prepare program (make_mesh_prepare), so no host
+                    # pre-pass runs at all
+                    host_vals = batch.vals
                 else:
                     counter = fn in ("rate", "increase") or delta_counter
-                    mesh_vals = batch.delta_host(counter=counter)
+                    host_vals = batch.delta_host(counter=counter)
                     if fn in ("rate", "increase"):
                         # rate/increase also need the raw values for the
                         # extrapolate-to-zero clamp (heuristic-only reference;
                         # delta never clamps, even when reset-corrected)
                         raw_vals = batch.vals
-                bt_ts, bt_counts = batch.ts, batch.counts
                 if B > 1:
-                    Pp_, S_ = bt_ts.shape
-                    mesh_vals = np.ascontiguousarray(
-                        mesh_vals.transpose(0, 2, 1)).reshape(Pp_ * B, S_)
+                    Pp_, S_ = ts_p.shape
+                    host_vals = np.ascontiguousarray(
+                        host_vals.transpose(0, 2, 1)).reshape(Pp_ * B, S_)
                     if raw_vals is not None:
                         raw_vals = np.ascontiguousarray(
                             raw_vals.transpose(0, 2, 1)).reshape(Pp_ * B, S_)
-                    bt_ts = np.repeat(bt_ts, B, axis=0)
-                    bt_counts = np.repeat(bt_counts, B)
-                    gids_full = (gids_full[:, None] * B + np.arange(
+                    ts_p = np.repeat(ts_p, B, axis=0)
+                    counts_p = np.repeat(counts_p, B)
+                    gid_p = (gid_p[:, None] * B + np.arange(
                         B, dtype=np.int32)[None, :]).reshape(-1)
-                ts_p, vals_p, valid, gid_p = pad_for_mesh(
-                    bt_ts, mesh_vals, bt_counts, gids_full, mesh)
-                raw_p = None
-                if raw_vals is not None:
-                    raw_p = np.zeros(vals_p.shape, vals_p.dtype)
-                    raw_p[: raw_vals.shape[0], : raw_vals.shape[1]] = \
-                        np.nan_to_num(raw_vals, nan=0.0)
+                # a scalar batch of the raw lane was built in the placed
+                # dtype with 0 padding: it is placed as it is
+                vals_p = host_vals if lane == "raw" and B == 1 \
+                    else _placed_values(host_vals)
+                raw_p = None if raw_vals is None \
+                    else _placed_values(raw_vals)
+                # counts do not shard along the time axis: a mask does
+                valid = np.arange(ts_p.shape[1])[None, :] < counts_p[:, None]
                 if sp is not None:
-                    sp.tags["shape"] = list(vals_p.shape)
+                    # the [P,S] arrays this phase made beside the mask:
+                    # what is placed and is not the builder's own array
+                    sp.tags.update(
+                        shape=list(vals_p.shape),
+                        copied_bytes=sum(
+                            a.nbytes for a in (ts_p, vals_p, raw_p)
+                            if a is not None and a is not batch.ts
+                            and a is not batch.vals))
             with span("mesh-place") as sp:
                 placed = shard_batch_arrays(mesh, ts_p, vals_p, valid,
                                             gid_p, raw_p)

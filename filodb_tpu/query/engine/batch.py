@@ -5,6 +5,15 @@ The bridge between the host-side chunk store and the TPU kernels. Decoding
 packed into padded arrays whose shapes are bucketed (next power of two) so XLA
 compilation caches are reused across queries.
 
+``build_batch`` materialises a batch ONCE: span ``batch-read`` covers the
+per-series ``read_samples`` loop, span ``batch-stack`` the one allocation of
+``ts``/``vals``/``counts`` at their final shape — the power of two, rounded
+up to the caller's mesh axes where those do not divide it — and the one
+write of every sample into them. A caller that places the batch on a mesh
+without a host f64 pass (``host_f64=False``) gets ``vals`` in the device's
+float dtype with 0 for padding: the very array that is placed, so no later
+step copies, converts or re-pads it.
+
 Timestamps are rebased to ``base_ts`` and stored as int32 milliseconds —
 queries spanning more than ~24 days are split by the planner (reference analog:
 time-split planning, ``SingleClusterPlanner.materializeTimeSplitPlan``).
@@ -32,6 +41,18 @@ def _next_pow2(n: int, floor: int = 8) -> int:
     return v
 
 
+def _round_up(n: int, multiple: int) -> int:
+    return -(-n // multiple) * multiple
+
+
+def device_float() -> np.dtype:
+    """The numpy twin of ``kernels.fdtype()``: the dtype a float array has
+    once it is on the device (f32 in a server, f64 under x64)."""
+    from filodb_tpu.query.engine.kernels import fdtype
+
+    return np.dtype(fdtype())
+
+
 @dataclass
 class SeriesBatch:
     """Padded batch of P series with up to S samples each.
@@ -42,7 +63,9 @@ class SeriesBatch:
 
     base_ts: int                      # epoch ms subtracted from all timestamps
     ts: np.ndarray                    # int32 [P, S], padded with TS_PAD
-    vals: np.ndarray                  # float [P, S] or [P, S, B]
+    # f64 [P, S] padded with NaN, or [P, S, B] padded with 0; a scalar batch
+    # built with host_f64=False: device_float() [P, S] padded with 0
+    vals: np.ndarray
     counts: np.ndarray                # int32 [P]
     part_ids: list[int]               # originating partition ids (host metadata)
     les: np.ndarray | None = None     # [B] bucket bounds for histogram batches
@@ -129,7 +152,9 @@ def build_batch(partitions: list[TimeSeriesPartition], start: int, end: int,
                 value_col: int | None = None, pad_series: bool = True,
                 pad_samples: bool = True,
                 extra_chunks: dict[int, list] | None = None,
-                extra_by_obj: dict[int, list] | None = None) -> SeriesBatch:
+                extra_by_obj: dict[int, list] | None = None,
+                mesh_multiples: tuple[int, int] = (1, 1),
+                host_f64: bool = True) -> SeriesBatch:
     """Decode chunks overlapping [start, end] into a SeriesBatch.
 
     ``start`` already includes the lookback/window extension; ``base_ts`` is
@@ -137,6 +162,13 @@ def build_batch(partitions: list[TimeSeriesPartition], start: int, end: int,
     ``extra_chunks`` maps part_id → ODP-paged chunks to merge (single-shard
     callers); ``extra_by_obj`` maps ``id(partition)`` → chunks for callers
     batching across shards, where part_ids are not unique.
+
+    ``mesh_multiples`` are the (series, sample) axis sizes of the mesh the
+    batch will be sharded over: the padded shape is rounded up to them, so
+    it is the placed shape. ``host_f64=False`` says no host f64 pass
+    (``delta_host``, the magnitude check) follows: scalar ``vals`` are then
+    allocated in :func:`device_float` with 0 for padding, ready to place.
+    Histogram batches keep f64 either way (the mesh flattens them first).
     """
     per_ts: list[np.ndarray] = []
     per_vals: list = []
@@ -162,14 +194,20 @@ def build_batch(partitions: list[TimeSeriesPartition], start: int, end: int,
     with span("batch-stack") as sp:
         P = len(partitions)
         maxS = max((len(t) for t in per_ts), default=0)
-        S = _next_pow2(maxS) if pad_samples else max(maxS, 1)
-        Pp = _next_pow2(P) if pad_series else max(P, 1)
+        S = _round_up(_next_pow2(maxS) if pad_samples else max(maxS, 1),
+                      mesh_multiples[1])
+        Pp = _round_up(_next_pow2(P) if pad_series else max(P, 1),
+                       mesh_multiples[0])
         ts_arr = np.full((Pp, S), TS_PAD, np.int32)
         if les is not None:
             B = len(les)
             vals_arr = np.zeros((Pp, S, B), np.float64)
-        else:
+        elif host_f64:
             vals_arr = np.full((Pp, S), np.nan, np.float64)
+        else:
+            # in-count samples are never NaN (filtered above), so 0 for
+            # padding is all the mesh kernels need beside the validity mask
+            vals_arr = np.zeros((Pp, S), device_float())
         counts = np.zeros(Pp, np.int32)
         for i, (t, v) in enumerate(zip(per_ts, per_vals)):
             n = len(t)

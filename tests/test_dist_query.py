@@ -11,11 +11,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh
+from mesh_oracle import pad_for_mesh
 
-from filodb_tpu.parallel.dist_query import (
-    make_distributed_sum_rate,
-    pad_for_mesh,
-)
+from filodb_tpu.parallel.dist_query import make_distributed_sum_rate
 from filodb_tpu.query.engine import kernels
 from filodb_tpu.query.engine.aggregations import aggregate
 from filodb_tpu.query.engine.batch import TS_PAD

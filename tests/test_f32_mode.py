@@ -9,6 +9,8 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
+from mesh_oracle import CASES, MESHES
 
 SCRIPT = r"""
 import os
@@ -177,3 +179,86 @@ def test_f32_counter_precision_rebased():
         # sanity: the rates are real (deltas ~10 per 10s => ~1/s per series)
         if key.endswith("_rate"):
             assert (np.abs(b[finite]) > 0.1).all()
+
+
+PLACED_SCRIPT = r"""
+import os, sys
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.pop("JAX_ENABLE_X64", None)
+import jax
+jax.config.update("jax_platforms", "cpu")
+assert not jax.config.jax_enable_x64
+
+import json
+import numpy as np
+sys.path.insert(0, os.path.join(os.getcwd(), "tests"))
+from mesh_oracle import CASES, MESHES, placed_mismatches, run_case
+
+stores, out = {}, {}
+for case in CASES:
+    for mesh_name in MESHES:
+        cap = run_case(case, mesh_name, stores)
+        ts, vals, raw = cap.got[0], cap.got[1], cap.got[4]
+        P_, S_ = cap.f64.ts.shape
+        out[f"{case}/{mesh_name}"] = {
+            "bad": placed_mismatches(cap),
+            "dtype": str(vals.dtype),
+            "batch_dtype": str(cap.batch.vals.dtype),
+            "own": ts is cap.batch.ts and vals is cap.batch.vals,
+            # numpy's rounding of the f64 samples, exactly
+            "exact": cap.f64.vals.ndim == 2 and bool(np.array_equal(
+                vals[:P_, :S_], np.asarray(
+                    np.nan_to_num(cap.f64.vals, nan=0.0), np.float32))),
+            "raw": raw is not None,
+            "copied_bytes": cap.tags["mesh-pad"]["copied_bytes"],
+            "vals_bytes": vals.nbytes,
+        }
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def placed_f32():
+    """The equivalence matrix of ``test_mesh_batch_once.py`` with x64 off,
+    as a server runs: every lane on every mesh, in ONE subprocess."""
+    env = dict(os.environ)
+    env.pop("JAX_ENABLE_X64", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run([sys.executable, "-c", PLACED_SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=600,
+                          cwd=os.path.dirname(os.path.dirname(
+                              os.path.abspath(__file__))))
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_f32_placed_arrays_are_the_parents_bits(case, placed_f32):
+    """With x64 off the parent's put rounded f64 to f32; the placed arrays
+    now ARE f32, and hold the same bits, on every mesh."""
+    for mesh_name in MESHES:
+        cell = placed_f32[f"{case}/{mesh_name}"]
+        assert cell["bad"] == [], mesh_name
+        assert cell["dtype"] == "float32", mesh_name
+
+
+@pytest.mark.parametrize("case", ["raw-avg", "raw-max-fused",
+                                  "raw-last-sample"])
+def test_f32_raw_lane_is_built_in_f32_and_placed_as_built(case, placed_f32):
+    for mesh_name in MESHES:
+        cell = placed_f32[f"{case}/{mesh_name}"]
+        assert cell["batch_dtype"] == "float32" and cell["own"], mesh_name
+        assert cell["exact"], mesh_name
+        assert cell["copied_bytes"] == 0 and not cell["raw"], mesh_name
+
+
+def test_f32_delta_lanes_keep_f64_and_copy_once(placed_f32):
+    for case in ("split-small", "split-big", "split-delta", "corrected",
+                 "rebased-gauge", "rebased-counter"):
+        cell = placed_f32[f"{case}/2x2"]
+        assert cell["batch_dtype"] == "float64" and not cell["own"], case
+        # the split lane at >= F32_SAFE_MAX falls back to the host f64
+        # pre-pass, which for increase places the raw values too
+        two = case in ("split-big", "corrected")
+        assert cell["raw"] == two, case
+        assert cell["copied_bytes"] == (2 if two else 1) * cell["vals_bytes"]
