@@ -136,6 +136,57 @@ class NativeShardCore:
                 flags.ctypes.data_as(i32p))
         return out, flags
 
+    def batch_count(self, pids: np.ndarray, col: int, t0: int, t1: int):
+        """One C call for the series ``pids`` (int32), value column ``col``
+        (index into the native columns: schema column - 1): how many samples
+        of [t0, t1] a batch keeps of each (in range, not NaN), over in-range
+        sealed chunks and the write buffer, under the shard's lock. Returns
+        (counts, chunks, flags), each int32 [len(pids)] — ``chunks`` the
+        sealed chunks in range, ``flags`` nonzero where the native reader
+        declines the series (see ``shard_batch_count`` in filodb_native.cpp)
+        — or None when the loaded .so predates the entry point."""
+        if not hasattr(self._lib, "shard_batch_count"):
+            return None
+        if pids.dtype != np.int32 or not pids.flags.c_contiguous:
+            raise ValueError("batch_count: pids must be contiguous int32")
+        n = len(pids)
+        counts, chunks, flags = (np.empty(n, np.int32) for _ in range(3))
+        i32p = ctypes.POINTER(ctypes.c_int32)
+        with self.lock:
+            self._lib.shard_batch_count(
+                self._core, pids.ctypes.data_as(i32p), n, col, t0, t1,
+                counts.ctypes.data_as(i32p), chunks.ctypes.data_as(i32p),
+                flags.ctypes.data_as(i32p))
+        return counts, chunks, flags
+
+    def batch_fill(self, pids: np.ndarray, col: int, t0: int, t1: int,
+                   rows: np.ndarray, caps: np.ndarray, ts: np.ndarray,
+                   vals: np.ndarray, counts: np.ndarray) -> None:
+        """The second call: decode the same series again and write the
+        i-th one's samples — ``ts - t0`` and the value — into row
+        ``rows[i]`` (< 0: skip) of the C-contiguous ``ts`` int32 [P, S] and
+        ``vals`` f32/f64 [P, S], at most ``caps[i]`` of them
+        (``batch_count``'s count), and how many into ``counts[rows[i]]``.
+        Padding is left as it is."""
+        n = len(pids)
+        if not (ts.dtype == np.int32 and ts.flags.c_contiguous
+                and vals.flags.c_contiguous and vals.shape == ts.shape
+                and vals.dtype in (np.float32, np.float64)
+                and pids.dtype == rows.dtype == caps.dtype == counts.dtype
+                == np.int32 and len(rows) == len(caps) == n
+                and len(counts) >= ts.shape[0]
+                and (n == 0 or (rows.max() < ts.shape[0]
+                                and caps.max() <= ts.shape[1]))):
+            raise ValueError("batch_fill: arrays do not fit the batch")
+        i32p = ctypes.POINTER(ctypes.c_int32)
+        with self.lock:
+            self._lib.shard_batch_fill(
+                self._core, pids.ctypes.data_as(i32p), n, col, t0,
+                t1, rows.ctypes.data_as(i32p), caps.ctypes.data_as(i32p),
+                ts.ctypes.data_as(i32p), ts.shape[1],
+                vals.ctypes.data_as(ctypes.c_void_p), vals.shape[1],
+                int(vals.dtype == np.float32), counts.ctypes.data_as(i32p))
+
     def lookup(self, key_blob: bytes) -> int:
         """pid for canonical key bytes, or -1 — the authoritative key map
         for restored shards (no host-language dictionary needed)."""

@@ -204,6 +204,17 @@ def _load():
                                            i64p, i64p, i32, i32, f64p,
                                            ctypes.POINTER(i32)]
             lib.shard_buf_fold.restype = i32
+        # batched series read (query/engine/batch.py:build_batch); absent
+        # on an older .so — NativeShardCore.batch_count hasattr-gates
+        if hasattr(lib, "shard_batch_count"):
+            p32 = ctypes.POINTER(i32)
+            lib.shard_batch_count.argtypes = [vp, p32, i32, i32, i64, i64,
+                                              p32, p32, p32]
+            lib.shard_batch_count.restype = i32
+            lib.shard_batch_fill.argtypes = [vp, p32, i32, i32, i64, i64,
+                                             p32, p32, p32, i64, vp, i64,
+                                             i32, p32]
+            lib.shard_batch_fill.restype = i32
         # TagIndex (native part-key inverted index hot paths)
         i32p = ctypes.POINTER(ctypes.c_int32)
         u32p = ctypes.POINTER(ctypes.c_uint32)

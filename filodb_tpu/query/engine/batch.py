@@ -5,14 +5,21 @@ The bridge between the host-side chunk store and the TPU kernels. Decoding
 packed into padded arrays whose shapes are bucketed (next power of two) so XLA
 compilation caches are reused across queries.
 
-``build_batch`` materialises a batch ONCE: span ``batch-read`` covers the
-per-series ``read_samples`` loop, span ``batch-stack`` the one allocation of
-``ts``/``vals``/``counts`` at their final shape — the power of two, rounded
-up to the caller's mesh axes where those do not divide it — and the one
-write of every sample into them. A caller that places the batch on a mesh
-without a host f64 pass (``host_f64=False``) gets ``vals`` in the device's
-float dtype with 0 for padding: the very array that is placed, so no later
-step copies, converts or re-pads it.
+``build_batch`` materialises a batch ONCE. Series that live in a native shard
+core (``NativeBackedPartition``) are read by the core, two C calls a shard:
+span ``batch-read`` covers grouping them by core, ``batch_count`` — the exact
+number of samples each row keeps — and a ``read_samples`` call for every
+other series (Python partitions, histogram columns, partitions with paged
+chunks, rows the core declines); span ``batch-stack`` the one allocation of
+``ts``/``vals``/``counts`` at their final shape — the power of two over the
+largest kept count, rounded up to the caller's mesh axes where those do not
+divide it — then ``batch_fill``, which decodes and writes the native rows
+straight into them, and the row writes of the others. Which way a row goes
+is read off the partition, never chosen by an option; the batch is the same
+bit for bit. A caller that places the batch on a mesh without a host f64
+pass (``host_f64=False``) gets ``vals`` in the device's float dtype with 0
+for padding: the very array that is placed, so no later step copies,
+converts or re-pads it.
 
 Timestamps are rebased to ``base_ts`` and stored as int32 milliseconds —
 queries spanning more than ~24 days are split by the planner (reference analog:
@@ -24,11 +31,17 @@ every in-count sample is valid.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import numpy as np
 
-from filodb_tpu.core.memstore.partition import TimeSeriesPartition
+from filodb_tpu.core.memstore.native_shard import NativeBackedPartition
+from filodb_tpu.core.memstore.partition import (
+    TimeSeriesPartition,
+    chunks_queried,
+)
 from filodb_tpu.memory.codecs import HistogramColumn
+from filodb_tpu.utils.metrics import BATCH_ROWS_FALLBACK, BATCH_ROWS_NATIVE
 from filodb_tpu.utils.tracing import span
 
 TS_PAD = np.iinfo(np.int32).max
@@ -51,6 +64,18 @@ def device_float() -> np.dtype:
     from filodb_tpu.query.engine.kernels import fdtype
 
     return np.dtype(fdtype())
+
+
+class _NativeRead(NamedTuple):
+    """What one ``NativeShardCore.batch_count`` said of one (core, column)'s
+    series, kept for the ``batch_fill`` that follows."""
+
+    core: object
+    col: int                # index into the core's columns
+    pids: np.ndarray        # int32 [n]
+    rows: np.ndarray        # int32 [n]: the batch row of each, -1 declined
+    kept: np.ndarray        # int32 [n]: samples each row keeps
+    chunks: np.ndarray      # int32 [n]: sealed chunks in range
 
 
 @dataclass
@@ -170,30 +195,90 @@ def build_batch(partitions: list[TimeSeriesPartition], start: int, end: int,
     allocated in :func:`device_float` with 0 for padding, ready to place.
     Histogram batches keep f64 either way (the mesh flattens them first).
     """
-    per_ts: list[np.ndarray] = []
-    per_vals: list = []
+    P = len(partitions)
+
+    def paged(p):
+        extra = extra_by_obj.get(id(p)) if extra_by_obj else None
+        if extra is None and extra_chunks:
+            extra = extra_chunks.get(p.part_id)
+        return extra
+
+    per_series: dict[int, tuple] = {}   # row -> (ts, vals) read one by one
+    reads: list[_NativeRead] = []       # one a native (core, column)
     les = None
-    with span("batch-read", partitions=len(partitions)):
-        for p in partitions:
-            extra = extra_by_obj.get(id(p)) if extra_by_obj else None
-            if extra is None and extra_chunks:
-                extra = extra_chunks.get(p.part_id)
-            ts, vals = p.read_samples(start, end, value_col,
-                                      extra_chunks=extra)
-            if isinstance(vals, HistogramColumn):
-                les = vals.les if les is None or len(vals.les) > len(les) \
-                    else les
-                rows = vals.rows.astype(np.float64)
-                per_ts.append(ts)
-                per_vals.append(rows)
-            else:
-                valid = ~np.isnan(vals)
-                per_ts.append(ts[valid])
-                per_vals.append(vals[valid])
+
+    def read_series(i):
+        nonlocal les
+        p = partitions[i]
+        ts, vals = p.read_samples(start, end, value_col,
+                                  extra_chunks=paged(p))
+        if isinstance(vals, HistogramColumn):
+            les = vals.les if les is None or len(vals.les) > len(les) \
+                else les
+            per_series[i] = ts, vals.rows.astype(np.float64)
+        else:
+            valid = ~np.isnan(vals)
+            per_series[i] = ts[valid], vals[valid]
+
+    with span("batch-read", partitions=P) as sp:
+        # series that live in a native shard core are read by the core, one
+        # call a (core, column); every other series one at a time
+        groups: dict[tuple, tuple] = {}
+        fallback: list[int] = []
+        any_paged = bool(extra_by_obj or extra_chunks)
+        g = None
+        for i, p in enumerate(partitions):
+            if not isinstance(p, NativeBackedPartition) \
+                    or (any_paged and paged(p)):
+                fallback.append(i)
+                continue
+            col = p.schema.data.value_column if value_col is None \
+                else value_col
+            core = p._core
+            if g is None or g[0] is not core or g[1] != col:
+                g = groups.get((id(core), col))
+                if g is None:
+                    g = groups[id(core), col] = (core, col, [], [])
+            g[2].append(i)
+            g[3].append(p.part_id)
+        for core, col, rows, pids in groups.values():
+            rows = np.asarray(rows, np.int32)
+            pids = np.asarray(pids, np.int32)
+            counted = core.batch_count(pids, col - 1, start, end)
+            if counted is None:     # a library without the entry point
+                fallback.extend(rows.tolist())
+                continue
+            kept, nchunks, flags = counted
+            if flags.any():         # declined: histogram, unsorted, ...
+                fallback.extend(rows[flags != 0].tolist())
+                rows = np.where(flags == 0, rows, -1).astype(np.int32)
+            reads.append(_NativeRead(core, col - 1, pids, rows, kept,
+                                     nchunks))
+        fallback.sort()
+        for i in fallback:
+            read_series(i)
+        if les is not None and reads:
+            # a histogram among scalar series makes the batch 3-D, which
+            # the native fill does not write: read those the old way too
+            again = sorted(i for r in reads for i in r.rows.tolist()
+                           if i >= 0)
+            reads = []
+            for i in again:
+                read_series(i)
+            fallback += again
+        for r in reads:
+            chunks_queried.inc(int(r.chunks[r.rows >= 0].sum()))
+        n_native = P - len(fallback)
+        if sp is not None:
+            sp.tags.update(native_rows=n_native,
+                           fallback_rows=len(fallback))
+        BATCH_ROWS_NATIVE.inc(n_native)
+        BATCH_ROWS_FALLBACK.inc(len(fallback))
 
     with span("batch-stack") as sp:
-        P = len(partitions)
-        maxS = max((len(t) for t in per_ts), default=0)
+        maxS = max([len(t) for t, _ in per_series.values()]
+                   + [int(r.kept.max(initial=0)) for r in reads],
+                   default=0)
         S = _round_up(_next_pow2(maxS) if pad_samples else max(maxS, 1),
                       mesh_multiples[1])
         Pp = _round_up(_next_pow2(P) if pad_series else max(P, 1),
@@ -209,7 +294,10 @@ def build_batch(partitions: list[TimeSeriesPartition], start: int, end: int,
             # padding is all the mesh kernels need beside the validity mask
             vals_arr = np.zeros((Pp, S), device_float())
         counts = np.zeros(Pp, np.int32)
-        for i, (t, v) in enumerate(zip(per_ts, per_vals)):
+        for r in reads:
+            r.core.batch_fill(r.pids, r.col, start, end, r.rows, r.kept,
+                              ts_arr, vals_arr, counts)
+        for i, (t, v) in per_series.items():
             n = len(t)
             counts[i] = n
             if n:

@@ -138,6 +138,14 @@ class _Timer:
 # dashboard watching this family catches a dead gauge the first scrape
 SCRAPE_ERRORS = Counter("filodb_metric_scrape_errors")
 
+# query/engine/batch.py:build_batch — series a batch build read through the
+# native shard core's one call a shard, and series it read one at a time
+_BATCH_ROWS_HELP = "series read into a query batch, by read path"
+BATCH_ROWS_NATIVE = Counter("filodb_batch_rows", {"path": "native"},
+                            help=_BATCH_ROWS_HELP)
+BATCH_ROWS_FALLBACK = Counter("filodb_batch_rows", {"path": "fallback"},
+                              help=_BATCH_ROWS_HELP)
+
 
 def get_counter(name: str, tags: dict[str, str] | None = None,
                 help: str | None = None) -> Counter:

@@ -362,6 +362,9 @@ namespace {
 struct NSealed {
     int64_t id, start, end;
     int32_t nrows;
+    // bit c: value column c holds a NaN (bit 31: some column >= 31 does);
+    // lets the batched read count a NaN-free column without decoding it
+    uint32_t nan_cols = 0;
     std::string ts_bytes;
     std::vector<std::string> col_bytes;
 };
@@ -549,6 +552,12 @@ void seal_part(ShardCore* c, int32_t pid, NPart& p) {
             encode_hist2d(c, *hs, n, s.col_bytes[i]);
         else
             encode_xor(c, p.cols[i].data(), n, s.col_bytes[i]);
+        for (double v : p.cols[i]) {
+            if (v != v) {
+                s.nan_cols |= 1u << (i < 31 ? i : 31);
+                break;
+            }
+        }
     }
     p.samples_sealed += n;
     p.sealed.push_back(std::move(s));
@@ -1767,6 +1776,205 @@ int32_t shard_buf_fold(void* cp, const int32_t* pids, int32_t npids,
             r[5] = fts; r[6] = fv; r[7] = lts; r[8] = lv;
             r[9] = resets; r[10] = corr; r[11] = changes;
         }
+    }
+    return 0;
+}
+
+
+// ---------------------------------------------------------------------------
+// batched series read (the batch build of the mesh engine and the exec tree)
+//
+// For each pid: the sealed chunks that overlap [t0, t1] decoded, in order,
+// then the write buffer, for value column `col` (index into NPart::cols); a
+// sample is kept iff t0 <= ts <= t1 and its value is not NaN — read_samples'
+// range mask and build_batch's staleness filter. shard_batch_count counts
+// what is kept, so the caller can size the batch exactly; shard_batch_fill
+// then writes `ts - t0` as int32 and the value as float or double straight
+// into the caller's rows. Each call runs under the shard's lock and sees one
+// state of every partition. Between the two a partition may have grown:
+// fill keeps at most the counted samples of a row, which are the same
+// samples, since a partition only grows at its end.
+//
+// A sealed chunk whose column holds no NaN (NSealed::nan_cols, noted when it
+// was sealed) is counted from its timestamps alone; its values are decoded
+// once, by the fill.
+
+}  // extern "C"
+
+namespace {
+
+struct BatchScratch {
+    std::vector<uint64_t> words;
+    std::vector<int64_t> ts;
+    std::vector<double> vals;
+    void reserve(size_t n) {
+        if (words.size() < n) {
+            words.resize(n);
+            ts.resize(n);
+            vals.resize(n);
+        }
+    }
+};
+
+struct CountSink {
+    static constexpr bool fills = false;
+    int64_t n = 0;
+    inline void put(int64_t, double) { n++; }
+};
+
+template <class F>
+struct FillSink {
+    static constexpr bool fills = true;
+    int32_t* ts;
+    F* vals;
+    int64_t cap;
+    int64_t n = 0;
+    inline void put(int64_t dt, double v) {
+        if (n < cap) {
+            ts[n] = (int32_t)dt;
+            vals[n] = (F)v;
+            n++;
+        }
+    }
+};
+
+// flag bits a row may carry (nonzero: the caller reads the series the
+// per-series way)
+constexpr int32_t BR_DEAD = 1;      // unknown or freed pid
+constexpr int32_t BR_COLUMN = 2;    // no such column, or one out of step
+constexpr int32_t BR_HIST = 4;      // histogram column
+constexpr int32_t BR_UNSORTED = 8;  // timestamps not strictly increasing
+                                    // across chunks + buffer (read_samples
+                                    // sorts)
+constexpr int32_t BR_CODEC = 16;    // a vector this reader does not decode
+                                    // (not delta-delta / XOR-double)
+
+template <class Sink>
+int32_t batch_read_series(const ShardCore* c, int32_t pid, int32_t col,
+                          int64_t t0, int64_t t1, BatchScratch& sc,
+                          Sink& out, int32_t* nchunks) {
+    *nchunks = 0;
+    if (pid < 0 || (size_t)pid >= c->parts.size() || !c->parts[pid].alive)
+        return BR_DEAD;
+    const NPart& p = c->parts[pid];
+    if (col < 0 || (size_t)col >= p.cols.size()
+            || p.cols[col].size() != p.ts.size())
+        return BR_COLUMN;
+    if (p.hist_col == col) return BR_HIST;
+    int32_t flags = 0;
+    int64_t prev = 0;
+    bool have_prev = false;
+    auto scan = [&](const int64_t* ts, const double* vals, int64_t n) {
+        for (int64_t k = 0; k < n; k++) {
+            int64_t t = ts[k];
+            if (t < t0 || t > t1) continue;
+            if (have_prev && t <= prev) flags |= BR_UNSORTED;
+            prev = t;
+            have_prev = true;
+            double v = vals ? vals[k] : 0.0;
+            if (v != v) continue;
+            out.put(t - t0, v);
+        }
+    };
+    for (const NSealed& s : p.sealed) {
+        if (s.end < t0 || s.start > t1) continue;
+        (*nchunks)++;
+        if ((size_t)col >= s.col_bytes.size()) return BR_CODEC;
+        const uint8_t* tb = (const uint8_t*)s.ts_bytes.data();
+        const uint8_t* vb = (const uint8_t*)s.col_bytes[col].data();
+        int64_t tlen = (int64_t)s.ts_bytes.size();
+        int64_t vlen = (int64_t)s.col_bytes[col].size();
+        int64_t n = s.nrows;
+        // ts: u8 codec (1 | 2 const) | u32 n | i64 base | i64 slope
+        //     [| nibble_pack(zigzag(residuals))]
+        // values: u8 codec 3 | u32 n | nibble_pack(xor-prep)
+        if (tlen < 21 || (tb[0] != 1 && tb[0] != 2)
+                || (int64_t)rd_u32(tb + 1) != n || vlen < 5 || vb[0] != 3
+                || (int64_t)rd_u32(vb + 1) != n)
+            return BR_CODEC;
+        sc.reserve((size_t)n);
+        int64_t base = rd_i64(tb + 5), slope = rd_i64(tb + 13);
+        int64_t* ts = sc.ts.data();
+        if (tb[0] == 1) {
+            if (nibble_unpack(tb + 21, tlen - 21, sc.words.data(), n) < 0)
+                return BR_CODEC;
+            zigzag_decode_u64(sc.words.data(), ts, n);
+            delta_delta_reconstruct(ts, n, base, slope, ts);
+        } else {
+            for (int64_t k = 0; k < n; k++) ts[k] = base + slope * k;
+        }
+        const double* vals = nullptr;
+        if (Sink::fills || ((s.nan_cols >> (col < 31 ? col : 31)) & 1)) {
+            if (nibble_unpack(vb + 5, vlen - 5, sc.words.data(), n) < 0)
+                return BR_CODEC;
+            xor_decode_f64(sc.words.data(), sc.vals.data(), n);
+            vals = sc.vals.data();
+        }
+        scan(ts, vals, n);
+    }
+    scan(p.ts.data(), p.cols[col].data(), (int64_t)p.ts.size());
+    return flags;
+}
+
+// one row of a fill: at most `cap` samples of `pid` into ts / vals
+template <class F>
+int32_t batch_fill_row(const ShardCore* c, int32_t pid, int32_t col,
+                       int64_t t0, int64_t t1, BatchScratch& sc, int32_t* ts,
+                       F* vals, int32_t cap) {
+    FillSink<F> sink{ts, vals, cap};
+    int32_t nchunks;
+    batch_read_series(c, pid, col, t0, t1, sc, sink, &nchunks);
+    return (int32_t)sink.n;
+}
+
+}  // namespace
+
+extern "C" {
+
+// counts_out[i]: samples kept for pids[i]; chunks_out[i]: sealed chunks that
+// overlap the range (what read_samples adds to memstore_chunks_queried);
+// flags_out[i]: 0, or BR_* bits — the row is then not to be trusted (its
+// count reads 0) and the caller reads that series the per-series way.
+int32_t shard_batch_count(void* cp, const int32_t* pids, int32_t npids,
+                          int32_t col, int64_t t0, int64_t t1,
+                          int32_t* counts_out, int32_t* chunks_out,
+                          int32_t* flags_out) {
+    const ShardCore* c = static_cast<ShardCore*>(cp);
+    BatchScratch sc;
+    for (int32_t i = 0; i < npids; i++) {
+        CountSink sink;
+        flags_out[i] = batch_read_series(c, pids[i], col, t0, t1, sc, sink,
+                                         &chunks_out[i]);
+        counts_out[i] = flags_out[i] ? 0 : (int32_t)sink.n;
+    }
+    return 0;
+}
+
+// Writes the samples of pids[i] into row row_of[i] (< 0: skipped) of ts_out
+// and vals_out, whose rows are ts_stride / vals_stride elements apart:
+// at most caps[i] of them (the count shard_batch_count gave, which the row
+// must hold), and how many into counts_out[row]. vals_out is float where
+// vals_f32, else double. What lies beyond a row's count is the caller's
+// padding, untouched.
+int32_t shard_batch_fill(void* cp, const int32_t* pids, int32_t npids,
+                         int32_t col, int64_t t0, int64_t t1,
+                         const int32_t* row_of, const int32_t* caps,
+                         int32_t* ts_out, int64_t ts_stride, void* vals_out,
+                         int64_t vals_stride, int32_t vals_f32,
+                         int32_t* counts_out) {
+    const ShardCore* c = static_cast<ShardCore*>(cp);
+    BatchScratch sc;
+    for (int32_t i = 0; i < npids; i++) {
+        int64_t row = row_of[i];
+        if (row < 0) continue;
+        int32_t* ts = ts_out + row * ts_stride;
+        counts_out[row] = vals_f32
+            ? batch_fill_row(c, pids[i], col, t0, t1, sc, ts,
+                             static_cast<float*>(vals_out)
+                             + row * vals_stride, caps[i])
+            : batch_fill_row(c, pids[i], col, t0, t1, sc, ts,
+                             static_cast<double*>(vals_out)
+                             + row * vals_stride, caps[i]);
     }
     return 0;
 }

@@ -10,6 +10,7 @@ import sys
 
 import numpy as np
 import pytest
+from batch_oracle import RANGES
 from mesh_oracle import CASES, MESHES
 
 SCRIPT = r"""
@@ -213,6 +214,31 @@ for case in CASES:
             "copied_bytes": cap.tags["mesh-pad"]["copied_bytes"],
             "vals_bytes": vals.nbytes,
         }
+
+# the batch build itself: rows filled by the native shard cores against the
+# per-series builder, in the dtype a server places (f32 here)
+from batch_oracle import RANGES, World, mismatches, per_series_batch
+from filodb_tpu.core.memstore.native_shard import native_available
+from filodb_tpu.query.engine.batch import build_batch
+
+world = World() if native_available() else None
+for rng, (lo, hi) in RANGES.items() if world else ():
+    with np.errstate(over="ignore"):
+        got = build_batch(world.parts, lo, hi, host_f64=False,
+                          mesh_multiples=(4, 2))
+        want = per_series_batch(world.parts, lo, hi, host_f64=False,
+                                mesh_multiples=(4, 2))
+        f64 = per_series_batch(world.parts, lo, hi, mesh_multiples=(4, 2))
+        out[f"native-fill/{rng}"] = {
+            "bad": mismatches(got, want)
+            + mismatches(build_batch(world.parts, lo, hi,
+                                     mesh_multiples=(4, 2)), f64),
+            "dtype": str(got.vals.dtype),
+            # numpy's rounding of the f64 samples, exactly
+            "exact": got.vals.tobytes() == np.asarray(
+                np.nan_to_num(f64.vals, nan=0.0), np.float32).tobytes(),
+            "samples": int(got.counts.sum()),
+        }
 print(json.dumps(out))
 """
 
@@ -262,3 +288,16 @@ def test_f32_delta_lanes_keep_f64_and_copy_once(placed_f32):
         two = case in ("split-big", "corrected")
         assert cell["raw"] == two, case
         assert cell["copied_bytes"] == (2 if two else 1) * cell["vals_bytes"]
+
+
+@pytest.mark.parametrize("rng", RANGES)
+def test_f32_native_fill_is_the_per_series_builders_bits(rng, placed_f32):
+    """x64 off: ``build_batch`` allocates f32 and the native fill narrows in
+    C — the same bits as numpy's f64 -> f32 row assignment, and the f64
+    batch (``host_f64``) stays the per-series builder's too."""
+    if f"native-fill/{rng}" not in placed_f32:
+        pytest.skip("native library unavailable")
+    cell = placed_f32[f"native-fill/{rng}"]
+    assert cell["bad"] == []
+    assert cell["dtype"] == "float32" and cell["exact"]
+    assert (cell["samples"] > 0) == (rng not in ("before", "after"))

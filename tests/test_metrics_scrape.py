@@ -341,6 +341,14 @@ COSTMODEL_NAMES = [
 ]
 
 
+# the batch build (query/engine/batch.py): series read through a native
+# shard core's one call a shard against series read one at a time — one
+# family, both labels registered at utils/metrics import
+BATCH_NAMES = [
+    "filodb_batch_rows_total",
+]
+
+
 def _free_port():
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
@@ -500,6 +508,10 @@ class TestMetricsScrape:
         missing_cm = [n for n in COSTMODEL_NAMES if n not in names_present]
         assert not missing_cm, f"missing costmodel metrics: {missing_cm}"
 
+        # the batch build's read-path counter pair renders from import
+        missing_b = [n for n in BATCH_NAMES if n not in names_present]
+        assert not missing_b, f"missing batch metrics: {missing_b}"
+
         # shard-replication + hedged-read families render at zero before
         # any replica set is configured
         missing_rep = [n for n in REPLICATION_NAMES
@@ -529,6 +541,51 @@ class TestMetricsScrape:
         # ingest actually counted
         total = sum(float(t.rsplit(" ", 1)[1]) for t in tagged)
         assert total >= 150
+
+    @pytest.mark.parametrize("path", ["native", "fallback"])
+    def test_batch_rows_family_is_scraped_with_both_paths(self, server,
+                                                          path):
+        """``filodb_batch_rows_total{path=...}`` renders before any query,
+        under one HELP/TYPE header, and a mesh query moves it by the series
+        of its batch: on the native path where the shards are native."""
+        from filodb_tpu.core.memstore.native_shard import native_available
+
+        srv = server
+        before = _scrape(srv.http.port)
+        line = f'filodb_batch_rows_total{{path="{path}"}}'
+        assert [n for n in BATCH_NAMES
+                if f"# TYPE {n} counter" not in before] == []
+        assert before.count("# TYPE filodb_batch_rows_total counter") == 1
+        assert any(ln.startswith(line) for ln in before.splitlines())
+
+        def value(text):
+            (ln,) = [ln for ln in text.splitlines() if ln.startswith(line)]
+            return float(ln.rsplit(" ", 1)[1])
+
+        with socket.create_connection(("127.0.0.1",
+                                       srv.gateway.port)) as s:
+            for i in range(150):
+                ts_ns = (START + i * 10) * 1_000_000_000
+                s.sendall(f"batch_metric,host=h{i % 5},_ws_=demo,"
+                          f"_ns_=App-0 value={i} {ts_ns}\n".encode())
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline:
+            srv.gateway.sink.flush()
+            if sum(s2.stats.rows_ingested.value for s2 in
+                   srv.memstore.shards_for("timeseries")) >= 150:
+                break
+            time.sleep(0.3)
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{srv.http.port}/promql/timeseries/api/v1/"
+                f"query_range?query=sum(rate(batch_metric%5B1m%5D))"
+                f"&start={START}&end={START + 1500}&step=60") as r:
+            assert r.status == 200
+        moved = value(_scrape(srv.http.port)) - value(before)
+        engaged = "native" if native_available() else "fallback"
+        if path == engaged:     # five series a batch, a batch an extent
+            assert moved >= 5 and moved % 5 == 0
+        else:
+            assert moved == 0
 
     def test_flush_and_query_counters_move(self, server):
         srv = server

@@ -245,6 +245,53 @@ class TestMeshPhases:
         assert [k["name"] for k in kids] == list(POST)
         assert kids[0]["tags"]["eval_cache"] == "hit"
 
+    @pytest.mark.parametrize("native", [True, False],
+                             ids=["native-shards", "python-shards"])
+    def test_batch_read_and_stack_once_a_build_and_they_tile_decode(
+            self, store, native):
+        """``mesh_decode_ms`` and ``mesh_stack_ms`` read these two: each
+        opens once a build, under ``decode``, whichever way the rows are
+        read, and ``batch-read`` says how many went which way."""
+        if native:
+            from filodb_tpu.core.memstore.native_shard import native_available
+            if not native_available():
+                pytest.skip("native library unavailable")
+            ms = store
+        else:
+            ms = TimeSeriesMemStore()
+            for s in range(NUM_SHARDS):
+                ms.setup("timeseries", s, StoreConfig(
+                    max_chunk_size=100, groups_per_shard=4,
+                    native_ingest=False))
+            ingest_routed(ms, "timeseries", gauge_stream(
+                machine_metrics_series(240, metric="gauge_metric"), 240,
+                start_ms=START * 1000, interval_ms=10_000, seed=3),
+                NUM_SHARDS, spread=1)
+        svc = mesh_service(ms)
+        svc.query_range(PROMQL, START + 600, 60, START + 1800)  # compiles
+        tiled = 0.0
+        for shift in (30, 45, 15):
+            tracing.flight_recorder().clear()
+            svc.query_range(PROMQL, START + 600 + shift, 60,
+                            START + 1800 + shift)
+            (e,) = entries("query")
+            spans = e["spans"]
+            (dec,) = [s for s in spans if s["name"] == "decode"]
+            read, stack = children(spans, dec)
+            assert (read["name"], stack["name"]) == ("batch-read",
+                                                     "batch-stack")
+            assert [s["name"] for s in spans].count("batch-read") == 1
+            assert [s["name"] for s in spans].count("batch-stack") == 1
+            rows = (read["tags"]["native_rows"], read["tags"]["fallback_rows"])
+            assert rows == ((240, 0) if native else (0, 240))
+            assert sum(rows) == read["tags"]["partitions"] == 240
+            assert stack["tags"]["shape"] == dec["tags"]["shape"]
+            tiled = max(tiled, (read["duration_ms"] + stack["duration_ms"])
+                        / dec["duration_ms"])
+            if tiled >= 0.9:
+                break
+        assert tiled >= 0.9
+
     def test_build_batch_without_a_trace_allocates_no_span(
             self, store, monkeypatch):
         parts = [p for sh in store.shards_for("timeseries")
