@@ -96,9 +96,6 @@ def build_service(engine: str | None = None):
                                               groups_per_shard=8))
     n = ingest_routed(ms, "timeseries", stream, NUM_SHARDS, spread=1)
     assert n == NUM_SERIES * NUM_SAMPLES, n
-    # adaptive two-lane engine (parallel/adaptive.py): device mesh for
-    # batch/scan-heavy work, host lane for sync-floor-bound small queries,
-    # cost-routed — the TPU-native serving posture behind any link
     return QueryService(ms, "timeseries", NUM_SHARDS, spread=1,
                         engine=engine), keys
 
@@ -206,43 +203,31 @@ def run_queries_sustained(svc, start_sec, end_sec, threads=4, batch=25,
 
 
 def measure_big_scan():
-    """End-to-end lane comparison at scan-heavy scale (~9M samples per
-    query): the complement of the small-scan workload, where the per-query
-    sync floor dominates."""
+    """The mesh engine at scan-heavy scale (~9M samples per query): the
+    complement of the small-scan workload, where the per-query sync floor
+    dominates."""
     from filodb_tpu.promql.parser import TimeStepParams
 
-    svc = build_big_service("adaptive")
+    svc = build_big_service("mesh")
     start_sec = START_SEC + 3600
     end_sec = start_sec + BIG_RANGE_SEC
-    eng = svc.mesh_engine
-    out = {"engine": "adaptive",  # explicit: this IS the lane comparison
+    engine = svc.mesh_engine
+    out = {"engine": "mesh",
            "series": BIG_SERIES,
            "samples_per_query_approx":
                BIG_SERIES * (BIG_RANGE_SEC + 600) // 10}
-    plan = svc._parse_cached(BIG_QUERY, TimeStepParams(
-        start_sec, QUERY_STEP_SEC, end_sec))
-    host = eng._host()
-    lanes = {"device": eng.device_engine}
-    if host is not None:
-        lanes["host"] = host
-    for lane_name, engine in lanes.items():
-        lows = [engine._lower(plan)]
-        if lows[0] is None:
-            continue
-        for _ in range(2):  # warm: compile + batch build + upload
-            engine.execute_lowered_many(lows, svc.memstore,
-                                        "timeseries")[0].materialize()
-        iters = 5
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            engine.execute_lowered_many(lows, svc.memstore,
-                                        "timeseries")[0].materialize()
-        out[f"{lane_name}_lane_ms_per_query"] = round(
-            (time.perf_counter() - t0) / iters * 1e3, 1)
-    d = out.get("device_lane_ms_per_query")
-    h = out.get("host_lane_ms_per_query")
-    if d and h:
-        out["device_speedup_end_to_end"] = round(h / d, 2)
+    lows = [engine._lower(svc._parse_cached(BIG_QUERY, TimeStepParams(
+        start_sec, QUERY_STEP_SEC, end_sec)))]
+    for _ in range(2):  # warm: compile + batch build + upload
+        engine.execute_lowered_many(lows, svc.memstore,
+                                    "timeseries")[0].materialize()
+    iters = 5
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        engine.execute_lowered_many(lows, svc.memstore,
+                                    "timeseries")[0].materialize()
+    out["ms_per_query"] = round(
+        (time.perf_counter() - t0) / iters * 1e3, 1)
     return out
 
 
@@ -396,21 +381,8 @@ def main():
     qps = max(seq_qps, conc_qps, sustained_qps)
     baseline = naive_baseline_qps(svc, start_sec, end_sec)
 
-    # device-timed breakdown: where a single query's latency goes, so the
-    # sync-floor-bound sequential number is attributable — floor (one
-    # blocking host↔device round trip), pure
-    # device kernel time (microbench), and the device lane's end-to-end
-    # per-query cost as routed by the adaptive engine
-    eng = svc.mesh_engine
-    breakdown = {}
-    if getattr(eng, "sync_floor_s", None) is not None:
-        breakdown["sync_floor_ms"] = round(eng.sync_floor_s * 1e3, 2)
-    breakdown["device_kernel_ms"] = micro.get("fused_decode_rate_sum_ms")
-    if hasattr(eng, "_cost"):
-        breakdown["lane_costs_ms_per_query"] = {
-            f"{lane}_bs{b}": round(c.est * 1e3, 2)
-            for (lane, b), c in eng._cost.items() if c.est is not None}
-        breakdown["routed"] = dict(eng.routed)
+    # pure device kernel time (microbench) beside the end-to-end numbers
+    breakdown = {"device_kernel_ms": micro.get("fused_decode_rate_sum_ms")}
 
     big = measure_big_scan()
     sys.stderr.write(f"big scan: {json.dumps(big)}\n")

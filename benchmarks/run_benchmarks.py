@@ -92,7 +92,7 @@ def bench_query_hicard():
     keys = counter_series(5000, metric="hicard_total")
     for sd in counter_stream(keys, 60, start_ms=START * 1000, batch=5000):
         shard.ingest(sd)
-    svc = QueryService(ms, "bench", 1, spread=0, engine="adaptive")
+    svc = QueryService(ms, "bench", 1, spread=0, engine="mesh")
     q = 'sum(rate(hicard_total[5m]))'
     svc.query_range(q, START + 300, 60, START + 540)  # warm
     n = 20
@@ -117,7 +117,7 @@ def bench_query_and_ingest():
     keys = counter_series(100, metric="qi_total")
     for sd in counter_stream(keys, 720, start_ms=START * 1000):
         shard.ingest(sd)
-    svc = QueryService(ms, "bench", 1, spread=0, engine="adaptive")
+    svc = QueryService(ms, "bench", 1, spread=0, engine="mesh")
     q = 'sum(rate(qi_total[5m]))'
     svc.query_range(q, START + 3600, 60, START + 5400)
     stop = threading.Event()
@@ -220,7 +220,7 @@ def bench_hist_query():
     keys = histogram_series(20)
     for sd in histogram_stream(keys, 720, start_ms=START * 1000, batch=2000):
         shard.ingest(sd)
-    svc = QueryService(ms, "bench", 1, spread=0, engine="adaptive")
+    svc = QueryService(ms, "bench", 1, spread=0, engine="mesh")
     q = 'histogram_quantile(0.99, sum(rate(http_req_latency[5m])))'
     svc.query_range(q, START + 3600, 60, START + 5400)
     n = 30
@@ -517,20 +517,14 @@ def _bench_pyramid_topk_1m():
 
 def _bench_adaptive():
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from adaptive import bench_adaptive
-    return bench_adaptive()
+    import adaptive
+    return adaptive.bench_adaptive()
 
 
 def _bench_multiproc_mesh():
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     from multiproc_mesh import run_sweep
     return run_sweep()
-
-
-def _bench_mesh_scaling(devices=None):
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from mesh_scaling import DEFAULT_DEVICES, run_sweep
-    return run_sweep(tuple(devices) if devices else DEFAULT_DEVICES)
 
 
 ALL = {
@@ -559,7 +553,6 @@ ALL = {
     "federation_yearscan": _bench_federation_yearscan,
     "pyramid_topk_1m": _bench_pyramid_topk_1m,
     "adaptive": _bench_adaptive,
-    "mesh_scaling": _bench_mesh_scaling,
     "multiproc_mesh": _bench_multiproc_mesh,
 }
 
@@ -567,17 +560,7 @@ ALL = {
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
-    ap.add_argument("--devices", default=None,
-                    help="comma-separated mesh widths; runs ONLY the "
-                         "mesh_scaling sweep at those sizes, in this "
-                         "process over jax.devices()[:n]")
     args = ap.parse_args(argv)
-    if args.devices:
-        widths = [int(x) for x in args.devices.split(",") if x.strip()]
-        out = _bench_mesh_scaling(widths)
-        out["benchmark"] = "mesh_scaling"
-        print(json.dumps(out), flush=True)
-        return
     for name, fn in ALL.items():
         if args.only and name != args.only:
             continue

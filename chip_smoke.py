@@ -19,7 +19,8 @@ plain float64 evaluation of the same PromQL written here:
    (4 shards, spread 1, 400-sample chunks).
 4. queries      — through ``QueryService.query_range`` with the default
    configuration (engine ``mesh``, result cache on): the split
-   prepare/bounds/eval/reduce pipeline, the fused form, a post-aggregation
+   prepare/bounds/eval/reduce pipeline, the masked scan of a window max
+   (dispatch form ``fused``), a post-aggregation
    on the mesh output, and a binary join the mesh does not lower (exec tree).
 5. proof        — where the batch lives, bytes in use, mesh hits and fallback
    counters, compile and first/warm seconds. Reported, not judged.

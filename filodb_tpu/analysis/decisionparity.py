@@ -16,8 +16,7 @@ a cost model must, in the same function, do one of:
 - call ``.defer(...)`` (carrier hand-off; settled later by
   ``settle_deferred`` at the timing boundary, e.g. the sidecar gate);
 - ``return`` the name the decision was bound to (explicit hand-off to
-  the caller, which then owns the settle — e.g. the lane router's
-  ``_shared_decision``).
+  the caller, which then owns the settle).
 
 Static approximations: receiver types are not resolved — any
 ``.decide``/``.classify`` attribute call counts, which is fine in this

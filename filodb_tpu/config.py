@@ -290,6 +290,12 @@ class ServerConfig:
                 downsample=d.get("downsample"))
             spreads[name] = d.get("spread", 1)
             engines[name] = d.get("engine", "mesh")
+            if engines[name] not in ("mesh", "exec"):
+                raise ValueError(
+                    f"dataset {name!r}: engine {engines[name]!r} is not one "
+                    "of 'mesh' (the device mesh, with the exec tree for "
+                    "plans it does not lower) and 'exec' (the exec tree "
+                    "alone)")
         return ServerConfig(
             node_name=cfg["node_name"], data_dir=cfg["data_dir"],
             wal_dir=cfg.get("wal_dir"),

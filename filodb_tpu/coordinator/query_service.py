@@ -111,9 +111,8 @@ class QueryService:
     lookback_ms: int = 300_000
     # "exec" = scatter-gather exec-plan tree (the reference's distribution);
     # "mesh" = lower supported agg(range_fn(sel[w])) by (...) plans onto the
-    # (shard × time) device mesh, falling back to exec for everything else;
-    # "adaptive" = mesh plus a host lane, cost-routed per batch size
-    # (parallel/adaptive.py) — the default serving posture
+    # (shard × time) device mesh, falling back to exec for everything else
+    # (what a server's config defaults to)
     engine: str = "exec"
     mesh: object = None  # jax Mesh override for engine="mesh"
     # per-query deadline; every socket/HTTP timeout on the distributed
@@ -121,7 +120,7 @@ class QueryService:
     query_timeout_s: float | None = None
     # extent result cache (filodb_tpu.query.result_cache): a config dict /
     # ResultCacheConfig / ResultCache / True enables it; None or False
-    # disables. Sits in front of exec, mesh, and adaptive engines alike.
+    # disables. Sits in front of both engines alike.
     result_cache: object = None
     # callable () -> [(shard, status_str)] for queryable-but-not-ACTIVE
     # shards (recovery/handoff); results touching them carry a warning so
@@ -153,10 +152,6 @@ class QueryService:
         if self.engine == "mesh":
             from filodb_tpu.parallel.mesh_engine import MeshQueryEngine
             self.mesh_engine = MeshQueryEngine(mesh=self.mesh, sidecars=True)
-        elif self.engine == "adaptive":
-            from filodb_tpu.parallel.adaptive import AdaptiveQueryEngine
-            self.mesh_engine = AdaptiveQueryEngine(mesh=self.mesh,
-                                                   sidecars=True)
 
     # ---- promql entry points --------------------------------------------
 

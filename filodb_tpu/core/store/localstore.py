@@ -50,6 +50,7 @@ class _Db:
     def __init__(self, root: str):
         self.root = root
         self._conns: dict[tuple[str, int], sqlite3.Connection] = {}
+        self._read_locks: dict[tuple[str, int], threading.Lock] = {}
         self._lock = threading.Lock()
 
     def conn(self, dataset: str, shard: int) -> sqlite3.Connection:
@@ -103,14 +104,29 @@ class _Db:
                                   "INTEGER DEFAULT 0")
                     except sqlite3.OperationalError:
                         pass  # column already present
+                self._read_locks[key] = threading.Lock()
                 self._conns[key] = c
             return c
+
+    def read(self, dataset: str, shard: int, sql: str, params=()) -> list:
+        """Every row of one SELECT. The connection is shared by all the
+        threads of the process (the chunk server answers each client on a
+        thread of its own), and sqlite3 keeps ONE prepared statement for
+        each SQL text on a connection: two threads that run the same
+        SELECT step that one statement at once and get each other's rows,
+        or none. So a read holds the connection's read lock from execute
+        to the last row. Writers run other statements, under the store's
+        write lock."""
+        c = self.conn(dataset, shard)
+        with self._read_locks[(dataset, shard)]:
+            return c.execute(sql, params).fetchall()
 
     def close(self):
         with self._lock:
             for c in self._conns.values():
                 c.close()
             self._conns.clear()
+            self._read_locks.clear()
 
 
 class LocalDiskColumnStore(ColumnStore):
@@ -158,11 +174,11 @@ class LocalDiskColumnStore(ColumnStore):
             c.commit()
 
     def read_chunks(self, dataset, shard, part_key, start_time, end_time):
-        c = self._db.conn(dataset, shard)
-        rows = c.execute(
+        rows = self._db.read(
+            dataset, shard,
             "SELECT data FROM chunks WHERE partition=? AND end_time>=? AND "
             "start_time<=? ORDER BY chunkid", (_pk_blob(part_key), start_time,
-                                               end_time)).fetchall()
+                                               end_time))
         return [Chunk.deserialize(r[0]) for r in rows]
 
     def write_part_keys(self, dataset, shard, records):
@@ -180,27 +196,29 @@ class LocalDiskColumnStore(ColumnStore):
             c.commit()
 
     def scan_part_keys(self, dataset, shard):
-        c = self._db.conn(dataset, shard)
-        rows = c.execute(
-            "SELECT partition, start_time, end_time FROM partkeys").fetchall()
+        rows = self._db.read(
+            dataset, shard,
+            "SELECT partition, start_time, end_time FROM partkeys")
         return [PartKeyRecord(_pk_from_blob(b), st, et) for b, st, et in rows]
 
     def scan_chunks_by_ingestion_time(self, dataset, shard, start, end):
-        c = self._db.conn(dataset, shard)
-        parts = c.execute(
+        parts = self._db.read(
+            dataset, shard,
             "SELECT DISTINCT partition FROM ingestion_time_index WHERE "
-            "ingestion_time>=? AND ingestion_time<?", (start, end)).fetchall()
+            "ingestion_time>=? AND ingestion_time<?", (start, end))
         for (blob,) in parts:
-            ids = [r[0] for r in c.execute(
+            ids = [r[0] for r in self._db.read(
+                dataset, shard,
                 "SELECT chunkid FROM ingestion_time_index WHERE partition=? "
                 "AND ingestion_time>=? AND ingestion_time<?",
                 (blob, start, end))]
             if not ids:
                 continue
             q = ",".join("?" * len(ids))
-            rows = c.execute(
+            rows = self._db.read(
+                dataset, shard,
                 f"SELECT data FROM chunks WHERE partition=? AND chunkid IN "
-                f"({q}) ORDER BY chunkid", (blob, *ids)).fetchall()
+                f"({q}) ORDER BY chunkid", (blob, *ids))
             yield _pk_from_blob(blob), [Chunk.deserialize(r[0]) for r in rows]
 
     def truncate(self, dataset):
@@ -222,24 +240,23 @@ class LocalDiskColumnStore(ColumnStore):
             c.commit()
 
     def max_persisted_ts(self, dataset, shard):
-        c = self._db.conn(dataset, shard)
-        rows = c.execute(
-            "SELECT partition, MAX(end_time) FROM chunks GROUP BY partition"
-        ).fetchall()
+        rows = self._db.read(
+            dataset, shard,
+            "SELECT partition, MAX(end_time) FROM chunks GROUP BY partition")
         return {_pk_from_blob(b): int(mx) for b, mx in rows}
 
     def max_persisted_ts_since(self, dataset, shard, chunk_token):
-        c = self._db.conn(dataset, shard)
-        rows = c.execute(
+        rows = self._db.read(
+            dataset, shard,
             "SELECT partition, MAX(end_time) FROM chunks WHERE upd > ? "
-            "GROUP BY partition", (chunk_token,)).fetchall()
+            "GROUP BY partition", (chunk_token,))
         return {_pk_from_blob(b): int(mx) for b, mx in rows}
 
     def scan_part_keys_since(self, dataset, shard, pk_token):
-        c = self._db.conn(dataset, shard)
-        rows = c.execute(
+        rows = self._db.read(
+            dataset, shard,
             "SELECT partition, start_time, end_time FROM partkeys "
-            "WHERE upd > ?", (pk_token,)).fetchall()
+            "WHERE upd > ?", (pk_token,))
         return [PartKeyRecord(_pk_from_blob(b), st, et) for b, st, et in rows]
 
     def update_tokens(self, dataset, shard):
@@ -310,8 +327,8 @@ class LocalDiskMetaStore(MetaStore):
             c.commit()
 
     def read_checkpoints(self, dataset, shard):
-        c = self._db.conn(dataset, shard)
-        return dict(c.execute("SELECT grp, offset FROM checkpoints"))
+        return dict(self._db.read(dataset, shard,
+                                  "SELECT grp, offset FROM checkpoints"))
 
     # cost-model snapshots: atomic-replace file beside the dataset's shard
     # dbs, so learned estimates survive a restart (query/cost_model.py)

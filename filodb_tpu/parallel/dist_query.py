@@ -33,8 +33,9 @@ from filodb_tpu.query.engine.kernels import fdtype
 # that a profile names an operation by what it does and not by the
 # compiler's ``while.13``: ``prepare/correct``, ``prepare/prefix``,
 # ``bounds/search``, ``eval/<fn>``, ``reduce/<agg>``. Metadata only. The
-# fused programs evaluate bounds and correction inside ``eval/<fn>``, so
-# there the names nest (``eval/max_over_time/bounds/search``).
+# masked-scan program of window min/max searches its bounds inside
+# ``eval/<fn>``, so there the names nest
+# (``eval/max_over_time/bounds/search``).
 
 
 @jax.named_scope("bounds/search")
@@ -67,9 +68,14 @@ def _rate_partials_from_bounds(ts, vals, counts_mask, lo, hi, cv=None,
 
     ``cv`` is the (optionally counter-corrected) value tensor; when None
     the masked values are used directly (delta / non-counter semantics).
-    Shared by the fused kernels (bounds computed in-kernel) and the split
-    prepare/bounds/step pipeline (bounds and correction cached across
-    queries) so both forms run the identical float ops.
+
+    ``raw`` [P_l, S_l] is the uncorrected value tensor when ``vals`` are
+    the host's pre-corrected/rebased f64 pass (``SeriesBatch.delta_host``,
+    placed when the device dtype cannot correct the batch itself); it feeds
+    ONLY the ``v_first_raw`` field, whose sole consumer is Prometheus'
+    extrapolate-to-zero heuristic. The boundary combine keeps using the
+    rebased first/last (a large base would not cancel exactly in f32
+    there).
     """
     dt = fdtype()
     valid = counts_mask
@@ -96,30 +102,6 @@ def _rate_partials_from_bounds(ts, vals, counts_mask, lo, hi, cv=None,
         v_first_raw = jnp.where(has, g(rawm, i_first), 0.0)
     return jnp.stack([n.astype(dt), t_first, v_first, t_last, v_last, inc,
                       v_first_raw], axis=-1)
-
-
-def _local_rate_partials(ts, vals, counts_mask, steps, window,
-                         counter: bool = True, raw=None):
-    """Per-device window partials for the local (P_l, S_l) time block.
-
-    Returns [P_l, K, 7]: n, t_first, v_first, t_last, v_last, internal
-    (counter-corrected when ``counter``) increase, v_first_raw. Missing
-    => n=0 and sentinels.
-
-    ``raw`` [P_l, S_l] is the uncorrected value tensor when ``vals`` ride
-    the pre-corrected/rebased f32-precision lane (``SeriesBatch
-    .delta_host``); it feeds ONLY the ``v_first_raw`` field, whose sole
-    consumer is Prometheus' extrapolate-to-zero heuristic. The boundary
-    combine keeps using the rebased first/last (a large base would not
-    cancel exactly in f32 there).
-    """
-    dt = fdtype()
-    valid = counts_mask
-    v = jnp.where(valid, vals, 0.0).astype(dt)
-    lo, hi = _window_bounds(ts, steps, window)
-    cv = _counter_correct(v, valid) if counter else None
-    return _rate_partials_from_bounds(ts, vals, counts_mask, lo, hi, cv=cv,
-                                      raw=raw)
 
 
 def _combine_time_partials(parts, steps, window, mode: str = "rate",
@@ -206,7 +188,8 @@ def _simple_partials_from_bounds(ts, vals, counts_mask, csum, cnt, csum2,
     """[P_l, K, 7] simple-fn partials given precomputed prefixes + bounds:
     sum, count, min, max, last, t_last, sumsq. ``with_minmax=False`` fills
     the min/max fields with sentinels — window min/max have no prefix form
-    (the split pipeline excludes those fns and keeps the fused kernel)."""
+    (only the masked-scan program, ``make_distributed_range_agg``, asks
+    for them)."""
     dt = fdtype()
     valid = counts_mask
     v = jnp.where(valid, vals, 0.0).astype(dt)
@@ -235,16 +218,6 @@ def _simple_partials_from_bounds(ts, vals, counts_mask, csum, cnt, csum2,
     return jnp.stack([s, n, mn, mx, last, t_last, s2], axis=-1)
 
 
-def _local_simple_partials(ts, vals, counts_mask, steps, window):
-    """Per-device partials for associative over-time functions:
-    [P_l, K, 7] = sum, count, min, max, last, t_last, sumsq
-    (+inf/-inf/0 sentinels)."""
-    lo, hi = _window_bounds(ts, steps, window)
-    csum, cnt, csum2 = _simple_prefixes(vals, counts_mask)
-    return _simple_partials_from_bounds(ts, vals, counts_mask, csum, cnt,
-                                        csum2, lo, hi)
-
-
 def _sc_var(p):
     n = p[..., 1].sum(0)
     s = p[..., 0].sum(0)
@@ -266,10 +239,6 @@ _SIMPLE_COMBINE = {
     "max_over_time": lambda p: jnp.where(p[..., 1].sum(0) > 0,
                                          p[..., 3].max(0), jnp.nan),
     "last_over_time": lambda p: jnp.where(
-        p[..., 1].sum(0) > 0,
-        jnp.take_along_axis(p[..., 4], jnp.argmax(p[..., 5], axis=0)[None],
-                            axis=0)[0], jnp.nan),
-    "last_sample": lambda p: jnp.where(
         p[..., 1].sum(0) > 0,
         jnp.take_along_axis(p[..., 4], jnp.argmax(p[..., 5], axis=0)[None],
                             axis=0)[0], jnp.nan),
@@ -318,87 +287,39 @@ def _group_reduce(res, gid_l, num_groups, agg):
 COUNTER_FNS = {"rate": ("rate", True), "increase": ("increase", True),
                "delta": ("delta", False)}
 
-
-def _mesh_call(ts, vals, valid, group_ids, steps, window, raw=None):
-    """(in_specs, args) for the distributed step functions' shard_map —
-    appending the optional raw-value operand when the pre-corrected lane
-    supplies it."""
-    in_specs = (P("shard", "time"), P("shard", "time"),
-                P("shard", "time"), P("shard"), P(None), P())
-    args = (ts, vals, valid, group_ids, steps, window)
-    if raw is not None:
-        in_specs += (P("shard", "time"),)
-        args += (raw,)
-    return in_specs, args
-
 # aggs with associative mesh reductions
 MESH_AGG_OPS = ("sum", "avg", "count", "min", "max", "stddev", "stdvar",
                 "group")
 
 
-def make_distributed_range_agg(mesh: Mesh, fn: str, num_groups: int,
-                               agg: str | None = "sum"):
-    """Distributed ``agg(fn(x[w])) by (g)`` over the (shard, time) mesh —
-    time-block partials all-gathered over ``time``, label groups reduced via
-    segment ops + collectives over ``shard``. ``agg=None`` returns the
-    per-series [P, K] matrix (raw selectors / un-aggregated range functions),
-    sharded over the shard axis."""
-
-    @jax.named_scope(f"eval/{fn}")
-    def per_series(ts_l, vals_l, valid_l, steps_r, window_r, raw_l=None):
-        if fn in COUNTER_FNS:
-            mode, counter = COUNTER_FNS[fn]
-            parts = _local_rate_partials(ts_l, vals_l, valid_l, steps_r,
-                                         window_r, counter=counter,
-                                         raw=raw_l)
-            gathered = lax.all_gather(parts, "time")  # [dt, P_l, K, 7]
-            return _combine_time_partials(gathered, steps_r, window_r,
-                                          mode=mode, counter=counter)
-        combine = _SIMPLE_COMBINE[fn]
-        parts = _local_simple_partials(ts_l, vals_l, valid_l, steps_r,
-                                       window_r)
-        gathered = lax.all_gather(parts, "time")  # [dt, P_l, K, 7]
-        return combine(gathered)
-
-    def step(ts, vals, valid, group_ids, steps, window, raw=None):
-        # ``raw`` [P, S]: uncorrected values, present when ``vals`` ride
-        # the pre-corrected/rebased f32-precision lane
-        def kernel(ts_l, vals_l, valid_l, gid_l, steps_r, window_r,
-                   *rest):
-            res = per_series(ts_l, vals_l, valid_l, steps_r, window_r,
-                             rest[0] if rest else None)
-            if agg is None:
-                return res
-            with jax.named_scope(f"reduce/{agg}"):
-                return _group_reduce(res, gid_l, num_groups, agg)
-
-        in_specs, args = _mesh_call(ts, vals, valid, group_ids, steps,
-                                    window, raw)
-        return jax.shard_map(
-            kernel, mesh=mesh, in_specs=in_specs,
-            out_specs=P("shard", None) if agg is None else P(None, None),
-            check_vma=False,
-        )(*args)
-
-    return jax.jit(step)
-
-
-# ---- split pipeline: prepare / bounds / step --------------------------------
+# ---- split pipeline: prepare / bounds / eval / reduce -----------------------
 #
-# The fused kernels above recompute two batch-level passes on EVERY query:
-# the counter-correction cumsum over [P, S] and the vmapped searchsorted
-# window bounds — together ~90% of a warm big-scan query's device time,
-# even though neither depends on anything but (batch version, step grid,
-# window). The split pipeline hoists both into separately-jitted sharded
-# programs whose outputs stay resident on device and are cached by the
-# mesh engine, so a warm query runs only the tiny step program (a handful
-# of gathers, the time-axis all_gather of [dt, P_l, K, 7] partials, and
-# the segment_sum + psum group reduce). All three programs are
-# shard_map-wrapped over the same (shard, time) mesh and reuse the exact
-# helper functions of the fused path, so results are identical.
+# A query's device work is cut by what each pass depends on, and each pass
+# is its own jitted program whose output stays on the device, cached by the
+# mesh engine:
 #
-# Window min/max are excluded: they have no prefix-summable form (the
-# fused kernel's blocked masked scan stays the per-query cost there).
+#   prepare  (batch version)                    counter-correction cumsum
+#                                               over [P, S], or the three
+#                                               exclusive prefix sums
+#   bounds   (batch version, grid, window)      the vmapped double
+#                                               searchsorted: (lo, hi) of
+#                                               every window
+#   eval     (batch version, grid, window, fn)  boundary gathers, the
+#                                               all_gather of [dt, P_l, K, 7]
+#                                               partials over ``time``, the
+#                                               combine: [P, K] a series
+#   reduce   (every query)                      segment reduce + psum over
+#                                               ``shard``: [G, K]
+#
+# so a query that repeats a grid over unchanged data runs only the reduce,
+# and sum() and avg() over one rate() share one eval. All four are
+# shard_map programs over the same (shard, time) mesh: an output is only
+# ever read by a program with the same sharding, and no global layout is
+# materialised between them.
+#
+# The functions below have that form because a window of theirs is a
+# difference of two prefix values or a pair of boundary samples. Window
+# min/max are neither: see ``make_distributed_range_agg``.
 SPLIT_FNS = ("rate", "increase", "delta", "sum_over_time",
              "count_over_time", "avg_over_time", "last_over_time",
              "present_over_time", "stddev_over_time", "stdvar_over_time")
@@ -409,11 +330,10 @@ def make_mesh_prepare(mesh: Mesh, kind: str):
     """Per-batch-version prepare program, sharded like the batch itself.
 
     ``kind="counter"``: (vals, valid) → counter-corrected values [P, S]
-    (block-local cumsum, identical to the fused kernels' in-kernel
-    correction — cross-block resets are still handled by the combine's
-    boundary terms). This is the device-side replacement for the host
-    ``SeriesBatch.delta_host`` pre-pass when the value magnitudes make
-    direct f32 arithmetic safe (see mesh_engine._device_correction_ok).
+    (block-local cumsum — resets across time blocks are handled by the
+    combine's boundary terms). This is the device-side replacement for
+    the host ``SeriesBatch.delta_host`` pre-pass when the value magnitudes
+    make direct f32 arithmetic safe (see mesh_engine._device_correction_ok).
 
     ``kind="prefix"``: (vals, valid) → (csum, cnt, csum2) exclusive
     prefixes, globally [P, S + dt] sharded (shard, time) — each time block
@@ -477,7 +397,8 @@ def make_mesh_eval_delta(mesh: Mesh, fn: str, counter: bool | None = None):
     window) — never on the query's grouping — so the engine caches THIS
     stage's output and re-runs only the group reduce per query. ``cv``
     (counter-corrected values) rides along for counter fns; ``raw``
-    accompanies the host-corrected lane exactly as in the fused kernels.
+    accompanies the host's pre-corrected values (see
+    ``_rate_partials_from_bounds``).
 
     ``counter`` overrides the per-fn default: delta on a COUNTER schema
     is reset-corrected like rate/increase (mirroring the exec
@@ -515,7 +436,7 @@ def make_mesh_eval_delta(mesh: Mesh, fn: str, counter: bool | None = None):
 def make_mesh_eval_simple(mesh: Mesh, fn: str):
     """Per-(batch version, grid, window) series evaluation for the
     prefix-summable over-time fns given cached prefixes + bounds (window
-    min/max have no prefix form and stay on the fused kernel). Output
+    min/max have no prefix form: ``make_distributed_range_agg``). Output
     [P, K] sharded on ``shard``, replicated over ``time`` — cached by the
     engine like the delta-family eval."""
     if fn not in _SIMPLE_SPLIT_FNS:
@@ -561,47 +482,57 @@ def make_mesh_group_reduce(mesh: Mesh, num_groups: int, agg: str):
     return jax.jit(step)
 
 
-def make_distributed_sum_rate(mesh: Mesh, num_groups: int):
-    """Build the jitted distributed ``sum(rate(x[w])) by (g)`` step.
+# ---- window min/max: the masked scan ----------------------------------------
 
-    Inputs (global shapes):
-      ts [P, S] int32 relative ms (padded TS_PAD), vals [P, S],
-      valid [P, S] bool, group_ids [P] int32, steps [K] int32,
-      window int32 scalar.
-    Output: [G, K] group sums, fully replicated.
-    """
+def make_distributed_range_agg(mesh: Mesh, fn: str, num_groups: int,
+                               agg: str | None = "sum"):
+    """The program of the range functions that have no prefix form,
+    ``min_over_time`` and ``max_over_time``: distributed
+    ``agg(fn(x[w])) by (g)`` over the (shard, time) mesh in ONE program,
+    run whole by every query. Each device searches its block's window
+    bounds and scans every window under a mask ([P_l, K, S_l] compares);
+    the time-block partials are all-gathered over ``time`` and combined,
+    and label groups are reduced via segment ops + collectives over
+    ``shard``. ``agg=None`` returns the per-series [P, K] matrix, sharded
+    over the shard axis.
 
-    def step(ts, vals, valid, group_ids, steps, window, raw=None):
-        def kernel(ts_l, vals_l, valid_l, gid_l, steps_r, window_r, *rest):
-            parts = _local_rate_partials(ts_l, vals_l, valid_l, steps_r,
-                                         window_r,
-                                         raw=rest[0] if rest else None)
-            gathered = lax.all_gather(parts, "time")  # [dt, P_l, K, 7]
-            rate = _combine_time_partials(gathered, steps_r, window_r)
-            present = ~jnp.isnan(rate)
-            contrib = jnp.where(present, rate, 0.0)
-            gsum = jax.ops.segment_sum(contrib, gid_l, num_groups)
-            gcnt = jax.ops.segment_sum(present.astype(contrib.dtype), gid_l,
-                                       num_groups)
-            gsum = lax.psum(gsum, "shard")
-            gcnt = lax.psum(gcnt, "shard")
-            return jnp.where(gcnt > 0, gsum, jnp.nan)
+    Inputs (global shapes): ts [P, S] int32 relative ms, vals [P, S],
+    valid [P, S] bool, group_ids [P] int32, steps [K] int32, window int32
+    scalar. A ``fn`` of ``SPLIT_FNS`` is refused: its programs are the
+    four above."""
+    if fn not in ("min_over_time", "max_over_time"):
+        raise ValueError(f"{fn} is not a masked-scan range function")
+    combine = _SIMPLE_COMBINE[fn]
 
-        in_specs, args = _mesh_call(ts, vals, valid, group_ids, steps,
-                                    window, raw)
+    def step(ts, vals, valid, group_ids, steps, window):
+        def kernel(ts_l, vals_l, valid_l, gid_l, steps_r, window_r):
+            with jax.named_scope(f"eval/{fn}"):
+                lo, hi = _window_bounds(ts_l, steps_r, window_r)
+                csum, cnt, csum2 = _simple_prefixes(vals_l, valid_l)
+                parts = _simple_partials_from_bounds(
+                    ts_l, vals_l, valid_l, csum, cnt, csum2, lo, hi)
+                gathered = lax.all_gather(parts, "time")  # [dt, P_l, K, 7]
+                res = combine(gathered)
+            if agg is None:
+                return res
+            with jax.named_scope(f"reduce/{agg}"):
+                return _group_reduce(res, gid_l, num_groups, agg)
+
         return jax.shard_map(
-            kernel, mesh=mesh, in_specs=in_specs,
-            out_specs=P(None, None),
+            kernel, mesh=mesh,
+            in_specs=(P("shard", "time"), P("shard", "time"),
+                      P("shard", "time"), P("shard"), P(None), P()),
+            out_specs=P("shard", None) if agg is None else P(None, None),
             check_vma=False,
-        )(*args)
+        )(ts, vals, valid, group_ids, steps, window)
 
     return jax.jit(step)
 
 
 def shard_batch_arrays(mesh: Mesh, ts, vals, valid, group_ids, raw=None):
     """Place host arrays with (shard, time) shardings. ``raw`` [P, S]
-    (optional — the uncorrected values accompanying the rebased lane)
-    shards like ``vals``."""
+    (optional — the uncorrected values accompanying the host's
+    pre-corrected pass) shards like ``vals``."""
     s2 = NamedSharding(mesh, P("shard", "time"))
     s1 = NamedSharding(mesh, P("shard"))
     placed = (jax.device_put(ts, s2), jax.device_put(vals, s2),
@@ -609,128 +540,3 @@ def shard_batch_arrays(mesh: Mesh, ts, vals, valid, group_ids, raw=None):
     if raw is not None:
         placed += (jax.device_put(raw, s2),)
     return placed
-
-
-def make_distributed_sum_rate_ring(mesh: Mesh, num_groups: int):
-    """Ring variant of the distributed rate pipeline: instead of
-    all-gathering every time-block's partials, carry state around the time
-    axis with ``lax.ppermute`` (the literal ring-attention communication
-    shape). Each of the dt-1 hops passes the running combine state
-    [P_l, K, 8] to the next time block:
-
-        (n_so_far, t_first, v_first, inc_so_far, has_prev, v_prev, t_last,
-         v_first_raw)
-
-    Memory per device stays O(P_l·K) regardless of dt (the all_gather
-    version holds [dt, P_l, K, 7]); latency is dt-1 ICI hops.
-    """
-    dt_size = mesh.shape["time"]
-
-    def step(ts, vals, valid, group_ids, steps, window, raw=None):
-        def kernel(ts_l, vals_l, valid_l, gid_l, steps_r, window_r,
-                   *rest):
-            dtt = fdtype()
-            parts = _local_rate_partials(ts_l, vals_l, valid_l, steps_r,
-                                         window_r,
-                                         raw=rest[0] if rest else None)
-            n_l, tf_l, vf_l, tl_l, vl_l, inc_l, vfr_l = [
-                parts[..., i] for i in range(7)]
-            has_l = n_l > 0
-            t_idx = lax.axis_index("time")
-
-            # state flowing forward around the ring
-            state = jnp.stack([
-                n_l, tf_l, jnp.where(has_l, vf_l, 0.0), inc_l,
-                has_l.astype(dtt), jnp.where(has_l, vl_l, 0.0), tl_l,
-                jnp.where(has_l, vfr_l, 0.0)],
-                axis=-1)
-
-            perm = [(i, i + 1) for i in range(dt_size - 1)]
-
-            def hop(state_in, _):
-                prev = lax.ppermute(state_in, "time", perm)
-                # devices with t_idx == 0 receive zeros (no source): mask the
-                # counts/flags AND re-sentinel the min/max-combined fields so
-                # zeros can't pollute t_first (min) / t_last (max)
-                p_n, p_tf, p_vf, p_inc, p_has, p_vl, p_tl, p_vfr = [
-                    prev[..., i] for i in range(8)]
-                first_block = (t_idx == 0)
-                p_n = jnp.where(first_block, 0.0, p_n)
-                p_has = jnp.where(first_block, 0.0, p_has)
-                no_prev = p_has == 0
-                p_tf = jnp.where(no_prev, jnp.array(2**31 - 1, dtt), p_tf)
-                p_tl = jnp.where(no_prev, jnp.array(-(2**31 - 1), dtt), p_tl)
-                p_inc = jnp.where(first_block, 0.0, p_inc)
-                # combine prev-state with the local block
-                boundary = jnp.where(
-                    has_l & (p_has > 0),
-                    jnp.where(vf_l < p_vl, vf_l, vf_l - p_vl), 0.0)
-                n_c = p_n + n_l
-                inc_c = p_inc + inc_l + boundary
-                tf_c = jnp.minimum(p_tf, tf_l)
-                vf_c = jnp.where(p_has > 0, p_vf,
-                                 jnp.where(has_l, vf_l, 0.0))
-                p_vfr = jnp.where(first_block, 0.0, p_vfr)
-                vfr_c = jnp.where(p_has > 0, p_vfr,
-                                  jnp.where(has_l, vfr_l, 0.0))
-                has_c = jnp.maximum(p_has, has_l.astype(dtt))
-                vl_c = jnp.where(has_l, vl_l, p_vl)
-                tl_c = jnp.maximum(p_tl, tl_l)
-                out = jnp.stack([n_c, tf_c, vf_c, inc_c, has_c, vl_c, tl_c,
-                                 vfr_c], axis=-1)
-                return out, None
-
-            state, _ = lax.scan(hop, state, None, length=max(dt_size - 1, 1)
-                                if dt_size > 1 else 0)
-            # after dt-1 hops the LAST time block holds the full combine;
-            # broadcast it back to every block (masked psum: single
-            # contributor)
-            if dt_size > 1:
-                full = lax.psum(
-                    jnp.where(t_idx == dt_size - 1, state, 0.0), "time")
-            else:
-                full = state
-            (n_tot, t_first_g, _, total_inc, _, _, t_last_g,
-             v_first_raw_g) = [full[..., i] for i in range(8)]
-
-            # Prometheus extrapolation (same as the gather variant)
-            t_first_s = t_first_g / 1000.0
-            t_last_s = t_last_g / 1000.0
-            range_start = (steps_r[None, :] - window_r).astype(dtt) / 1000.0
-            range_end = steps_r[None, :].astype(dtt) / 1000.0
-            sampled = t_last_s - t_first_s
-            avg_dur = sampled / jnp.maximum(n_tot - 1.0, 1.0)
-            dur_start = t_first_s - range_start
-            dur_end = range_end - t_last_s
-            dur_zero = jnp.where(
-                total_inc > 0,
-                sampled * v_first_raw_g / jnp.maximum(total_inc, 1e-30),
-                jnp.inf)
-            dur_start = jnp.minimum(dur_start, dur_zero)
-            threshold = avg_dur * 1.1
-            extend = sampled
-            extend = extend + jnp.where(dur_start < threshold, dur_start,
-                                        avg_dur / 2)
-            extend = extend + jnp.where(dur_end < threshold, dur_end,
-                                        avg_dur / 2)
-            rate = total_inc * extend / jnp.maximum(sampled, 1e-10) \
-                / (window_r.astype(dtt) / 1000.0)
-            rate = jnp.where(n_tot >= 2, rate, jnp.nan)
-
-            present = ~jnp.isnan(rate)
-            contrib = jnp.where(present, rate, 0.0)
-            gsum = lax.psum(jax.ops.segment_sum(contrib, gid_l, num_groups),
-                            "shard")
-            gcnt = lax.psum(jax.ops.segment_sum(
-                present.astype(contrib.dtype), gid_l, num_groups), "shard")
-            return jnp.where(gcnt > 0, gsum, jnp.nan)
-
-        in_specs, args = _mesh_call(ts, vals, valid, group_ids, steps,
-                                    window, raw)
-        return jax.shard_map(
-            kernel, mesh=mesh, in_specs=in_specs,
-            out_specs=P(None, None),
-            check_vma=False,
-        )(*args)
-
-    return jax.jit(step)

@@ -31,7 +31,6 @@ scatter-gather exec tree for every other plan shape.
 from __future__ import annotations
 
 import logging
-import os
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -52,18 +51,18 @@ from filodb_tpu.utils.tracing import span, tag
 
 log = logging.getLogger(__name__)
 
-# mesh-engine observability: plan recognition, dispatch form, cache
-# behavior, and adaptive lane routing (tests/test_metrics_scrape.py pins
-# these families). Registered eagerly so a scrape sees the families even
-# before the first mesh query.
+# mesh-engine observability: plan recognition, dispatch form and cache
+# behavior (tests/test_metrics_scrape.py pins these families). Registered
+# eagerly so a scrape sees the families even before the first mesh query.
 _M_SUPPORTED = get_counter(
     "filodb_mesh_supported", help="plans recognized for mesh execution")
 _M_UNSUPPORTED = get_counter(
     "filodb_mesh_unsupported", help="plans that fell back to the exec path "
     "at recognition time")
 _M_DISPATCH = {f: get_counter("filodb_mesh_dispatch", {"form": f},
-                              help="mesh batch dispatches by kernel form "
-                              "(split pipeline vs fused one-shot)")
+                              help="mesh batch dispatches by program form "
+                              "(split pipeline; fused masked scan of "
+                              "window min/max)")
                for f in ("split", "fused")}
 _M_COMPILE = {e: get_counter("filodb_mesh_compile_cache", {"event": e},
                              help="compiled mesh program cache hits/misses")
@@ -83,9 +82,6 @@ _M_FALLBACK = {r: get_counter("filodb_mesh_fallback", {"reason": r},
                               help="mesh dispatches that fell back to the "
                               "exec path after recognition")
                for r in ("declined", "error", "shards")}
-_M_ROUTED = {la: get_counter("filodb_mesh_routed", {"lane": la},
-                             help="adaptive engine lane routing decisions")
-             for la in ("device", "single", "host")}
 GaugeFn("filodb_mesh_hit_rate",
         lambda: _M_SUPPORTED.value / t
         if (t := _M_SUPPORTED.value + _M_UNSUPPORTED.value) else 0.0,
@@ -209,7 +205,6 @@ class MeshQueryEngine:
     """
 
     mesh: object = None
-    variant: str = "gather"  # or "ring" (ppermute time combine)
     # prepare-stage sidecar delegation (engine/sidecar_lane.py): tick-shaped
     # grids (K ≤ 2 steps — rule ticks and alert probes evaluate at a single
     # instant) over eligible range functions are declined here so the exec
@@ -411,7 +406,7 @@ class MeshQueryEngine:
                              stats: "QueryStats | list | None" = None
                              ) -> list:
         """Evaluate lowered plans sharing a signature (same selector/fn/agg;
-        step grids may differ) in ONE mesh program. Returns one StepMatrix
+        step grids may differ) over ONE placed batch. Returns one StepMatrix
         (or None) per entry. ``stats`` is one QueryStats (single query) or a
         list aligned with ``lows`` — every query in the group scanned the
         whole shared batch, so each gets the full scan counts.
@@ -421,8 +416,9 @@ class MeshQueryEngine:
         every sample written once into arrays of the placed shape — and, on
         the ``raw`` lane, the placed dtype), ``mesh-group`` (keys and group
         ids at the placed length), ``mesh-pad`` (no padding any more: the
-        lane choice, the delta lanes' host f64 pass and the one converted
-        copy it needs — tag ``copied_bytes``, 0 on the ``raw`` lane — the
+        lane choice, the ``split`` lane's conversion of the f64 batch or,
+        where the device dtype cannot correct it, its host f64 pre-pass —
+        tag ``copied_bytes``, 0 on the ``raw`` lane — the
         histogram flatten, the validity mask), ``mesh-place`` (the put: the
         batch's own ``ts``/``vals`` on the ``raw`` lane); a batch-cache hit
         opens none of these five. Then ``mesh-dispatch``, ``mesh-fetch``,
@@ -432,7 +428,6 @@ class MeshQueryEngine:
         from filodb_tpu.core.memstore.odp import page_partitions
         from filodb_tpu.parallel.dist_query import (
             make_distributed_range_agg,
-            make_distributed_sum_rate_ring,
             shard_batch_arrays,
         )
         from filodb_tpu.query.engine.batch import build_batch
@@ -448,27 +443,20 @@ class MeshQueryEngine:
 
         shards = memstore.shards_for(dataset)
         version = sum(s.data_version for s in shards)
-        # split pipeline (prepare/bounds/step, dist_query.py): correction
-        # and window bounds are cached on device across queries instead of
-        # recomputed per call. Window min/max have no prefix form and the
-        # ring variant is a fused-only memory optimization — both keep the
-        # fused kernels. FILODB_MESH_SPLIT=0 is the safety valve (also how
-        # benchmarks measure the pre-split baseline).
-        use_split = (self.variant != "ring" and fn in SPLIT_FNS
-                     and os.environ.get("FILODB_MESH_SPLIT", "1") != "0")
-        # delta-family fns place the pre-corrected/rebased f64→f32 value
-        # lane (SeriesBatch.delta_host) instead of raw values, so the lane
-        # kind is part of the cache key ("corrected" also implies counter
-        # reset correction; "rebased" is shift-only, for delta on gauges —
-        # delta on a COUNTER schema is reset-corrected too, mirroring the
-        # exec transformers, decided once the matched schema is known).
-        # On the split pipeline ("split" lane) the correction instead runs
-        # ON DEVICE over the raw placed values whenever the batch's
-        # magnitudes make that safe (_device_correction_ok) — the host
-        # pre-pass survives only as the big-magnitude fallback.
-        lane = ("split" if use_split and fn in ("rate", "increase", "delta")
-                else "corrected" if fn in ("rate", "increase")
-                else "rebased" if fn == "delta" else "raw")
+        # split pipeline (prepare/bounds/eval/reduce, dist_query.py):
+        # correction, prefixes, window bounds and evaluated windows are
+        # cached on device across queries. Window min/max have no prefix
+        # form: they run the masked-scan program, whole, every query.
+        use_split = fn in SPLIT_FNS
+        # what is placed, and so part of the cache key. ``raw``: the
+        # values as stored. ``split`` (the delta family): the same raw
+        # values when the batch's magnitudes let the device dtype correct
+        # counter resets and cancel bases itself (_device_correction_ok);
+        # otherwise the host's f64 pre-pass (SeriesBatch.delta_host:
+        # reset-corrected for rate/increase and for delta on a COUNTER
+        # schema, as the exec transformers do; rebased only for delta on
+        # a gauge) with, for rate/increase, the raw values beside it.
+        lane = "split" if fn in ("rate", "increase", "delta") else "raw"
         # the agg NAME is part of the key (not just agg-vs-none): a
         # histogram batch cached under sum must not satisfy a later
         # min/max/avg over the same selector — those fall back to the
@@ -600,12 +588,11 @@ class MeshQueryEngine:
             with span("mesh-pad", lane=lane) as sp:
                 ts_p, counts_p, gid_p = batch.ts, batch.counts, gids
                 raw_vals = None
-                if lane == "raw" or (lane == "split"
-                                     and _device_correction_ok(batch.vals)):
+                if lane == "raw" or _device_correction_ok(batch.vals):
                     # raw values go straight to the device; on the split
-                    # lane the counter correction is fused into the cached
-                    # prepare program (make_mesh_prepare), so no host
-                    # pre-pass runs at all
+                    # lane the counter correction is the cached prepare
+                    # program's (make_mesh_prepare), so no host pre-pass
+                    # runs at all
                     host_vals = batch.vals
                 else:
                     counter = fn in ("rate", "increase") or delta_counter
@@ -662,13 +649,9 @@ class MeshQueryEngine:
                 step_fn = None if agg is None else self._get_fn(
                     ("split-reduce", agg, Gp),
                     lambda: make_mesh_group_reduce(mesh, Gp, agg))
-            elif self.variant == "ring" and fn == "rate" and agg == "sum":
-                step_fn = self._get_fn(
-                    (fn, agg, Gp if agg else None, self.variant),
-                    lambda: make_distributed_sum_rate_ring(mesh, Gp))
             else:
                 step_fn = self._get_fn(
-                    (fn, agg, Gp if agg else None, self.variant),
+                    (fn, agg, Gp if agg else None),
                     lambda: make_distributed_range_agg(mesh, fn, Gp, agg))
             _M_DISPATCH["split" if use_split else "fused"].inc()
 
@@ -678,7 +661,7 @@ class MeshQueryEngine:
 
             # replicated small operands are PINNED to the mesh's devices:
             # the default backend may be a different platform (e.g. a
-            # host-lane CPU mesh inside a TPU process), and a default-placed
+            # CPU mesh inside a TPU process), and a default-placed
             # operand would drag cross-backend transfers into every call
             repl = NamedSharding(mesh, PartitionSpec())
             win_d = jax.device_put(np.int32(low0.window), repl)
@@ -736,9 +719,6 @@ class MeshQueryEngine:
                             split_prefix, raw_d, delta_counter)
                         out = ev_d if step_fn is None \
                             else step_fn(ev_d, gid_d)
-                    elif raw_d is not None:
-                        out = step_fn(ts_d, vals_d, valid_d, gid_d, grid_d,
-                                      win_d, raw_d)
                     else:
                         out = step_fn(ts_d, vals_d, valid_d, gid_d, grid_d,
                                       win_d)

@@ -2,7 +2,7 @@
 
 Every either/or planning decision in the query path — sidecar fold vs
 payload decode, pyramid compose vs chunk fallback, aggregate pushdown vs
-local evaluation, mesh lane routing, cold-tier paging granularity,
+local evaluation, cold-tier paging granularity,
 governor admission classing, result-cache admission — historically ran on
 a static constant or a hand-tuned valve. This module closes the loop from
 settled :class:`~filodb_tpu.query.model.QueryStats` back to those
@@ -12,12 +12,11 @@ predicted-cheaper arm, then settles the observed wall time back with
 when the settle point is downstream of the decision point — filolint
 DC601 enforces that pairing).
 
-Estimator per (site, signature-class, arm): an EWMA point estimate with
-the same warmup semantics as PR 14's lane router (first two samples
-replace outright, then ``est += alpha * (v - est)``) plus a bounded
-reservoir of recent samples for percentile queries (governor Retry-After,
-debug surfaces). Signature classes are caller-bucketed feature strings
-(``"b16"``, ``"span4096"``) or hashed canonical plan signatures; the
+Estimator per (site, signature-class, arm): an EWMA point estimate (the
+first two samples replace outright, then ``est += alpha * (v - est)``)
+plus a bounded reservoir of recent samples for percentile queries
+(governor Retry-After, debug surfaces). Signature classes are
+caller-bucketed feature strings (``"b16"``, ``"span4096"``) or hashed canonical plan signatures; the
 table is LRU-bounded over signature classes so adversarial cardinality
 cannot grow memory without bound.
 
@@ -58,7 +57,7 @@ __all__ = [
 
 # The known decision sites. Metrics are pre-created per site at import so
 # scrapes expose every series from process start (PR206 parity).
-SITES = ("sidecar", "pyramid", "pushdown", "lane", "paging", "admit", "cache")
+SITES = ("sidecar", "pyramid", "pushdown", "paging", "admit", "cache")
 
 _SOURCES = ("static", "model", "override")
 
@@ -74,8 +73,7 @@ _calib_gauge = {
 _signatures_gauge = get_gauge("filodb_costmodel_signatures")
 _evicted = get_counter("filodb_costmodel_evictions")
 
-# EWMA weight for calibration error and arm estimates (matches the PR 14
-# lane router so the generalized "lane" site reproduces its routing).
+# EWMA weight for calibration error and arm estimates.
 _ALPHA = 0.3
 
 
@@ -257,8 +255,8 @@ class CostModel:
     ) -> Decision:
         """Route one decision. Returns the ``static_arm`` unless adaptive
         routing is enabled AND the competing arms are warm (all of them
-        when ``require_all``, any subset otherwise — the lane router keeps
-        PR 14's min-over-known semantics via ``require_all=False``)."""
+        when ``require_all``, any subset otherwise: the cheapest of the
+        arms that are known)."""
         sig = signature_key(signature)
         if override is not None:
             ctr = _decided.get((site, "override"))
@@ -322,8 +320,7 @@ class CostModel:
         """Settle a decision with its observed cost; feeds the estimator,
         per-site calibration error, and the prediction-vs-actual ring.
         ``observe=False`` skips the estimator update for call sites that
-        already fed the sample through :meth:`observe` (the lane router
-        mirrors every serve)."""
+        already fed the sample through :meth:`observe`."""
         arm = decision.settle_arm or decision.arm
         if observe:
             self.observe(decision.site, decision.signature, arm, actual_s)

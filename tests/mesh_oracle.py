@@ -14,7 +14,6 @@ Not a test module: imported by ``test_mesh_batch_once.py``,
 
 from __future__ import annotations
 
-import os
 from types import SimpleNamespace
 
 import numpy as np
@@ -70,9 +69,7 @@ def parent_placed(batch, gids, mesh, lane, fn, delta_counter):
     gids_full = np.zeros(batch.ts.shape[0], np.int32)
     gids_full[: len(gids)] = gids
     raw_vals = None
-    if lane == "raw":
-        mesh_vals = batch.vals
-    elif lane == "split" and mesh_engine._device_correction_ok(batch.vals):
+    if lane == "raw" or mesh_engine._device_correction_ok(batch.vals):
         mesh_vals = batch.vals
     else:
         counter = fn in ("rate", "increase") or delta_counter
@@ -271,52 +268,44 @@ STORES = {
 MESHES = {"1x1": (1, 1), "4x1": (4, 1), "2x2": (2, 2), "4x2": (4, 2),
           "3x1": (3, 1)}
 
-# name → (store, PromQL, lane the engine must pick, split pipeline on?)
+# name → (store, PromQL, lane the engine must pick)
 CASES = {
     "raw-avg": ("gauge", "avg by (host)(avg_over_time(gauge_metric[5m]))",
-                "raw", True),
+                "raw"),
     "raw-max-fused": ("gauge", "max(max_over_time(gauge_metric[5m]))",
-                      "raw", True),
-    "raw-last-sample": ("gauge", "gauge_metric", "raw", True),
+                      "raw"),
+    "raw-last-sample": ("gauge", "gauge_metric", "raw"),
     "split-small": ("counter", "sum by (job)(rate(http_requests_total[5m]))",
-                    "split", True),
+                    "split"),
     "split-big": ("big-counter",
-                  "sum(increase(http_requests_total[5m]))", "split", True),
-    "split-delta": ("gauge", "delta(gauge_metric[5m])", "split", True),
-    "corrected": ("big-counter", "sum(rate(http_requests_total[5m]))",
-                  "corrected", False),
-    "rebased-gauge": ("gauge", "sum(delta(gauge_metric[5m]))",
-                      "rebased", False),
-    "rebased-counter": ("counter", "delta(http_requests_total[5m])",
-                        "rebased", False),
+                  "sum(increase(http_requests_total[5m]))", "split"),
+    "split-delta": ("gauge", "delta(gauge_metric[5m])", "split"),
+    "split-big-rate": ("big-counter", "sum(rate(http_requests_total[5m]))",
+                       "split"),
+    "split-delta-sum": ("gauge", "sum(delta(gauge_metric[5m]))", "split"),
+    # delta on a COUNTER schema: the device corrects the resets
+    "split-delta-counter": ("counter", "delta(http_requests_total[5m])",
+                            "split"),
     "histogram-split": ("histogram",
                         "sum(rate(http_req_latency[5m])) by (app)",
-                        "split", True),
-    "histogram-corrected": ("histogram", "sum(rate(http_req_latency[5m]))",
-                            "corrected", False),
+                        "split"),
+    "histogram-split-one-group": ("histogram",
+                                  "sum(rate(http_req_latency[5m]))",
+                                  "split"),
     "histogram-raw": ("histogram",
-                      "sum(sum_over_time(http_req_latency[5m]))",
-                      "raw", True),
+                      "sum(sum_over_time(http_req_latency[5m]))", "raw"),
 }
 
 
 def run_case(case: str, mesh_name: str, stores: dict, run: bool = False):
     """One cell of the equivalence matrix; ``stores`` caches built stores
     by name across calls."""
-    store, query, lane, split = CASES[case]
+    store, query, lane = CASES[case]
     ms = stores.get(store)
     if ms is None:
         ms = stores[store] = STORES[store]()
     ds, dtm = MESHES[mesh_name]
     eng = MeshQueryEngine(mesh=make_query_mesh(ds * dtm, dtm))
-    prev = os.environ.get("FILODB_MESH_SPLIT")
-    os.environ["FILODB_MESH_SPLIT"] = "1" if split else "0"
-    try:
-        cap = capture_placed(eng, query, ms, run=run)
-    finally:
-        if prev is None:
-            del os.environ["FILODB_MESH_SPLIT"]
-        else:
-            os.environ["FILODB_MESH_SPLIT"] = prev
+    cap = capture_placed(eng, query, ms, run=run)
     assert cap.tags["mesh-pad"]["lane"] == lane, (case, cap.tags["mesh-pad"])
     return cap

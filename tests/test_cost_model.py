@@ -108,13 +108,13 @@ class TestDecide:
         assert (d.arm, d.source) == ("decode", "static")
 
     def test_require_all_false_keeps_min_over_known(self):
-        # the lane router's PR 14 semantics: route by whatever is warm
+        # route by whatever is warm: the cheapest of the known arms
         m = CostModel(min_samples=2)
         for _ in range(3):
-            m.observe("lane", "b4", "device", 0.002)
-        d = m.decide("lane", "b4", ("device", "single", "host"),
-                     static_arm="host", require_all=False)
-        assert (d.arm, d.source) == ("device", "model")
+            m.observe("paging", "b4", "exact", 0.002)
+        d = m.decide("paging", "b4", ("exact", "wide"),
+                     static_arm="wide", require_all=False)
+        assert (d.arm, d.source) == ("exact", "model")
 
     def test_env_kill_switch_pins_static(self, monkeypatch):
         monkeypatch.setenv("FILODB_ADAPTIVE", "0")

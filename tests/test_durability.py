@@ -106,6 +106,40 @@ class TestLocalDiskStore:
         assert recs[0].start_time == 100 and recs[0].end_time == 500
         cs.close()
 
+    def test_concurrent_scans_each_read_every_row(self, tmp_path):
+        """Threads that run the same SELECT on the store's one connection
+        (the chunk server's handler threads do) each get their own rows:
+        the statement sqlite3 caches for that SQL text is never stepped by
+        two of them at once."""
+        import sys
+        import time
+        from concurrent.futures import ThreadPoolExecutor
+
+        from filodb_tpu.core.store.api import PartKeyRecord
+        cs = LocalDiskColumnStore(str(tmp_path))
+        keys = machine_metrics_series(48)
+        cs.write_part_keys("ds", 0, [PartKeyRecord(k, 1, 2) for k in keys])
+        want = sorted(str(k) for k in keys)
+
+        def scan(_):
+            return sorted(str(r.part_key)
+                          for r in cs.scan_part_keys("ds", 0))
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            deadline = time.monotonic() + 3.0
+            with ThreadPoolExecutor(max_workers=16) as ex:
+                rounds = 0
+                while time.monotonic() < deadline and rounds < 400:
+                    for got in ex.map(scan, range(16)):
+                        assert got == want
+                    rounds += 1
+        finally:
+            sys.setswitchinterval(interval)
+            cs.close()
+        assert rounds > 0
+
 
 _SERVERS: dict = {}
 

@@ -279,13 +279,13 @@ def test_f32_raw_lane_is_built_in_f32_and_placed_as_built(case, placed_f32):
 
 
 def test_f32_delta_lanes_keep_f64_and_copy_once(placed_f32):
-    for case in ("split-small", "split-big", "split-delta", "corrected",
-                 "rebased-gauge", "rebased-counter"):
+    for case in ("split-small", "split-big", "split-delta", "split-big-rate",
+                 "split-delta-sum", "split-delta-counter"):
         cell = placed_f32[f"{case}/2x2"]
         assert cell["batch_dtype"] == "float64" and not cell["own"], case
         # the split lane at >= F32_SAFE_MAX falls back to the host f64
         # pre-pass, which for increase places the raw values too
-        two = case in ("split-big", "corrected")
+        two = case in ("split-big", "split-big-rate")
         assert cell["raw"] == two, case
         assert cell["copied_bytes"] == (2 if two else 1) * cell["vals_bytes"]
 

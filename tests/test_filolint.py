@@ -958,11 +958,10 @@ class TestDecisionParity:
         assert out == []
 
     def test_return_hand_off_settles(self, tmp_path):
-        # the lane-router shape: the decision rides out in a tuple and
-        # the caller owns the settle
+        # the decision rides out in a tuple and the caller owns the settle
         out = run_pass(tmp_path, decisionparity, {"filodb_tpu/m.py": """
-            def shared_decision(model, lanes, lane, sig):
-                d = model.decide("lane", sig, tuple(lanes), lane)
+            def shared_decision(model, arms, arm, sig):
+                d = model.decide("paging", sig, tuple(arms), arm)
                 return d.arm, d, model
             """})
         assert out == []

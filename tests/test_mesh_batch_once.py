@@ -56,13 +56,15 @@ def test_raw_lane_places_the_builders_own_arrays(case, mesh_name, stores):
 
 
 @pytest.mark.parametrize("case,arrays", [
-    ("split-small", 1), ("split-delta", 1), ("rebased-gauge", 1),
-    ("rebased-counter", 1), ("corrected", 2),
+    ("split-small", 1), ("split-delta", 1), ("split-delta-sum", 1),
+    ("split-delta-counter", 1), ("split-big-rate", 1),
 ])
 def test_delta_lanes_copy_one_value_array(case, arrays, stores):
-    """The delta lanes keep the f64 batch (``delta_host`` and the magnitude
-    check read it) and make each placed value array from it in one pass:
-    ``vals``, and ``raw`` beside it where rate/increase clamp."""
+    """The split lane keeps the f64 batch (``delta_host`` and the magnitude
+    check read it) and makes each placed value array from it in one pass:
+    ``vals`` — the raw values under x64, whatever their magnitude — and
+    ``raw`` beside it only where the host pre-pass ran (x64 off,
+    ``test_f32_mode.py``)."""
     cap = run_case(case, "2x2", stores)
     vals, raw = cap.got[1], cap.got[4]
     assert cap.batch.vals.dtype == np.float64
@@ -108,12 +110,12 @@ def test_build_batch_rounds_its_one_allocation(multiples, shape, stores):
     assert (b.ts[P_:] == np.iinfo(np.int32).max).all()
 
 
-@pytest.mark.parametrize("case", ["raw-avg", "split-small", "rebased-gauge",
-                                  "histogram-split"])
+@pytest.mark.parametrize("case", ["raw-avg", "split-small",
+                                  "split-delta-sum", "histogram-split"])
 def test_a_mesh_that_divides_no_power_of_two_answers_like_exec(case, stores):
     """3×1: the same allocation rounded up to the axis, not a fallback copy
     — and the answer is the exec tree's."""
-    store, query, _, _ = CASES[case]
+    store, query, _ = CASES[case]
     cap = run_case(case, "3x1", stores, run=True)
     assert cap.got[0].shape[0] % 3 == 0
     assert cap.tags["mesh-place"]["bytes"] == sum(

@@ -187,13 +187,6 @@ class TestMeshParity:
         self.q(m, query)
         assert hits
 
-    def test_ring_variant_parity(self, counter_store):
-        from filodb_tpu.parallel.mesh_engine import MeshQueryEngine
-        e, m = services(counter_store)
-        m.mesh_engine = MeshQueryEngine(variant="ring")
-        query = 'sum(rate(http_requests_total[5m])) by (_ns_)'
-        assert_same(self.q(e, query), self.q(m, query))
-
 
 class TestDeviceFailureSurfaces:
     """A failure inside the device engine (compile refusal, out of memory)

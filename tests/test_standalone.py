@@ -41,6 +41,17 @@ def _free_port():
         return s.getsockname()[1]
 
 
+def test_unknown_engine_refused_at_load(tmp_path):
+    """``engine`` is input from outside: a value that names no engine stops
+    the boot, and the message names the two that exist."""
+    cfg_path = tmp_path / "server.json"
+    cfg_path.write_text(json.dumps({
+        "datasets": {"timeseries": {"engine": "adaptive"}}}))
+    with pytest.raises(ValueError, match="'adaptive'") as e:
+        ServerConfig.load(str(cfg_path))
+    assert "'mesh'" in str(e.value) and "'exec'" in str(e.value)
+
+
 class TestFiloServer:
     def test_ingest_via_gateway_then_query(self, server):
         srv, tmp_path = server
