@@ -32,20 +32,77 @@ from filodb_tpu.query.engine.kernels import fdtype
 # Stable ``jax.named_scope`` names on the bodies of the device programs, so
 # that a profile names an operation by what it does and not by the
 # compiler's ``while.13``: ``prepare/correct``, ``prepare/prefix``,
-# ``bounds/search``, ``eval/<fn>``, ``reduce/<agg>``. Metadata only. The
-# masked-scan program of window min/max searches its bounds inside
-# ``eval/<fn>``, so there the names nest
-# (``eval/max_over_time/bounds/search``).
+# ``bounds/count`` or ``bounds/search`` (the form that was traced),
+# ``eval/<fn>``, ``reduce/<agg>``. Metadata only. The masked-scan program
+# of window min/max finds its bounds inside ``eval/<fn>``, so there the
+# names nest (``eval/max_over_time/bounds/count``).
 
 
-@jax.named_scope("bounds/search")
-def _window_bounds(ts, steps, window):
+def bounds_form(mesh: Mesh) -> str:
+    """The form ``_window_bounds`` takes in the programs of ``mesh``,
+    ``"count"`` or ``"search"``: the ONE place that decides, at trace time,
+    from the platform of the mesh's devices. On a TPU a data-dependent
+    gather costs ~18 ns an element and a compare a lane-cycle, so the count
+    wins at every shape measured on a v5e (``make_mesh_bounds``, ms, search
+    → count; PERF.md §6, PR 32): (P, S, K) = (16384, 1024, 32) 215.6 → 1.7;
+    (16384, 1024, 256) 947.9 → 7.0; (1024, 1024, 256) 60.5 → 1.6;
+    (131072, 1024, 32) 1,753.9 → 7.2; (16384, 8192, 256) 2,072.9 → 49.0 —
+    the count grows as K·S and the search as K·log S, and at 42× apart at
+    S 8,192 they meet beyond any S a batch is built at, so the shape has
+    no say. On the CPU the gather is cheap and the search is ~10× the
+    faster (sandbox, CPU only): the tier-1 meshes keep it; so does any
+    platform nobody has measured."""
+    return "count" if mesh.devices.flat[0].platform == "tpu" else "search"
+
+
+def _bounds_by_search(ts, steps, window):
+    """(lo, hi) by two binary searches a row: ⌈log2(S_l+1)⌉ dependent
+    gathers an element."""
     def bounds(tsp):
         hi = jnp.searchsorted(tsp, steps, side="right")
         lo = jnp.searchsorted(tsp, steps - window, side="right")
         return lo, hi
 
     return jax.vmap(bounds)(ts)
+
+
+# Window edges counted in one pass over ``ts``. On the chip the compare
+# fuses into the row reduce whatever the chunk (0 bytes of temporaries in
+# HBM); 8 is as fast as a whole grid in one fusion at S 1,024 and 3.7× the
+# faster at S 8,192, and 1 is 4–7× the slower (v5e; PERF.md §6, PR 32). A
+# compiler that fuses nothing (the CPU's) holds [8, P_l, S_l] at most.
+_COUNT_CHUNK = 8
+
+
+def _bounds_by_count(ts, steps, window):
+    """(lo, hi) by compare-and-count. A row ascends and its padding
+    (``TS_PAD``, int32 max) comes last, in every time block, so
+    ``#{ts_row <= q}`` IS the ``side="right"`` insertion point of ``q``:
+    lane-parallel compares and adds, no gather, no dependent loop. Both
+    edges of every window are counted in the same passes over ``ts``,
+    ``_COUNT_CHUNK`` of them a pass, so the [P_l, K, S_l] compare of a
+    whole grid never exists."""
+    k = steps.shape[0]
+    q = jnp.concatenate([steps - window, steps])
+    c = min(_COUNT_CHUNK, 2 * k)
+    q = jnp.pad(q, (0, -(2 * k) % c))
+
+    def count(qc):  # [c] edges -> [c, P_l]: the sample axis reduces away
+        return jnp.sum(ts[None, :, :] <= qc[:, None, None], axis=-1,
+                       dtype=jnp.int32)
+
+    n = lax.map(count, q.reshape(-1, c)).reshape(-1, ts.shape[0]).T
+    return n[:, :k], n[:, k:2 * k]
+
+
+def _window_bounds(ts, steps, window, mesh: Mesh):
+    """int32 [P_l, K] pair (lo, hi): samples ``lo[p, k] .. hi[p, k] - 1`` of
+    row ``p`` lie in ``(steps[k] - window, steps[k]]``. Both forms return
+    the same integers; ``bounds_form`` picks one for the mesh."""
+    form = bounds_form(mesh)
+    with jax.named_scope(f"bounds/{form}"):
+        return (_bounds_by_count if form == "count"
+                else _bounds_by_search)(ts, steps, window)
 
 
 @jax.named_scope("prepare/correct")
@@ -301,9 +358,10 @@ MESH_AGG_OPS = ("sum", "avg", "count", "min", "max", "stddev", "stdvar",
 #   prepare  (batch version)                    counter-correction cumsum
 #                                               over [P, S], or the three
 #                                               exclusive prefix sums
-#   bounds   (batch version, grid, window)      the vmapped double
-#                                               searchsorted: (lo, hi) of
-#                                               every window
+#   bounds   (batch version, grid, window)      (lo, hi) of every window:
+#                                               a compare-and-count on the
+#                                               chip, the vmapped double
+#                                               searchsorted on a CPU mesh
 #   eval     (batch version, grid, window, fn)  boundary gathers, the
 #                                               all_gather of [dt, P_l, K, 7]
 #                                               partials over ``time``, the
@@ -364,14 +422,15 @@ def make_mesh_bounds(mesh: Mesh):
     time block's bounds local to its own [P_l, S_l] slice. Globally
     [P, dt·K] sharded (shard, time); only ever consumed by step programs
     with the same sharding, so the global layout is never materialized.
-    The vmapped double searchsorted here is the single most expensive op
-    of the whole query (~200 ms at P=8192, S=2048, K=256 on one CPU
-    device) — caching its output per (batch version, grid, window) is
-    what the split pipeline exists for."""
+    As a binary search this was three quarters of the device's time in
+    the listed cell (215.6 ms at P=16384, S=1024, K=32 on a v5e; ~200 ms
+    at P=8192, S=2048, K=256 on one CPU device); as a count it is 1.7 ms
+    there (``bounds_form``). Its output is still cached per (batch
+    version, grid, window): a repeated grid runs no bounds at all."""
 
     def bounds(ts, steps, window):
         def kernel(ts_l, steps_r, window_r):
-            lo, hi = _window_bounds(ts_l, steps_r, window_r)
+            lo, hi = _window_bounds(ts_l, steps_r, window_r, mesh)
             return lo.astype(jnp.int32), hi.astype(jnp.int32)
 
         return jax.shard_map(
@@ -391,11 +450,14 @@ def make_mesh_eval_delta(mesh: Mesh, fn: str, counter: bool | None = None):
     with Prometheus extrapolation. Output [P, K] per-series values,
     sharded on ``shard`` and replicated over ``time``.
 
-    The boundary gathers are the dominant remaining cost once bounds are
-    cached (XLA's gather is per-element on CPU: ~280 ms for the 7 gathers
-    at P=8192, K=256) and depend only on (data version, step grid,
-    window) — never on the query's grouping — so the engine caches THIS
-    stage's output and re-runs only the group reduce per query. ``cv``
+    The boundary gathers are the dominant device cost now that bounds
+    are a count (on a v5e ``jit_ev`` is ~60 of the 66 ms a request of the
+    listed cell keeps the device busy, ``avg_over_time`` at P=16384, K=32:
+    PERF.md §5, PR 32; a CPU figure: ~280 ms for the 7 gathers at P=8192,
+    K=256, XLA's gather being per-element there) and depend only on (data
+    version, step grid, window) — never on the query's grouping — so the
+    engine caches THIS stage's output and re-runs only the group reduce
+    per query. ``cv``
     (counter-corrected values) rides along for counter fns; ``raw``
     accompanies the host's pre-corrected values (see
     ``_rate_partials_from_bounds``).
@@ -507,7 +569,7 @@ def make_distributed_range_agg(mesh: Mesh, fn: str, num_groups: int,
     def step(ts, vals, valid, group_ids, steps, window):
         def kernel(ts_l, vals_l, valid_l, gid_l, steps_r, window_r):
             with jax.named_scope(f"eval/{fn}"):
-                lo, hi = _window_bounds(ts_l, steps_r, window_r)
+                lo, hi = _window_bounds(ts_l, steps_r, window_r, mesh)
                 csum, cnt, csum2 = _simple_prefixes(vals_l, valid_l)
                 parts = _simple_partials_from_bounds(
                     ts_l, vals_l, valid_l, csum, cnt, csum2, lo, hi)

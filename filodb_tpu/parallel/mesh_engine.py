@@ -38,6 +38,7 @@ import numpy as np
 from filodb_tpu.parallel.dist_query import (
     MESH_AGG_OPS,
     SPLIT_FNS,
+    bounds_form,
     make_mesh_bounds,
     make_mesh_eval_delta,
     make_mesh_eval_simple,
@@ -70,10 +71,14 @@ _M_COMPILE = {e: get_counter("filodb_mesh_compile_cache", {"event": e},
 _M_BATCH = {e: get_counter("filodb_mesh_batch_cache", {"event": e},
                            help="decoded+placed batch cache hits/misses")
             for e in ("hit", "miss")}
-_M_BOUNDS = {e: get_counter("filodb_mesh_bounds_cache", {"event": e},
-                            help="cached window-bounds (searchsorted) "
-                            "hits/misses on the split pipeline")
-             for e in ("hit", "miss")}
+_BOUNDS_HELP = ("cached window-bounds hits/misses on the split pipeline; "
+                "a miss runs the bounds program, in the form ``method``")
+_M_BOUNDS = {"hit": get_counter("filodb_mesh_bounds_cache", {"event": "hit"},
+                                help=_BOUNDS_HELP),
+             **{m: get_counter("filodb_mesh_bounds_cache",
+                               {"event": "miss", "method": m},
+                               help=_BOUNDS_HELP)
+                for m in ("count", "search")}}
 _M_EVAL = {e: get_counter("filodb_mesh_eval_cache", {"event": e},
                           help="cached per-series window evaluation "
                           "hits/misses on the split pipeline")
@@ -799,15 +804,17 @@ class MeshQueryEngine:
     def _window_bounds_cached(self, dkey, version, window, grid_bytes,
                               mesh, ts_d, grid_d, win_d):
         """Cached (lo, hi) window bounds per (batch version, step grid,
-        window) — the vmapped double searchsorted is the dominant per-query
-        cost of the fused path, and its inputs change only when data or
-        the query grid do."""
+        window): their inputs change only when data or the query grid do.
+        A miss runs the bounds program and says in which form (the span's
+        ``bounds`` tag, the counter's ``method``)."""
         bkey = (dkey, version, window, grid_bytes)
         hit = self._bounds_cache.get(bkey)
         if hit is not None:
             _M_BOUNDS["hit"].inc()
             return hit
-        _M_BOUNDS["miss"].inc()
+        form = bounds_form(mesh)
+        tag("bounds", form)
+        _M_BOUNDS[form].inc()
         bounds_fn = self._get_fn(("bounds",), lambda: make_mesh_bounds(mesh))
         out = bounds_fn(ts_d, grid_d, win_d)
         if len(self._bounds_cache) >= self._bounds_cache_cap:

@@ -157,6 +157,32 @@ def test_fused_max_over_time_compiles(topo, f32, shape):
            collectives)
 
 
+@pytest.mark.parametrize("shape", list(MESH_SHAPES))
+def test_bounds_count_on_the_chip_without_the_compare(topo, f32, shape):
+    """On a mesh of the chip's devices the bounds program is the count form,
+    and the chip's compiler fuses its compare into the row reduce: at the
+    gauge bucket with eight grids in a chunk (K 256) the temporaries in HBM
+    stay under ``ts`` itself, where the [P, K, S] compare of both edges would
+    be 512 of it, and no ``while`` of dependent gathers is left."""
+    from filodb_tpu.parallel import dist_query as dq
+
+    mesh = _mesh(topo, shape)
+    k = 8 * K
+    assert dq.bounds_form(mesh) == "count"
+
+    def sds(shp, spec):
+        return jax.ShapeDtypeStruct(shp, jnp.int32,
+                                    sharding=NamedSharding(mesh, spec))
+
+    compiled = dq.make_mesh_bounds(mesh).lower(
+        sds((P_GAUGE, S_GAUGE), P("shard", "time")), sds((k,), P()),
+        sds((), P())).compile()
+    text = _check(compiled, mesh.devices.size)
+    assert "gather(" not in text
+    ts_bytes = P_GAUGE * S_GAUGE * 4 // mesh.devices.size
+    assert compiled.memory_analysis().temp_size_in_bytes < ts_bytes
+
+
 def _one_chip_sds(topo):
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
     return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,

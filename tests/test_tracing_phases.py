@@ -227,9 +227,35 @@ class TestMeshPhases:
         assert tags["mesh-dispatch"]["form"] == "split"
         assert tags["mesh-dispatch"]["programs"] == 1
         assert tags["mesh-dispatch"]["eval_cache"] == "miss"
+        assert tags["mesh-dispatch"]["bounds"] == "search"  # a CPU mesh
         assert tags["mesh-fetch"]["bytes"] > 0
         assert tags["mesh-assemble"]["rows"] == tags["mesh-group"]["groups"]
         assert tags["finish"]["series"] == tags["mesh-group"]["groups"]
+
+    def test_another_end_compiles_nothing_and_names_the_bounds_form(
+            self, store):
+        """A query at another ``end`` is a new grid over a new batch: the
+        bounds cache misses and the bounds program runs again, in the form
+        the ``mesh-dispatch`` span and the counter name (the search, on a
+        CPU mesh) — and no program is traced or compiled for it."""
+        from filodb_tpu.utils.metrics import get_counter
+
+        svc = mesh_service(store)
+        svc.query_range(PROMQL, START + 600, 60, START + 1800)
+        fns = svc.mesh_engine._fns
+        compiled = {k: f._cache_size() for k, f in fns.items()}
+        assert ("bounds",) in compiled and set(compiled.values()) == {1}
+        missed = get_counter("filodb_mesh_bounds_cache",
+                             {"event": "miss", "method": "search"})
+        before = missed.value
+        tracing.flight_recorder().clear()
+        svc.query_range(PROMQL, START + 607, 60, START + 1807)
+        assert {k: f._cache_size() for k, f in fns.items()} == compiled
+        (e,) = entries("query")
+        (dispatch,) = [s for s in e["spans"] if s["name"] == "mesh-dispatch"]
+        assert dispatch["tags"]["eval_cache"] == "miss"
+        assert dispatch["tags"]["bounds"] == "search"
+        assert missed.value == before + 1
 
     def test_batch_cache_hit_opens_the_device_phases_only(self, store):
         svc = mesh_service(store)
