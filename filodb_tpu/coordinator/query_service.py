@@ -39,6 +39,13 @@ from filodb_tpu.utils.tracing import (
 
 query_latency = Histogram("query_latency_seconds")
 partial_results = get_counter("filodb_partial_results")
+# what the front door hands over in one pass of its loop: how many members
+# a query_range_many call carries (a single counts as a batch of one)
+_M_BATCHES = get_counter(
+    "filodb_query_batches", help="query_range_many calls")
+_M_BATCH_MEMBERS = get_counter(
+    "filodb_query_batch_members", help="queries carried by query_range_many "
+    "calls; over filodb_query_batches_total, the mean members a batch")
 
 
 class _BudgetCtx:
@@ -201,6 +208,8 @@ class QueryService:
         ``finish``."""
         t0 = time.perf_counter()
         n = len(queries)
+        _M_BATCHES.inc()
+        _M_BATCH_MEMBERS.inc(n)
         if n == 1:
             # a single-member batch has nothing to coalesce; take the
             # fully-traced query_range path so head-sampling and slow-query

@@ -65,6 +65,10 @@ _M_DISPATCH = {f: get_counter("filodb_mesh_dispatch", {"form": f},
                               "(split pipeline; fused masked scan of "
                               "window min/max)")
                for f in ("split", "fused")}
+_M_SAMPLES = get_counter(
+    "filodb_mesh_samples_scanned", help="samples of the placed batch a mesh "
+    "dispatch scans, once a dispatch: a batch-cache hit scans its batch "
+    "again, and a batch's members share one scan")
 _M_COMPILE = {e: get_counter("filodb_mesh_compile_cache", {"event": e},
                              help="compiled mesh program cache hits/misses")
               for e in ("hit", "miss")}
@@ -483,9 +487,11 @@ class MeshQueryEngine:
                         for lo in lows]
             if batch.is_histogram and low0.agg not in (None, "sum"):
                 return [None] * len(lows)
+            samples = int(batch.counts.sum())
+            _M_SAMPLES.inc(samples)
             for st in stats_objs:
                 st.series_scanned += len(keys)
-                st.samples_scanned += int(batch.counts.sum())
+                st.samples_scanned += samples
         else:
             placed = None
             parts = []
@@ -540,6 +546,7 @@ class MeshQueryEngine:
             if batch.is_histogram and low0.agg not in (None, "sum"):
                 # bucket-wise semantics only defined for sum (and raw)
                 return [None] * len(lows)
+            _M_SAMPLES.inc(samples)
             for st in stats_objs:
                 st.series_scanned += len(parts)
                 st.samples_scanned += samples

@@ -310,6 +310,16 @@ MESH_NAMES = [
     "filodb_mesh_eval_cache_total",
     "filodb_mesh_fallback_total",
     "filodb_mesh_hit_rate",
+    "filodb_mesh_samples_scanned_total",
+]
+
+
+# what the front door hands the query service in one call, and how many
+# queries ride in it (coordinator/query_service.py:query_range_many);
+# registered at query_service import
+QUERY_BATCH_NAMES = [
+    "filodb_query_batches_total",
+    "filodb_query_batch_members_total",
 ]
 
 
@@ -509,6 +519,11 @@ class TestMetricsScrape:
         # the batch build's read-path counter pair renders from import
         missing_b = [n for n in BATCH_NAMES if n not in names_present]
         assert not missing_b, f"missing batch metrics: {missing_b}"
+
+        # calls and members of query_range_many render from import
+        missing_qb = [n for n in QUERY_BATCH_NAMES
+                      if n not in names_present]
+        assert not missing_qb, f"missing query-batch metrics: {missing_qb}"
 
         # shard-replication + hedged-read families render at zero before
         # any replica set is configured
