@@ -167,7 +167,10 @@ class NativeShardCore:
         ``rows[i]`` (< 0: skip) of the C-contiguous ``ts`` int32 [P, S] and
         ``vals`` f32/f64 [P, S], at most ``caps[i]`` of them
         (``batch_count``'s count), and how many into ``counts[rows[i]]``.
-        Padding is left as it is."""
+        What lies beyond a row's count, and every row not named, is left as
+        it is: the padding is the allocator's, which ``build_batch`` asks
+        for arrays already filled with it (new, or a staging buffer of the
+        mesh engine refilled)."""
         n = len(pids)
         if not (ts.dtype == np.int32 and ts.flags.c_contiguous
                 and vals.flags.c_contiguous and vals.shape == ts.shape

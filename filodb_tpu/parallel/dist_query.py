@@ -594,11 +594,18 @@ def make_distributed_range_agg(mesh: Mesh, fn: str, num_groups: int,
 def shard_batch_arrays(mesh: Mesh, ts, vals, valid, group_ids, raw=None):
     """Place host arrays with (shard, time) shardings. ``raw`` [P, S]
     (optional — the uncorrected values accompanying the host's
-    pre-corrected pass) shards like ``vals``."""
+    pre-corrected pass) shards like ``vals``.
+
+    The placed arrays never alias the caller's memory: it overwrites its
+    staging buffers once they are ready. An accelerator's put is a copy. A
+    CPU client may KEEP an aligned numpy buffer as the device's own — under
+    ``may_alias=False`` too, which jax 0.9 honours for device arrays alone
+    (``pxla._shard_np_array`` drops it) — so on a CPU mesh the put is handed
+    a copy it may keep."""
     s2 = NamedSharding(mesh, P("shard", "time"))
     s1 = NamedSharding(mesh, P("shard"))
-    placed = (jax.device_put(ts, s2), jax.device_put(vals, s2),
-              jax.device_put(valid, s2), jax.device_put(group_ids, s1))
-    if raw is not None:
-        placed += (jax.device_put(raw, s2),)
-    return placed
+    keeps = mesh.devices.flat[0].platform == "cpu"
+    return tuple(jax.device_put(a.copy() if keeps else a, s)
+                 for a, s in ((ts, s2), (vals, s2), (valid, s2),
+                              (group_ids, s1), (raw, s2))
+                 if a is not None)

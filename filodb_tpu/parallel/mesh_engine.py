@@ -45,6 +45,7 @@ from filodb_tpu.parallel.dist_query import (
     make_mesh_group_reduce,
     make_mesh_prepare,
 )
+from filodb_tpu.parallel.staging import StagingPool
 from filodb_tpu.query import logical as lp
 from filodb_tpu.query.model import QueryStats, RangeVectorKey, StepMatrix
 from filodb_tpu.utils.metrics import GaugeFn, get_counter
@@ -124,8 +125,8 @@ def _placed_values(vals: np.ndarray) -> np.ndarray:
     """The array that is placed for an f64 host value array: ONE allocation
     in the device's float dtype (numpy's ``astype`` rounds as
     ``device_put`` would), then the NaN that marks padding set to 0 — the
-    kernels mask by validity, not by NaN. Always a copy: ``vals`` may be the
-    cached batch's own."""
+    kernels mask by validity, not by NaN. Always a copy, and never from the
+    staging pool: ``vals`` may be ``delta_host``'s cached array."""
     from filodb_tpu.query.engine.batch import device_float
 
     out = vals.astype(device_float())
@@ -225,10 +226,16 @@ class MeshQueryEngine:
     sidecars: bool = False
 
     _fns: dict = field(default_factory=dict)
-    # decoded global batches are reused across queries over unchanged data
-    # (the mesh analog of the exec path's per-shard batch cache)
+    # PLACED global batches are reused across queries over unchanged data
+    # (the mesh analog of the exec path's per-shard batch cache). An entry
+    # is (version, BatchHeader, keys, gids, out_keys, placed, is_counter):
+    # the device arrays and what a hit reads beside them — never the host
+    # ``ts``/``vals``, whose memory went back to ``_staging``
     _batch_cache: dict = field(default_factory=dict)
     _batch_cache_cap: int = 16
+    # the [P, S] host arrays one placement needs (build_batch's ts/vals,
+    # the validity mask), taken back once the placed arrays are ready
+    _staging: StagingPool = field(default_factory=StagingPool)
     # step-grid device arrays keyed by their bytes: repeated queries
     # re-upload identical grids every batch otherwise (a host→device
     # transfer per chunk)
@@ -423,15 +430,18 @@ class MeshQueryEngine:
         Phase spans, in order, tiling the caller's ``mesh-execute``:
         ``mesh-lookup`` (posting lists, paging), ``decode`` (``build_batch``:
         every sample written once into arrays of the placed shape — and, on
-        the ``raw`` lane, the placed dtype), ``mesh-group`` (keys and group
-        ids at the placed length), ``mesh-pad`` (no padding any more: the
-        lane choice, the ``split`` lane's conversion of the f64 batch or,
-        where the device dtype cannot correct it, its host f64 pre-pass —
-        tag ``copied_bytes``, 0 on the ``raw`` lane — the
-        histogram flatten, the validity mask), ``mesh-place`` (the put: the
-        batch's own ``ts``/``vals`` on the ``raw`` lane); a batch-cache hit
-        opens none of these five. Then ``mesh-dispatch``, ``mesh-fetch``,
-        ``mesh-assemble``."""
+        the ``raw`` lane, the placed dtype — that come from ``_staging``,
+        tag ``reused_bytes`` on ``batch-stack``), ``mesh-group`` (keys and
+        group ids at the placed length), ``mesh-pad`` (no padding any more:
+        the lane choice, the ``split`` lane's conversion of the f64 batch
+        or, where the device dtype cannot correct it, its host f64 pre-pass
+        — tag ``copied_bytes``, 0 on the ``raw`` lane — the histogram
+        flatten, the validity mask, written into a staging buffer too),
+        ``mesh-place`` (the put: the batch's own ``ts``/``vals`` on the
+        ``raw`` lane); a batch-cache hit opens none of these five and takes
+        nothing from the pool. Then ``mesh-dispatch``, ``mesh-fetch`` — at
+        whose end a miss waits for its placed arrays and gives the staging
+        buffers back, the one point where they return — ``mesh-assemble``."""
         stats_objs = stats if isinstance(stats, list) \
             else ([stats] if stats is not None else [])
         from filodb_tpu.core.memstore.odp import page_partitions
@@ -479,6 +489,7 @@ class MeshQueryEngine:
         cached = self._batch_cache.get(ckey)
         _M_BATCH["hit" if cached is not None and cached[0] == version
                  else "miss"].inc()
+        lease = None
         if cached is not None and cached[0] == version:
             _, batch, keys, gids, out_keys, placed, is_counter = cached
             if batch is None:
@@ -494,6 +505,9 @@ class MeshQueryEngine:
                 st.samples_scanned += samples
         else:
             placed = None
+            # the host arrays of THIS placement; dropped, not given back,
+            # by any return or exception before the end of mesh-fetch
+            lease = self._staging.lease()
             parts = []
             extra_by_obj: dict[int, list] = {}
             with span("mesh-lookup", shards=len(shards)) as sp:
@@ -530,15 +544,17 @@ class MeshQueryEngine:
             with span("decode", partitions=len(parts)) as sp:
                 # the batch is built at the shape that is placed, and on
                 # the raw lane (no host f64 pass follows) in the dtype too
-                batch = build_batch(
+                built = build_batch(
                     parts, chunk_start, chunk_end,
                     extra_by_obj=extra_by_obj or None,
                     mesh_multiples=(mesh.shape["shard"], mesh.shape["time"]),
-                    host_f64=lane != "raw")
+                    host_f64=lane != "raw", alloc=lease.take)
+                # what the cached entry keeps, and what a hit reads
+                batch = built.header()
                 samples = int(batch.counts.sum())
                 if sp is not None:
                     sp.tags.update(samples=samples,
-                                   shape=list(batch.vals.shape))
+                                   shape=list(built.vals.shape))
             # counter-ness of the scanned value column (same source the
             # exec path reads): decides delta's reset-correction semantics
             sdata = parts[0].schema.data
@@ -558,7 +574,7 @@ class MeshQueryEngine:
                 keys = [p.part_key.range_vector_key for p in parts]
                 # one id a padded row: padding series join group 0 and
                 # contribute nothing (no valid samples)
-                gids = np.zeros(batch.ts.shape[0], np.int32)
+                gids = np.zeros(built.ts.shape[0], np.int32)
                 if low0.agg is None:
                     out_keys = []
                 else:
@@ -573,8 +589,7 @@ class MeshQueryEngine:
         # (series, bucket) pair becomes one scalar row, group ids become
         # g*B + b, and the same associative kernels/combines apply. The
         # output un-flattens to [rows, K, B].
-        B = batch.vals.shape[2] \
-            if (batch is not None and batch.is_histogram) else 1
+        B = batch.buckets
         # delta mirrors the exec kernels: reset-corrected on counter
         # schemas, raw differences on gauges (rate/increase always correct)
         delta_counter = fn == "delta" and is_counter
@@ -598,22 +613,22 @@ class MeshQueryEngine:
 
         if placed is None:
             with span("mesh-pad", lane=lane) as sp:
-                ts_p, counts_p, gid_p = batch.ts, batch.counts, gids
+                ts_p, counts_p, gid_p = built.ts, built.counts, gids
                 raw_vals = None
-                if lane == "raw" or _device_correction_ok(batch.vals):
+                if lane == "raw" or _device_correction_ok(built.vals):
                     # raw values go straight to the device; on the split
                     # lane the counter correction is the cached prepare
                     # program's (make_mesh_prepare), so no host pre-pass
                     # runs at all
-                    host_vals = batch.vals
+                    host_vals = built.vals
                 else:
                     counter = fn in ("rate", "increase") or delta_counter
-                    host_vals = batch.delta_host(counter=counter)
+                    host_vals = built.delta_host(counter=counter)
                     if fn in ("rate", "increase"):
                         # rate/increase also need the raw values for the
                         # extrapolate-to-zero clamp (heuristic-only reference;
                         # delta never clamps, even when reset-corrected)
-                        raw_vals = batch.vals
+                        raw_vals = built.vals
                 if B > 1:
                     Pp_, S_ = ts_p.shape
                     host_vals = np.ascontiguousarray(
@@ -632,7 +647,11 @@ class MeshQueryEngine:
                 raw_p = None if raw_vals is None \
                     else _placed_values(raw_vals)
                 # counts do not shard along the time axis: a mask does
-                valid = np.arange(ts_p.shape[1])[None, :] < counts_p[:, None]
+                # (an int32 range, as counts are: numpy would widen both
+                # sides to int64 first, three times the compare's time)
+                valid = np.less(
+                    np.arange(ts_p.shape[1], dtype=np.int32)[None, :],
+                    counts_p[:, None], out=lease.take(ts_p.shape, np.bool_))
                 if sp is not None:
                     # the [P,S] arrays this phase made beside the mask:
                     # what is placed and is not the builder's own array
@@ -640,8 +659,8 @@ class MeshQueryEngine:
                         shape=list(vals_p.shape),
                         copied_bytes=sum(
                             a.nbytes for a in (ts_p, vals_p, raw_p)
-                            if a is not None and a is not batch.ts
-                            and a is not batch.vals))
+                            if a is not None and a is not built.ts
+                            and a is not built.vals))
             with span("mesh-place") as sp:
                 placed = shard_batch_arrays(mesh, ts_p, vals_p, valid,
                                             gid_p, raw_p)
@@ -756,6 +775,12 @@ class MeshQueryEngine:
             if sp is not None:
                 sp.tags["bytes"] = sum(a.nbytes
                                        for a in fetched.values())
+            if lease is not None:
+                # the puts only enqueued, and a program may never have read
+                # the new arrays (an eval-cache hit under the same dkey):
+                # the host memory is free to overwrite once they are ready
+                jax.block_until_ready(placed)
+                lease.give_back()
         with span("mesh-assemble", rows=nrows):
             for ci, (_, chunk, Kp) in enumerate(calls):
                 out_np = fetched[ci]

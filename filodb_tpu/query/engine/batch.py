@@ -13,8 +13,11 @@ other series (Python partitions, histogram columns, partitions with paged
 chunks, rows the core declines); span ``batch-stack`` the one allocation of
 ``ts``/``vals``/``counts`` at their final shape — the power of two over the
 largest kept count, rounded up to the caller's mesh axes where those do not
-divide it — then ``batch_fill``, which decodes and writes the native rows
-straight into them, and the row writes of the others. Which way a row goes
+divide it; ``ts``/``vals`` from the caller's allocator where it passes one
+(the mesh engine's staging pool, ``parallel/staging.py``: arrays an earlier
+placement gave back, refilled with padding), else new — then ``batch_fill``,
+which decodes and writes the native rows straight into them, and the row
+writes of the others. Which way a row goes
 is read off the partition, never chosen by an option; the batch is the same
 bit for bit. A caller that places the batch on a mesh without a host f64
 pass (``host_f64=False``) gets ``vals`` in the device's float dtype with 0
@@ -58,6 +61,15 @@ def _round_up(n: int, multiple: int) -> int:
     return -(-n // multiple) * multiple
 
 
+def fresh_array(shape, dtype, fill=None) -> np.ndarray:
+    """``build_batch``'s default allocator: a new array of ``fill`` (None:
+    uninitialised). Zeros come from ``calloc``, which touches no page
+    before the first write."""
+    if fill is None:
+        return np.empty(shape, dtype)
+    return np.zeros(shape, dtype) if fill == 0 else np.full(shape, fill, dtype)
+
+
 def device_float() -> np.dtype:
     """The numpy twin of ``kernels.fdtype()``: the dtype a float array has
     once it is on the device (f32 in a server, f64 under x64)."""
@@ -79,11 +91,35 @@ class _NativeRead(NamedTuple):
 
 
 @dataclass
+class BatchHeader:
+    """What a placed batch keeps of its host side once the device holds the
+    samples: all a batch-cache hit of the mesh engine reads."""
+
+    base_ts: int
+    counts: np.ndarray                # int32 [P]
+    part_ids: list[int]
+    les: np.ndarray | None = None
+
+    @property
+    def is_histogram(self) -> bool:
+        return self.les is not None
+
+    @property
+    def buckets(self) -> int:
+        """Rows a series takes once buckets are flattened into the series
+        axis: B of a histogram batch's [P, S, B], else 1."""
+        return 1 if self.les is None else len(self.les)
+
+
+@dataclass
 class SeriesBatch:
     """Padded batch of P series with up to S samples each.
 
-    ``ts``/``vals`` are numpy here; kernels convert to device arrays. For
-    histogram batches ``vals`` has shape [P, S, B] and ``les`` [B].
+    ``ts``/``vals`` are host (numpy) arrays. The exec path keeps the batch
+    in its shard's cache and uploads them once (:meth:`device_arrays`); the
+    mesh engine places them itself and keeps only :meth:`header`, because
+    their memory may be a staging buffer that the next build overwrites.
+    For histogram batches ``vals`` has shape [P, S, B] and ``les`` [B].
     """
 
     base_ts: int                      # epoch ms subtracted from all timestamps
@@ -102,6 +138,10 @@ class SeriesBatch:
     @property
     def is_histogram(self) -> bool:
         return self.vals.ndim == 3
+
+    def header(self) -> BatchHeader:
+        return BatchHeader(self.base_ts, self.counts, self.part_ids,
+                           self.les)
 
     def device_arrays(self):
         """(ts, vals, counts) as device arrays, uploaded once per batch —
@@ -179,7 +219,7 @@ def build_batch(partitions: list[TimeSeriesPartition], start: int, end: int,
                 extra_chunks: dict[int, list] | None = None,
                 extra_by_obj: dict[int, list] | None = None,
                 mesh_multiples: tuple[int, int] = (1, 1),
-                host_f64: bool = True) -> SeriesBatch:
+                host_f64: bool = True, alloc=fresh_array) -> SeriesBatch:
     """Decode chunks overlapping [start, end] into a SeriesBatch.
 
     ``start`` already includes the lookback/window extension; ``base_ts`` is
@@ -194,6 +234,11 @@ def build_batch(partitions: list[TimeSeriesPartition], start: int, end: int,
     (``delta_host``, the magnitude check) follows: scalar ``vals`` are then
     allocated in :func:`device_float` with 0 for padding, ready to place.
     Histogram batches keep f64 either way (the mesh flattens them first).
+
+    ``alloc(shape, dtype, fill)`` gives ``ts`` and ``vals``: a C-contiguous
+    array with ``fill`` in every cell. The default makes new arrays, which
+    the batch then owns for its whole life (the exec leaf's cached batches);
+    a caller that passes its own answers for the memory's lifetime.
     """
     P = len(partitions)
 
@@ -283,16 +328,18 @@ def build_batch(partitions: list[TimeSeriesPartition], start: int, end: int,
                       mesh_multiples[1])
         Pp = _round_up(_next_pow2(P) if pad_series else max(P, 1),
                        mesh_multiples[0])
-        ts_arr = np.full((Pp, S), TS_PAD, np.int32)
+        # the native fill and the row writes leave what lies beyond a row's
+        # count as ``alloc`` made it, and the kernels COUNT ``ts <= step``:
+        # every pad cell has to read TS_PAD
+        ts_arr = alloc((Pp, S), np.int32, TS_PAD)
         if les is not None:
-            B = len(les)
-            vals_arr = np.zeros((Pp, S, B), np.float64)
+            vals_arr = alloc((Pp, S, len(les)), np.float64, 0)
         elif host_f64:
-            vals_arr = np.full((Pp, S), np.nan, np.float64)
+            vals_arr = alloc((Pp, S), np.float64, np.nan)
         else:
             # in-count samples are never NaN (filtered above), so 0 for
             # padding is all the mesh kernels need beside the validity mask
-            vals_arr = np.zeros((Pp, S), device_float())
+            vals_arr = alloc((Pp, S), device_float(), 0)
         counts = np.zeros(Pp, np.int32)
         for r in reads:
             r.core.batch_fill(r.pids, r.col, start, end, r.rows, r.kept,

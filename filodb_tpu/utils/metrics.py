@@ -146,6 +146,16 @@ BATCH_ROWS_NATIVE = Counter("filodb_batch_rows", {"path": "native"},
 BATCH_ROWS_FALLBACK = Counter("filodb_batch_rows", {"path": "fallback"},
                               help=_BATCH_ROWS_HELP)
 
+# parallel/staging.py:StagingPool — bytes of [P, S] host staging arrays the
+# mesh engine handed to a batch build, by where the memory came from
+_BATCH_BUFFER_HELP = ("bytes of host staging arrays handed to a mesh batch "
+                      "build: taken back from an earlier placement, or "
+                      "fresh from the allocator")
+BATCH_BUFFER_REUSED = Counter("filodb_batch_buffer_bytes",
+                              {"source": "reused"}, help=_BATCH_BUFFER_HELP)
+BATCH_BUFFER_FRESH = Counter("filodb_batch_buffer_bytes",
+                             {"source": "fresh"}, help=_BATCH_BUFFER_HELP)
+
 
 def get_counter(name: str, tags: dict[str, str] | None = None,
                 help: str | None = None) -> Counter:

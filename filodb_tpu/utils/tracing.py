@@ -221,6 +221,17 @@ def tag(key: str, value) -> None:
         stack[-1].tags[key] = value
 
 
+def tag_add(key: str, n) -> None:
+    """Add ``n`` to a numeric tag of the innermost span open on this thread,
+    if tracing: a count that several calls under one span make up."""
+    if getattr(_local, "trace", None) is None:
+        return
+    stack = getattr(_local, "stack", None)
+    if stack:
+        tags = stack[-1].tags
+        tags[key] = tags.get(key, 0) + n
+
+
 def graft_spans(span_dicts: list, parent: Span | None = None,
                 **extra_tags) -> None:
     """Append a remote span tree (a list of ``Span.as_dict()`` dicts, as

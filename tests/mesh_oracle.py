@@ -297,15 +297,41 @@ CASES = {
 }
 
 
-def run_case(case: str, mesh_name: str, stores: dict, run: bool = False):
+def poison(pool) -> int:
+    """Overwrite every free staging buffer with garbage (0xA5 bytes: a
+    negative timestamp, which a window would count; a float that is neither
+    0 nor NaN; a bool that is neither). Returns the bytes poisoned."""
+    n = 0
+    for bufs in pool._free.values():
+        for buf in bufs:
+            buf.view(np.uint8).fill(0xA5)
+            n += buf.nbytes
+    return n
+
+
+def forget(eng: MeshQueryEngine) -> None:
+    """Drop every cache that could answer without reading a new build."""
+    for cache in (eng._batch_cache, eng._prep_cache, eng._bounds_cache,
+                  eng._eval_cache):
+        cache.clear()
+
+
+def run_case(case: str, mesh_name: str, stores: dict, run: bool = False,
+             poisoned: bool = False):
     """One cell of the equivalence matrix; ``stores`` caches built stores
-    by name across calls."""
+    by name across calls. ``poisoned``: the captured build writes into
+    staging buffers that an earlier run of the same query gave back and
+    that were then filled with garbage."""
     store, query, lane = CASES[case]
     ms = stores.get(store)
     if ms is None:
         ms = stores[store] = STORES[store]()
     ds, dtm = MESHES[mesh_name]
     eng = MeshQueryEngine(mesh=make_query_mesh(ds * dtm, dtm))
+    if poisoned:
+        capture_placed(eng, query, ms, run=True)
+        assert poison(eng._staging) == eng._staging.held_bytes > 0
+        forget(eng)
     cap = capture_placed(eng, query, ms, run=run)
     assert cap.tags["mesh-pad"]["lane"] == lane, (case, cap.tags["mesh-pad"])
     return cap

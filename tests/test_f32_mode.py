@@ -196,6 +196,8 @@ sys.path.insert(0, os.path.join(os.getcwd(), "tests"))
 from mesh_oracle import CASES, MESHES, placed_mismatches, run_case
 
 stores, out = {}, {}
+POISONED = ("raw-avg", "raw-max-fused", "split-small", "split-big",
+            "histogram-split")
 for case in CASES:
     for mesh_name in MESHES:
         cap = run_case(case, mesh_name, stores)
@@ -214,6 +216,21 @@ for case in CASES:
             "copied_bytes": cap.tags["mesh-pad"]["copied_bytes"],
             "vals_bytes": vals.nbytes,
         }
+
+# the same builds into POISONED staging buffers (an earlier run's, given
+# back and overwritten with garbage): f32 ``vals`` refilled with 0 on the
+# raw lane, f64 with NaN where a host pass follows
+for case in POISONED:
+    cap = run_case(case, "2x2", stores, poisoned=True)
+    out[f"poisoned/{case}"] = {
+        "bad": placed_mismatches(cap),
+        "dtype": str(cap.got[1].dtype),
+        "batch_dtype": str(cap.batch.vals.dtype),
+        "reused": cap.tags["batch-stack"]["reused_bytes"],
+        "built": cap.batch.ts.nbytes + cap.batch.vals.nbytes,
+        "mask_reused": cap.tags["mesh-pad"]["reused_bytes"],
+        "mask": cap.got[2].nbytes,
+    }
 
 # the batch build itself: rows filled by the native shard cores against the
 # per-series builder, in the dtype a server places (f32 here)
@@ -301,3 +318,20 @@ def test_f32_native_fill_is_the_per_series_builders_bits(rng, placed_f32):
     assert cell["bad"] == []
     assert cell["dtype"] == "float32" and cell["exact"]
     assert (cell["samples"] > 0) == (rng not in ("before", "after"))
+
+
+@pytest.mark.parametrize("case,batch_dtype", [
+    ("raw-avg", "float32"), ("raw-max-fused", "float32"),
+    ("split-small", "float64"), ("split-big", "float64"),
+    ("histogram-split", "float64")])
+def test_f32_poisoned_staging_buffers_place_the_parents_bits(
+        case, batch_dtype, placed_f32):
+    """x64 off: the raw lane's f32 ``vals`` (0 padding) and the split
+    lane's f64 (NaN padding, with and without the host pre-pass) written
+    into staging buffers full of garbage — the device receives the
+    parent's bits."""
+    cell = placed_f32[f"poisoned/{case}"]
+    assert cell["bad"] == []
+    assert (cell["dtype"], cell["batch_dtype"]) == ("float32", batch_dtype)
+    assert cell["reused"] == cell["built"] > 0
+    assert cell["mask_reused"] == cell["mask"] > 0

@@ -354,6 +354,9 @@ COSTMODEL_NAMES = [
 # family, both labels registered at utils/metrics import
 BATCH_NAMES = [
     "filodb_batch_rows_total",
+    # the mesh engine's staging pool (parallel/staging.py): bytes of host
+    # arrays a build wrote into, by where the memory came from
+    "filodb_batch_buffer_bytes_total",
 ]
 
 
@@ -599,6 +602,58 @@ class TestMetricsScrape:
             assert moved >= 5 and moved % 5 == 0
         else:
             assert moved == 0
+
+    @pytest.mark.parametrize("source", ["reused", "fresh"])
+    def test_batch_buffer_family_is_scraped_with_both_sources(self, server,
+                                                              source):
+        """``filodb_batch_buffer_bytes_total{source=...}`` renders before
+        any query under one HELP/TYPE header; a server's mesh queries over
+        new chunk ranges move it by whole ``[P, S]`` arrays — ``fresh`` on
+        the first build of a shape, ``reused`` after."""
+        srv = server
+        family = "filodb_batch_buffer_bytes_total"
+        line = f'{family}{{source="{source}"}}'
+
+        def values(text):
+            return {src: float(ln.rsplit(" ", 1)[1])
+                    for ln in text.splitlines() for src in ("reused", "fresh")
+                    if ln.startswith(f'{family}{{source="{src}"}}')}
+
+        before = _scrape(srv.http.port)
+        assert before.count(f"# TYPE {family} counter") == 1
+        assert before.count(f"# HELP {family} ") == 1
+        assert any(ln.startswith(line) for ln in before.splitlines())
+        with socket.create_connection(("127.0.0.1",
+                                       srv.gateway.port)) as s:
+            for i in range(150):
+                ts_ns = (START + i * 10) * 1_000_000_000
+                s.sendall(f"buffer_metric_{source},host=h{i % 5},_ws_=demo,"
+                          f"_ns_=App-0 value={i} {ts_ns}\n".encode())
+        want = sum(s2.stats.rows_ingested.value for s2 in
+                   srv.memstore.shards_for("timeseries")) + 150
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline:
+            srv.gateway.sink.flush()
+            if sum(s2.stats.rows_ingested.value for s2 in
+                   srv.memstore.shards_for("timeseries")) >= want:
+                break
+            time.sleep(0.3)
+        moved = []
+        # the aggregation is in the batch cache's key: two misses that
+        # build the same shapes, the second into the first's arrays
+        for agg in ("sum", "max"):
+            at = values(_scrape(srv.http.port))
+            with urllib.request.urlopen(
+                    f"http://127.0.0.1:{srv.http.port}/promql/timeseries/"
+                    f"api/v1/query_range?query={agg}(max_over_time("
+                    f"buffer_metric_{source}%5B1m%5D))"
+                    f"&start={START}&end={START + 1500}&step=60") as r:
+                assert r.status == 200
+            now = values(_scrape(srv.http.port))
+            moved.append({k: now[k] - at[k] for k in now})
+        first, second = moved
+        assert sum(first.values()) == sum(second.values()) > 0
+        assert second == {"fresh": 0, "reused": sum(first.values())}
 
     def test_flush_and_query_counters_move(self, server):
         srv = server
