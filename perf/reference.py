@@ -3,16 +3,53 @@ window looked at (no prefix sums, no binary search, no batching across
 steps). Imports nothing of ``filodb_tpu``: the program may change, the
 yardstick may not.
 
-``ref_rate``, ``ref_max_over_time``, ``ref_group``, the ``TIE_BAND`` rule and
-``assert_between`` are copies of ``chip_smoke.py``'s (PR 21), with the scrape
-interval an argument and ``avg`` added. ``check_panel`` holds one Prom JSON
-answer to the reference, driven by the panel's ``check`` block in its cell
-file.
+``check_panel`` holds one Prom JSON answer to the reference, driven by the
+panel's ``check`` block in its cell file. The block's ``fn`` (a window
+function) and ``post.fn`` (a step over the aggregate) each name a file
+under ``perf/forms/``, found by that name as a generator or a per-layer
+reader is: a panel with another function brings its form as a new file and
+edits nothing here. ``agg`` (``ref_group``), ``topk`` and the bucket matrix
+of a histogram are this module's. ``ref_group``, ``assert_between`` and,
+in ``forms/rate.py``, the tie band are copies of ``chip_smoke.py``'s (PR
+21), with ``avg`` added. A metric's ``vals`` is f64 ``[N, S]``, or a mapping
+of columns of which one is a histogram ``{"les": f64 [B], "counts": int64
+[N, S, B]}`` (PR 36).
 """
 
 from __future__ import annotations
 
+import importlib.util
+import os
+import types
+
 import numpy as np
+
+FORMS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "forms")
+_forms: dict = {}
+
+
+def load_form(name: str):
+    """``perf/forms/<name>.py``. A window function's form has
+    ``bounds(ref, ts, vals, steps_ms, window_ms, interval_ms)`` → (low,
+    high) f64 [N, K] per series, the same array twice where nothing is
+    decided two ways. A ``post`` step's form has ``compare(ref, check,
+    body, steps_ms, lo, hi, groups, what)`` → the numbers compared, raising
+    ``Mismatch``, and ``answer(ref, check, rows, les)`` → f64 [K] of one
+    group's rows. ``ref`` is ``LENT``, what this module lends a form:
+    handed over and not imported, since ``perf/`` is loaded by path under
+    more than one name and a second copy would raise another ``Mismatch``
+    than the caller catches."""
+    if name not in _forms:
+        path = os.path.join(FORMS, f"{name}.py")
+        if not os.path.isfile(path):
+            raise KeyError(f"no reference form {name!r}: a check block's "
+                           f"`fn` or `post.fn` names a file under {FORMS}")
+        spec = importlib.util.spec_from_file_location(
+            f"perf_forms_{name}", path)
+        _forms[name] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(_forms[name])
+    return _forms[name]
+
 
 class Mismatch(AssertionError):
     """An answer that is not the reference's. Raised, never ``assert``ed:
@@ -24,15 +61,6 @@ def require(ok, *what) -> None:
         raise Mismatch(" ".join(str(w) for w in what))
 
 
-# ``extrapolatedRate`` compares a duration with 1.1 average intervals. With
-# integer counters on a regular scrape that comparison is often an exact tie,
-# which f64, f32 on a CPU and f32 on a TPU each round their own way; the
-# extension it decides is worth ~10% of that one series' rate. The reference
-# therefore evaluates both outcomes of any comparison within this relative
-# band of its threshold and accepts an answer between the two.
-TIE_BAND = 1e-4
-
-
 def _window_columns(ts, t, window_ms, interval_ms):
     """Column slice that holds the window (t-w, t] of every series, given
     that sample j of each series lies in [first + j*interval, +interval)."""
@@ -42,77 +70,15 @@ def _window_columns(ts, t, window_ms, interval_ms):
     return int(c0), int(max(c1, c0))
 
 
-def ref_rate(ts, vals, steps_ms, window_ms, interval_ms, nudge=0.0):
-    """Prometheus ``rate`` as published (``extrapolatedRate``): counter
-    resets added back, extrapolated to the window's edges unless the first
-    or last sample is further than 1.1 average intervals from the edge, and
-    never below a zero crossing. ts int64 ms [N, S], vals f64 [N, S] →
-    f64 [N, K], NaN where a window holds fewer than two samples. ``nudge``
-    moves the 1.1-interval threshold by that relative amount."""
-    n_series = ts.shape[0]
-    out = np.full((n_series, len(steps_ms)), np.nan)
-    rows = np.arange(n_series)
+def windows(ts, steps_ms, window_ms, interval_ms):
+    """(k, t, column slice, mask [N, columns]) of each step t whose slice
+    is not empty: the mask says which of the slice's samples lie in the
+    window (t-w, t]. What a window function's form walks."""
     for k, t in enumerate(steps_ms):
         c0, c1 = _window_columns(ts, int(t), window_ms, interval_ms)
-        if c1 - c0 < 2:
-            continue
-        tsb, vb = ts[:, c0:c1], vals[:, c0:c1]
-        m = (tsb > t - window_ms) & (tsb <= t)
-        n = m.sum(1)
-        i0 = m.argmax(1)
-        i1 = m.shape[1] - 1 - m[:, ::-1].argmax(1)
-        pair = m[:, 1:] & m[:, :-1]
-        drop = pair & (vb[:, 1:] < vb[:, :-1])
-        inc = vb[rows, i1] - vb[rows, i0] + np.where(drop, vb[:, :-1],
-                                                     0.0).sum(1)
-        t_first = tsb[rows, i0] / 1000.0
-        t_last = tsb[rows, i1] / 1000.0
-        sampled = t_last - t_first
-        with np.errstate(divide="ignore", invalid="ignore"):
-            avg = sampled / (n - 1)
-            d_start = t_first - (t - window_ms) / 1000.0
-            d_end = t / 1000.0 - t_last
-            to_zero = np.where(inc > 0, sampled * vb[rows, i0] / inc, np.inf)
-            d_start = np.minimum(d_start, to_zero)
-            limit = avg * 1.1 * (1.0 + nudge)
-            extend = sampled + np.where(d_start < limit, d_start, avg / 2) \
-                + np.where(d_end < limit, d_end, avg / 2)
-            r = inc * (extend / sampled) / (window_ms / 1000.0)
-        out[:, k] = np.where(n >= 2, r, np.nan)
-    return out
-
-
-def _ref_window(ts, vals, steps_ms, window_ms, interval_ms, how):
-    out = np.full((ts.shape[0], len(steps_ms)), np.nan)
-    for k, t in enumerate(steps_ms):
-        c0, c1 = _window_columns(ts, int(t), window_ms, interval_ms)
-        if c1 <= c0:
-            continue
-        tsb = ts[:, c0:c1]
-        m = (tsb > t - window_ms) & (tsb <= t)
-        if how == "max":
-            v = np.where(m, vals[:, c0:c1], -np.inf).max(1)
-        else:
-            with np.errstate(invalid="ignore", divide="ignore"):
-                v = np.where(m, vals[:, c0:c1], 0.0).sum(1) / m.sum(1)
-        out[:, k] = np.where(m.any(1), v, np.nan)
-    return out
-
-
-def ref_max_over_time(ts, vals, steps_ms, window_ms, interval_ms):
-    return _ref_window(ts, vals, steps_ms, window_ms, interval_ms, "max")
-
-
-def ref_avg_over_time(ts, vals, steps_ms, window_ms, interval_ms):
-    return _ref_window(ts, vals, steps_ms, window_ms, interval_ms, "avg")
-
-
-def ref_rate_bounds(ts, vals, steps_ms, window_ms, interval_ms):
-    """(low, high) per series: the rate with every near-tie at the
-    extrapolation threshold decided one way, and the other."""
-    a = ref_rate(ts, vals, steps_ms, window_ms, interval_ms, -TIE_BAND)
-    b = ref_rate(ts, vals, steps_ms, window_ms, interval_ms, +TIE_BAND)
-    return np.minimum(a, b), np.maximum(a, b)
+        if c1 > c0:
+            tsb = ts[:, c0:c1]
+            yield k, int(t), slice(c0, c1), (tsb > t - window_ms) & (tsb <= t)
 
 
 def ref_group(per_series, gids, n_groups, how):
@@ -124,13 +90,14 @@ def ref_group(per_series, gids, n_groups, how):
         if not len(rows):
             continue
         present = ~np.isnan(rows)
+        kind = rows.dtype.type
         if how == "max":
-            agg = np.where(present, rows, -np.inf).max(0)
+            agg = np.where(present, rows, kind(-np.inf)).max(0)
         else:
-            agg = np.where(present, rows, 0.0).sum(0)
+            agg = np.where(present, rows, kind(0)).sum(0)
             if how == "avg":
                 with np.errstate(invalid="ignore", divide="ignore"):
-                    agg = agg / present.sum(0)
+                    agg = agg / present.sum(0).astype(kind)
         out[g] = np.where(present.any(0), agg, np.nan)
     return out
 
@@ -158,11 +125,13 @@ def assert_between(got, lo, hi, rtol, what="") -> float:
 # ---------------------------------------------------------------------------
 # one Prom JSON answer against the reference
 
-_WINDOW_FNS = {"max_over_time": ref_max_over_time,
-               "avg_over_time": ref_avg_over_time}
+def fmt_le(le: float) -> str:
+    """A bucket's bound as the program's Prom JSON labels its row
+    (``http/promjson.py:_fmt``): ``repr`` of the f64, ``+Inf``."""
+    return "+Inf" if np.isposinf(le) else repr(float(le))
 
 
-def _matrix(body: dict, steps_ms, by: str | None):
+def matrix(body: dict, steps_ms, by: str | None):
     """Prom matrix JSON → ({group label value: row index}, f64 [R, K]) with
     NaN where a row shows no sample at a step."""
     require(body.get("status") == "success", body.get("error", body))
@@ -182,57 +151,35 @@ def _matrix(body: dict, steps_ms, by: str | None):
     return names, got
 
 
-def check_panel(check: dict, metrics: dict, interval_ms: int, key,
-                start_s: int, end_s: int, step_s: int, body: dict,
-                rng) -> dict:
-    """Hold one ``query_range`` answer to the reference. ``check`` is the
-    panel's block in its cell file: ``metric``, ``select`` (label → value
-    template with ``{key}``), ``fn`` and ``window_s``, ``agg`` with ``by``
-    (a label, or null for one group), optionally ``topk`` and
-    ``sample_groups`` (hold that many seeded groups to the reference, and
-    only count the rest). Raises ``Mismatch`` on any difference."""
-    m = metrics[check["metric"]]
-    keep = np.ones(len(m["ts"]), bool)
-    for label, template in check.get("select", {}).items():
-        keep &= m["labels"][label] == template.format(key=key)
-    by = check.get("by")
-    group_of = m["labels"][by][keep] if by else np.zeros(keep.sum(), "U1")
-    names_all = np.unique(group_of)
-    steps_ms = np.arange(start_s, end_s + 1, step_s, dtype=np.int64) * 1000
-    names, got = _matrix(body, steps_ms, by)
-    n_sample = check.get("sample_groups")
-    if n_sample and n_sample < len(names_all):
-        chosen = rng.choice(names_all, n_sample, replace=False)
-    else:
-        chosen = names_all
-    pick = np.isin(group_of, chosen)
-    ts, vals = m["ts"][keep][pick], m["vals"][keep][pick]
-    chosen = np.unique(group_of[pick])
-    gids = np.searchsorted(chosen, group_of[pick])
-    window_ms = int(check["window_s"]) * 1000
-    if check["fn"] == "rate":
-        lo, hi = ref_rate_bounds(ts, vals, steps_ms, window_ms, interval_ms)
-    else:
-        lo = hi = _WINDOW_FNS[check["fn"]](ts, vals, steps_ms, window_ms,
-                                           interval_ms)
-    same = hi is lo
-    lo = ref_group(lo, gids, len(chosen), check["agg"])
-    hi = lo if same else ref_group(hi, gids, len(chosen), check["agg"])
-    rtol = float(check["rtol"])
-    what = f"{check['fn']} key={key} end={end_s}"
-    k = check.get("topk")
-    if not k:
-        shown = ~np.isnan(lo).all(1)  # Prom drops a row with no sample
-        want = int(shown.sum()) if len(chosen) == len(names_all) \
-            else len(names_all)
-        require(len(names) == want,
-                f"{what}: {len(names)} groups answered, {want} exist")
-        rows = [names.get(str(c), -1) for c in chosen[shown]]
-        require(min(rows, default=0) >= 0, f"{what}: a group is missing")
-        worst = assert_between(got[rows], lo[shown], hi[shown], rtol, what)
-        return {"groups_answered": len(names), "groups_checked": len(rows),
-                "worst_rel_error": worst}
-    # top k of the groups at each step: a row is shown only at its steps
+# what a form may use of this module (``load_form``)
+LENT = types.SimpleNamespace(
+    Mismatch=Mismatch, require=require, windows=windows, matrix=matrix,
+    assert_between=assert_between, fmt_le=fmt_le, ref_group=ref_group)
+
+
+def _compare_groups(check, body, steps_ms, lo, hi, groups, what):
+    """One row a group, every cell within ``rtol`` of [lo, hi]. ``groups``
+    is ``evaluate``'s."""
+    chosen, n_all, _ = groups
+    names, got = matrix(body, steps_ms, check.get("by"))
+    shown = ~np.isnan(lo).all(1)  # Prom drops a row with no sample
+    want = int(shown.sum()) if len(chosen) == n_all else n_all
+    require(len(names) == want,
+            f"{what}: {len(names)} groups answered, {want} exist")
+    rows = [names.get(str(c), -1) for c in chosen[shown]]
+    require(min(rows, default=0) >= 0, f"{what}: a group is missing")
+    worst = assert_between(got[rows], lo[shown], hi[shown],
+                           float(check["rtol"]), what)
+    return {"groups_answered": len(names), "groups_checked": len(rows),
+            "worst_rel_error": worst}
+
+
+def _compare_topk(check, body, steps_ms, lo, hi, groups, what):
+    """Top k of the groups at each step: a row is shown only at its
+    steps."""
+    chosen = groups[0]
+    k, rtol = check["topk"], float(check["rtol"])
+    names, got = matrix(body, steps_ms, check.get("by"))
     order = {str(c): g for g, c in enumerate(chosen)}
     require(set(names) <= set(order), f"{what}: unknown groups in the answer")
     ref_row = np.array([order[n] for n in names], np.int64)
@@ -254,3 +201,135 @@ def check_panel(check: dict, metrics: dict, interval_ms: int, key,
                            np.where(cell, hi[ref_row], 0.0), rtol, what)
     return {"rows_shown": len(names), "cells_checked": cells,
             "worst_rel_error": worst}
+
+
+def _compare_buckets(check, body, steps_ms, lo, hi, groups, what):
+    """A histogram answer with no ``post``: the bucket matrix of the one
+    group, a row a bucket, read by its ``le`` label. lo, hi [1, B, K]."""
+    require(lo.shape[0] == 1 and not check.get("by"),
+            f"{what}: a bucket matrix is compared for one group only")
+    les = groups[2]
+    names, got = matrix(body, steps_ms, "le")
+    want = [fmt_le(le) for le in les]
+    missing = [n for n in want if n not in names]
+    require(not missing and len(names) == len(want),
+            f"{what}: {len(names)} le rows answered of {len(want)}; "
+            f"missing {missing[:4]}")
+    worst = assert_between(got[[names[n] for n in want]], lo[0], hi[0],
+                           float(check["rtol"]), what)
+    return {"buckets_answered": len(names), "worst_rel_error": worst}
+
+
+def _comparison(check: dict, histogram: bool):
+    if check.get("post"):
+        form = load_form(check["post"]["fn"])
+        return lambda *args: form.compare(LENT, *args)
+    if check.get("topk"):
+        return _compare_topk
+    return _compare_buckets if histogram else _compare_groups
+
+
+def _value_column(vals):
+    """(f64 [N, S], None), or of a mapping its histogram (int64 [N, S, B],
+    les)."""
+    if isinstance(vals, dict):
+        h = next(c for c in vals.values() if isinstance(c, dict))
+        return h["counts"], np.asarray(h["les"], np.float64)
+    return vals, None
+
+
+def evaluate(check: dict, metrics: dict, interval_ms: int, key,
+             start_s: int, end_s: int, step_s: int, rng, cast=None) -> tuple:
+    """The reference's own answer to a panel: (steps_ms, lo, hi, groups).
+    lo and hi are f64 [G, K] a group — [G, B, K] for a histogram column —
+    the same array where nothing is decided two ways; ``groups`` is (the
+    groups evaluated, how many exist, a histogram's les or None). ``cast``
+    is applied to the samples first: the control evaluates in a lower
+    precision."""
+    m = metrics[check["metric"]]
+    keep = np.ones(len(m["ts"]), bool)
+    for label, template in check.get("select", {}).items():
+        keep &= m["labels"][label] == template.format(key=key)
+    by = check.get("by")
+    group_of = m["labels"][by][keep] if by else np.zeros(keep.sum(), "U1")
+    names_all = np.unique(group_of)
+    steps_ms = np.arange(start_s, end_s + 1, step_s, dtype=np.int64) * 1000
+    n_sample = check.get("sample_groups")
+    if n_sample and n_sample < len(names_all):
+        chosen = rng.choice(names_all, n_sample, replace=False)
+    else:
+        chosen = names_all
+    pick = np.isin(group_of, chosen)
+    vals, les = _value_column(m["vals"])
+    ts, vals = m["ts"][keep][pick], vals[keep][pick]
+    chosen = np.unique(group_of[pick])
+    gids = np.searchsorted(chosen, group_of[pick])
+    buckets = 1 if les is None else len(les)
+    if les is not None:
+        # a row a (series, bucket); group ids g·B + b
+        vals = vals.transpose(0, 2, 1).reshape(-1, vals.shape[1]) \
+            .astype(np.float64)
+        ts = np.repeat(ts, buckets, axis=0)
+        gids = (gids[:, None] * buckets + np.arange(buckets)).ravel()
+    if cast is not None:
+        vals = cast(vals)
+    lo, hi = load_form(check["fn"]).bounds(
+        LENT, ts, vals, steps_ms, int(check["window_s"]) * 1000, interval_ms)
+    same = hi is lo
+    lo = ref_group(lo, gids, len(chosen) * buckets, check["agg"])
+    hi = lo if same else ref_group(hi, gids, len(chosen) * buckets,
+                                   check["agg"])
+    if les is not None:
+        lo = lo.reshape(len(chosen), buckets, -1)
+        hi = hi.reshape(len(chosen), buckets, -1)
+    return steps_ms, lo, hi, (chosen, len(names_all), les)
+
+
+def answer_body(check: dict, steps_ms, rows, groups) -> dict:
+    """Prom matrix JSON of ``rows`` (a ``lo`` or ``hi`` of ``evaluate``) as
+    the program would answer the panel — the reference put in the program's
+    place: what a control, and a test of a comparison, starts from."""
+    by = check.get("by")
+    labelled = [({by: str(c)} if by else {}, r)
+                for c, r in zip(groups[0], rows)]
+    if check.get("post"):
+        les = groups[2]
+        answer = load_form(check["post"]["fn"]).answer
+        labelled = [(lab, answer(LENT, check, r, les)) for lab, r in labelled]
+    elif groups[2] is not None:
+        labelled = [({**lab, "le": fmt_le(le)}, r[b])
+                    for lab, r in labelled for b, le in enumerate(groups[2])]
+    elif check.get("topk"):
+        # exactly k a step: a tie at the k-th place goes to the earlier row
+        table = np.stack([r for _, r in labelled])
+        rank = np.argsort(np.argsort(-np.nan_to_num(table, nan=-np.inf),
+                                     axis=0, kind="stable"), axis=0)
+        labelled = [(lab, np.where(rank[g] < check["topk"], r, np.nan))
+                    for g, (lab, r) in enumerate(labelled)]
+    return {"status": "success", "data": {"resultType": "matrix", "result": [
+        {"metric": lab, "values": [[t / 1000.0, repr(float(v))]
+                                   for t, v in zip(steps_ms, r)
+                                   if not np.isnan(v)]}
+        for lab, r in labelled if not np.isnan(r).all()]}}
+
+
+def check_panel(check: dict, metrics: dict, interval_ms: int, key,
+                start_s: int, end_s: int, step_s: int, body: dict,
+                rng) -> dict:
+    """Hold one ``query_range`` answer to the reference. ``check`` is the
+    panel's block in its cell file: ``metric``, ``select`` (label → value
+    template with ``{key}``), ``fn`` (a form) and ``window_s``, ``agg``
+    (``sum``, ``max``, ``avg``) with ``by`` (a label, or null for one
+    group), ``rtol``, and optionally ``topk``, ``post`` (its ``fn`` a form)
+    and ``sample_groups`` (hold that many seeded groups to the reference,
+    and only count the rest). A histogram column is evaluated a bucket: the
+    ``[N·B, S]`` view of its cumulative counts under the same ``fn`` (for
+    ``rate``: counter semantics and the extrapolation a bucket), ``agg`` a
+    bucket over the group; the answer is the bucket matrix, rows read by
+    ``le``, or what ``post`` makes of it. Raises ``Mismatch`` on any
+    difference."""
+    steps_ms, lo, hi, groups = evaluate(
+        check, metrics, interval_ms, key, start_s, end_s, step_s, rng)
+    what = f"{check['fn']} key={key} end={end_s}"
+    return _comparison(check, groups[2] is not None)(
+        check, body, steps_ms, lo, hi, groups, what)

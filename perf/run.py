@@ -8,6 +8,15 @@ generator, layout, guarantees), ``perf/generators/<generator>.py``,
 ``perf/cells/<cell>.json`` (traffic) and, in a traced run,
 ``perf/layer_metrics/<metric>.py`` for each per-layer metric.
 
+    python3 perf/run.py --cell-file <cell.json> --config-file <config.json>
+                        [--chips N] [--metrics a,b] --seed <n> ...
+
+runs a cell that ``BENCHMARK.json`` does not list yet, through the same
+``run()``: to try a deployment on the chip before a PR lists it. The
+generator is still found by the configuration's ``generator``. Its per-layer
+metrics are every entry of ``per_layer`` without a ``workloads`` list plus
+those ``--metrics`` names; its line carries ``"unlisted": true``.
+
 Order of a run: start-up (compile cache) → generate from the seed → load →
 the default server's query service and HTTP front over the loaded store →
 warm-up of this cell's shapes → the window, driven by ``perf/client.py`` in
@@ -283,6 +292,82 @@ def verify(cell: dict, config: dict, metrics: dict, port: int, sent: list,
             "worst_rel_error": worst, "checks": checks}
 
 
+def compared(cell: dict, checked: dict, failed: int) -> dict:
+    """Each number that decides ``correct`` beside its limit: a panel's
+    worst relative error over the answers checked (null where one was
+    refused outright: the reason is under ``refused``) against its
+    ``rtol``, and the requests that failed against 0."""
+    out = {}
+    for p, panel in enumerate(cell["panels"]):
+        mine = [c for c in checked["checks"] if c["panel"] == p]
+        errors = [c["error"] for c in mine if "error" in c]
+        out[f"panel{p}_worst_rel_error"] = {
+            "value": None if errors or not mine else max(
+                c["worst_rel_error"] for c in mine),
+            "limit": float(panel["check"]["rtol"]), "answers": len(mine),
+            **({"refused": errors[0][:300]} if errors else {})}
+    out["failed_requests"] = {"value": failed, "limit": 0}
+    return out
+
+
+_KEPT_TAGS = ("reused_bytes", "copied_bytes", "native_rows", "fallback_rows")
+
+
+def slowest_request(done: list, t0: float, slice_wall,
+                    entries: list) -> dict:
+    """When the window's largest latency came, and what covered it: seconds
+    into the window at which it was sent; in a traced run the profiler
+    slice's start and end on the same clock (stopping a trace holds the
+    process) and the longest recorded entry that ended inside the request,
+    with its three longest spans. An entry far shorter than the request
+    says that the wait was outside the query service."""
+    _, _, sent, took, _, _ = max(done, key=lambda r: r[3])
+    out = {"sent_at_s": sent - t0, "latency_ms": took * 1e3}
+    if slice_wall:
+        out["trace_slice_s"] = [slice_wall[0] - t0, slice_wall[1] - t0]
+    inside = [e for e in entries if sent <= e["when"] <= sent + took + 0.05]
+    if inside:
+        e = max(inside, key=lambda e: e["duration_ms"])
+        spans = sorted(e.get("spans") or [], key=lambda s: -s["duration_ms"])
+        out["entry_ms"] = e["duration_ms"]
+        out["spans"] = [[s["name"], s["duration_ms"]] for s in spans[:3]]
+    return out
+
+
+def window_detail(latencies_ms: list, entries: list, timed: bool) -> dict:
+    """What the readers drop, for whoever sizes the next cell: the window's
+    latency quartiles, mean, p95 and largest (a timed run only), and of the
+    recorded spans the byte and row tags, a span name each — how many spans
+    carried the tag, its median and its largest — and the shapes built."""
+    import numpy as np
+
+    out = {}
+    if timed:
+        q1, q2, q3 = statistics.quantiles(latencies_ms, n=4) \
+            if len(latencies_ms) > 1 else latencies_ms * 3
+        out["latency_ms"] = {
+            "requests": len(latencies_ms), "min": min(latencies_ms),
+            "q1": q1, "median": q2, "q3": q3,
+            "mean": statistics.fmean(latencies_ms),
+            "p95": float(np.percentile(latencies_ms, 95)),
+            "max": max(latencies_ms)}
+    tags, shapes = {}, {}
+    for e in entries:
+        for s in e.get("spans") or []:
+            for k, v in (s.get("tags") or {}).items():
+                if k in _KEPT_TAGS:
+                    tags.setdefault(f"{s['name']}.{k}", []).append(v)
+                elif k == "shape":
+                    key = f"{s['name']} {list(v)}"
+                    shapes[key] = shapes.get(key, 0) + 1
+    if entries:
+        out["span_tags"] = {k: {"spans": len(v),
+                                "median": statistics.median(v),
+                                "max": max(v)} for k, v in sorted(tags.items())}
+        out["shapes"] = dict(sorted(shapes.items(), key=lambda kv: -kv[1])[:8])
+    return out
+
+
 def run(args, bench: dict, workload: dict, device: dict) -> dict:
     import jax
     import numpy as np
@@ -295,8 +380,11 @@ def run(args, bench: dict, workload: dict, device: dict) -> dict:
 
     cache_dir = startup.configure_jax()
     watch = CompileWatch()
-    config = read_json(HERE, "configs", f"{workload['config']}.json")
-    cell = read_json(HERE, "cells", f"{workload['name']}.json")
+    unlisted = "cell_file" in workload
+    config = read_json(workload.get("config_file") or os.path.join(
+        HERE, "configs", f"{workload['config']}.json"))
+    cell = read_json(workload.get("cell_file") or os.path.join(
+        HERE, "cells", f"{workload['name']}.json"))
     if cell["config"] != workload["config"] \
             or cell["loop"]["kind"] != "closed":
         raise ValueError(f"{workload['name']}: the cell file names another "
@@ -392,6 +480,11 @@ def run(args, bench: dict, workload: dict, device: dict) -> dict:
             if xplane:
                 trace = trace_reduce.reduce(
                     xplane, host_spans(entries, requests))
+        if args.out:
+            # every request's send time and latency, and what was recorded
+            with open(os.path.join(out_dir, "requests.json"), "w") as f:
+                json.dump({"t0": got["t0"], "requests": requests,
+                           "entries": entries}, f)
     finally:
         front.stop()
         if not args.out:
@@ -409,7 +502,8 @@ def run(args, bench: dict, workload: dict, device: dict) -> dict:
         recorded_queries=len(entries))
 
     def declared(m: dict) -> bool:
-        return "workloads" not in m or workload["name"] in m["workloads"]
+        return "workloads" not in m or workload["name"] in m["workloads"] \
+            or m["name"] in workload.get("metrics", ())
 
     out_metrics = {}
     if not traced:
@@ -456,27 +550,71 @@ def run(args, bench: dict, workload: dict, device: dict) -> dict:
                               window_s=trace["window_s"])
         line["breakdown"] = {"device_ops": trace["device_ops"],
                              "idle_gaps": trace["idle_gaps"]}
+    if unlisted:
+        line["unlisted"] = True
+    if traced or unlisted:
+        line["detail"] = window_detail(latencies_ms, entries,
+                                       timed=not args.rehearsal)
+        if not args.rehearsal:
+            line["detail"]["slowest"] = slowest_request(
+                done, got["t0"], slice_.wall if slice_ else None, entries)
+    line["compared"] = compared(cell, checked, failed)
+    for name, c in line["compared"].items():
+        print(f"compared {name}: {json.dumps(c)}", file=sys.stderr)
     return line
+
+
+def unlisted_workload(args, bench: dict) -> dict:
+    """The ``workloads`` entry a cell file would get, with where its files
+    are and the per-layer metrics asked for by name: named
+    ``<config>.<file's stem>`` (the stem alone where it starts so)."""
+    config = read_json(args.config_file)["name"]
+    stem = os.path.splitext(os.path.basename(args.cell_file))[0]
+    name = stem if stem.startswith(config + ".") else f"{config}.{stem}"
+    metrics = [m for m in args.metrics.split(",") if m]
+    unknown = set(metrics) - {m["name"] for m in bench["per_layer"]}
+    if unknown:
+        raise SystemExit(f"--metrics: no per-layer metric {sorted(unknown)}")
+    return {"name": name, "config": config, "chips": args.chips,
+            "cell_file": args.cell_file, "config_file": args.config_file,
+            "metrics": metrics}
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
+    ap.add_argument("--workload")
+    ap.add_argument("--cell-file", help="a cell BENCHMARK.json does not "
+                                        "list; with --config-file")
+    ap.add_argument("--config-file")
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="the chips an unlisted cell needs")
+    ap.add_argument("--metrics", default="",
+                    help="per-layer metrics an unlisted cell reads beside "
+                         "those every cell reads, comma-separated")
     ap.add_argument("--seed", type=int, default=24)
     ap.add_argument("--seconds", type=float)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearsal", action="store_true",
                     help="tiny sizes on any platform: counts and "
                          "`correct`, no timing")
-    ap.add_argument("--out", help="keep the trace and the request streams "
-                                  "here (default: a temporary directory, "
-                                  "removed at the end)")
+    ap.add_argument("--out", help="keep the trace, the request streams and "
+                                  "every request's send time and latency "
+                                  "(requests.json) here (default: a "
+                                  "temporary directory, removed at the end)")
     args = ap.parse_args(argv)
     bench = read_json(ROOT, "BENCHMARK.json")
-    workload = next((w for w in bench["workloads"]
-                     if w["name"] == args.workload), None)
-    if workload is None:
-        ap.error(f"no workload {args.workload!r} in BENCHMARK.json")
+    if args.cell_file:
+        if args.workload or not args.config_file:
+            ap.error("--cell-file goes with --config-file, without "
+                     "--workload")
+        workload = unlisted_workload(args, bench)
+    else:
+        if not args.workload or args.config_file or args.metrics:
+            ap.error("--workload <cell>, or --cell-file with --config-file")
+        workload = next((w for w in bench["workloads"]
+                         if w["name"] == args.workload), None)
+        if workload is None:
+            ap.error(f"no workload {args.workload!r} in BENCHMARK.json")
     if args.seconds is None:
         args.seconds = float(bench["run_seconds"])
 

@@ -169,20 +169,11 @@ def _tiny_fleet():
 
 
 def _answer(check, metrics, params, key, start, end, step):
-    """A Prom matrix body written from the reference itself."""
-    m = metrics[check["metric"]]
-    keep = m["labels"]["_ns_"] == f"App-{key}"
-    steps = np.arange(start, end + 1, step, dtype=np.int64) * 1000
-    fn = {"rate": lambda *a: reference.ref_rate(*a),
-          "max_over_time": reference.ref_max_over_time}[check["fn"]]
-    per = fn(m["ts"][keep], m["vals"][keep], steps,
-             check["window_s"] * 1000, params["interval_ms"])
-    row = reference.ref_group(per, np.zeros(keep.sum(), int), 1,
-                              check["agg"])[0]
-    values = [[t / 1000.0, repr(float(v))] for t, v in zip(steps, row)
-              if not np.isnan(v)]
-    return {"status": "success", "data": {
-        "resultType": "matrix", "result": [{"metric": {}, "values": values}]}}
+    """The reference's own answer, as a Prom matrix body."""
+    steps, lo, _, groups = reference.evaluate(
+        check, metrics, params["interval_ms"], key, start, end, step,
+        np.random.default_rng(0))
+    return reference.answer_body(check, steps, lo, groups)
 
 
 @pytest.mark.parametrize("panel", [0, 2])
@@ -212,8 +203,11 @@ def test_a_wrong_answer_is_not_correct(panel):
                               {"status": "error", "error": "x"}, rng)
 
 
-def test_reference_imports_nothing_of_the_program():
-    src = open(os.path.join(PERF, "reference.py")).read()
+@pytest.mark.parametrize("path", ["reference.py"] + sorted(
+    os.path.join("forms", f) for f in os.listdir(os.path.join(PERF, "forms"))
+    if f.endswith(".py")))
+def test_reference_imports_nothing_of_the_program(path):
+    src = open(os.path.join(PERF, path)).read()
     assert "filodb_tpu" not in src.split('"""', 2)[2]
     assert "import jax" not in src
 
