@@ -70,6 +70,13 @@ _M_SAMPLES = get_counter(
     "filodb_mesh_samples_scanned", help="samples of the placed batch a mesh "
     "dispatch scans, once a dispatch: a batch-cache hit scans its batch "
     "again, and a batch's members share one scan")
+_M_BUCKET_SAMPLES = get_counter(
+    "filodb_mesh_bucket_samples_scanned", help="scalar rows of the placed "
+    "batch a device program reads: a histogram sample counts once a bucket "
+    "(samples x buckets), a scalar sample once; moved once a program that "
+    "evaluates the placed rows (an eval-cache miss, a fused dispatch), so "
+    "a dispatch the eval cache answers, which runs the group reduce alone, "
+    "moves filodb_mesh_samples_scanned_total and not this")
 _M_COMPILE = {e: get_counter("filodb_mesh_compile_cache", {"event": e},
                              help="compiled mesh program cache hits/misses")
               for e in ("hit", "miss")}
@@ -436,12 +443,16 @@ class MeshQueryEngine:
         the lane choice, the ``split`` lane's conversion of the f64 batch
         or, where the device dtype cannot correct it, its host f64 pre-pass
         — tag ``copied_bytes``, 0 on the ``raw`` lane — the histogram
-        flatten, the validity mask, written into a staging buffer too),
+        flatten, in a child span ``hist-flatten`` of its own where the
+        batch has buckets, the validity mask, written into a staging buffer
+        too),
         ``mesh-place`` (the put: the batch's own ``ts``/``vals`` on the
         ``raw`` lane); a batch-cache hit opens none of these five and takes
         nothing from the pool. Then ``mesh-dispatch``, ``mesh-fetch`` — at
         whose end a miss waits for its placed arrays and gives the staging
-        buffers back, the one point where they return — ``mesh-assemble``."""
+        buffers back, the one point where they return — ``mesh-assemble``
+        (tag ``buckets`` on a histogram batch; a ``histogram_quantile``
+        post-transform runs under ``hist-quantile`` inside it)."""
         stats_objs = stats if isinstance(stats, list) \
             else ([stats] if stats is not None else [])
         from filodb_tpu.core.memstore.odp import page_partitions
@@ -631,15 +642,24 @@ class MeshQueryEngine:
                         raw_vals = built.vals
                 if B > 1:
                     Pp_, S_ = ts_p.shape
-                    host_vals = np.ascontiguousarray(
-                        host_vals.transpose(0, 2, 1)).reshape(Pp_ * B, S_)
-                    if raw_vals is not None:
-                        raw_vals = np.ascontiguousarray(
-                            raw_vals.transpose(0, 2, 1)).reshape(Pp_ * B, S_)
-                    ts_p = np.repeat(ts_p, B, axis=0)
-                    counts_p = np.repeat(counts_p, B)
-                    gid_p = (gid_p[:, None] * B + np.arange(
-                        B, dtype=np.int32)[None, :]).reshape(-1)
+                    with span("hist-flatten", rows=Pp_ * B,
+                              buckets=B) as fsp:
+                        host_vals = np.ascontiguousarray(
+                            host_vals.transpose(0, 2, 1)).reshape(
+                                Pp_ * B, S_)
+                        if raw_vals is not None:
+                            raw_vals = np.ascontiguousarray(
+                                raw_vals.transpose(0, 2, 1)).reshape(
+                                    Pp_ * B, S_)
+                        ts_p = np.repeat(ts_p, B, axis=0)
+                        counts_p = np.repeat(counts_p, B)
+                        gid_p = (gid_p[:, None] * B + np.arange(
+                            B, dtype=np.int32)[None, :]).reshape(-1)
+                        if fsp is not None:
+                            fsp.tags["bytes"] = sum(
+                                a.nbytes for a in (host_vals, raw_vals, ts_p,
+                                                   counts_p, gid_p)
+                                if a is not None)
                 # a scalar batch of the raw lane was built in the placed
                 # dtype with 0 padding: it is placed as it is
                 vals_p = host_vals if lane == "raw" and B == 1 \
@@ -747,10 +767,12 @@ class MeshQueryEngine:
                         ev_d = self._series_eval_cached(
                             dkey, version, low0.window, gkey, fn, mesh, ts_d,
                             vals_d, valid_d, grid_d, win_d, split_cv,
-                            split_prefix, raw_d, delta_counter)
+                            split_prefix, raw_d, delta_counter,
+                            scanned=samples * B)
                         out = ev_d if step_fn is None \
                             else step_fn(ev_d, gid_d)
                     else:
+                        _M_BUCKET_SAMPLES.inc(samples * B)
                         out = step_fn(ts_d, vals_d, valid_d, gid_d, grid_d,
                                       win_d)
                     calls.append((out, chunk, Kp))
@@ -781,7 +803,8 @@ class MeshQueryEngine:
                 # the host memory is free to overwrite once they are ready
                 jax.block_until_ready(placed)
                 lease.give_back()
-        with span("mesh-assemble", rows=nrows):
+        with span("mesh-assemble", rows=nrows,
+                  **({"buckets": B} if B > 1 else {})):
             for ci, (_, chunk, Kp) in enumerate(calls):
                 out_np = fetched[ci]
                 for j, i in enumerate(chunk):
@@ -857,13 +880,16 @@ class MeshQueryEngine:
     def _series_eval_cached(self, dkey, version, window, grid_bytes, fn,
                             mesh, ts_d, vals_d, valid_d, grid_d, win_d,
                             split_cv, split_prefix, raw_d,
-                            delta_counter=False):
+                            delta_counter=False, scanned=0):
         """Cached per-series evaluated windows [P, K] per (batch version,
         step grid, window, fn) — the boundary gathers + time combine that
         remain the dominant per-query device cost once bounds are cached.
         Nothing here depends on the query's grouping, so every agg over
         the same inner range function shares one entry and a warm query
-        runs only the group reduce."""
+        runs only the group reduce. A miss, which runs the programs over
+        the placed rows, moves ``filodb_mesh_bucket_samples_scanned_total``
+        by ``scanned``, the batch's samples x buckets; a hit reads none of
+        them and moves nothing."""
         ekey = (dkey, version, window, grid_bytes, fn)
         hit = self._eval_cache.get(ekey)
         tag("eval_cache", "miss" if hit is None else "hit")
@@ -871,6 +897,7 @@ class MeshQueryEngine:
             _M_EVAL["hit"].inc()
             return hit
         _M_EVAL["miss"].inc()
+        _M_BUCKET_SAMPLES.inc(scanned)
         lo_d, hi_d = self._window_bounds_cached(dkey, version, window,
                                                 grid_bytes, mesh, ts_d,
                                                 grid_d, win_d)
@@ -914,7 +941,15 @@ class MeshQueryEngine:
 
         for op in low.post:
             if op[0] == "instant":
-                m = InstantVectorFunctionMapper(op[1], op[2]).apply(m)
+                mapper = InstantVectorFunctionMapper(op[1], op[2])
+                if op[1] == "histogram_quantile":
+                    with span("hist-quantile", groups=m.num_series,
+                              steps=m.num_steps,
+                              buckets=m.values.shape[2] if m.is_histogram
+                              else 0):
+                        m = mapper.apply(m)
+                else:
+                    m = mapper.apply(m)
             elif op[0] == "scalarop":
                 m = ScalarOperationMapper(op=op[1], scalar=op[2],
                                           scalar_is_lhs=op[3],

@@ -281,7 +281,10 @@ _STAGES = ("parse", "plan-materialize", "exec-dispatch", "dispatch",
            # query/engine/batch.py) and the tails above it
            "mesh-lookup", "batch-read", "batch-stack", "mesh-group",
            "mesh-pad", "mesh-place", "mesh-dispatch", "mesh-fetch",
-           "mesh-assemble", "finish", "cache-merge", "batch-fetch")
+           "mesh-assemble", "finish", "cache-merge", "batch-fetch",
+           # what a histogram batch adds: the bucket flatten under
+           # mesh-pad, histogram_quantile under mesh-assemble
+           "hist-flatten", "hist-quantile")
 _stage_hists = {}
 for _s in _STAGES:
     _stage_hists[_s] = Histogram("filodb_query_stage_seconds",

@@ -213,7 +213,7 @@ TRACING_STAGES = [
     "mesh-execute", "scan", "decode", "reduce", "odp-page", "cache",
     "mesh-lookup", "batch-read", "batch-stack", "mesh-group", "mesh-pad",
     "mesh-place", "mesh-dispatch", "mesh-fetch", "mesh-assemble", "finish",
-    "cache-merge", "batch-fetch",
+    "cache-merge", "batch-fetch", "hist-flatten", "hist-quantile",
 ]
 
 
@@ -311,6 +311,7 @@ MESH_NAMES = [
     "filodb_mesh_fallback_total",
     "filodb_mesh_hit_rate",
     "filodb_mesh_samples_scanned_total",
+    "filodb_mesh_bucket_samples_scanned_total",
 ]
 
 
@@ -390,6 +391,25 @@ def _scrape(port: int) -> str:
             f"http://127.0.0.1:{port}/metrics") as r:
         assert r.status == 200
         return r.read().decode()
+
+
+def _ingest_and_wait(srv, metric: str, rows: int = 150) -> None:
+    """``rows`` Influx lines of ``metric`` over five hosts of App-0
+    through the gateway, then wait until the shards have counted them."""
+    want = rows + sum(s.stats.rows_ingested.value
+                      for s in srv.memstore.shards_for("timeseries"))
+    with socket.create_connection(("127.0.0.1", srv.gateway.port)) as sock:
+        for i in range(rows):
+            ts_ns = (START + i * 10) * 1_000_000_000
+            sock.sendall(f"{metric},host=h{i % 5},_ws_=demo,"
+                         f"_ns_=App-0 value={i} {ts_ns}\n".encode())
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline:
+        srv.gateway.sink.flush()
+        if sum(s.stats.rows_ingested.value
+               for s in srv.memstore.shards_for("timeseries")) >= want:
+            break
+        time.sleep(0.3)
 
 
 class TestMetricsScrape:
@@ -654,6 +674,44 @@ class TestMetricsScrape:
         first, second = moved
         assert sum(first.values()) == sum(second.values()) > 0
         assert second == {"fresh": 0, "reused": sum(first.values())}
+
+    @pytest.mark.parametrize("family,says", [
+        ("filodb_mesh_samples_scanned_total", "samples of the placed batch"),
+        ("filodb_mesh_bucket_samples_scanned_total",
+         "a histogram sample counts once a bucket"),
+    ], ids=["samples", "bucket-samples"])
+    def test_mesh_scan_counters_are_scraped_and_move_alike_on_scalars(
+            self, server, family, says):
+        """Both scan counters render before any query under their
+        ``_total`` names, one HELP/TYPE header each with the help text of
+        ``parallel/mesh_engine.py``; a mesh query over scalar series moves
+        the two by the same step (a histogram's by its buckets:
+        ``tests/test_histo_fleet.py``)."""
+        srv = server
+        both = ("filodb_mesh_samples_scanned_total",
+                "filodb_mesh_bucket_samples_scanned_total")
+
+        def values(text):
+            return {f: float(ln.rsplit(" ", 1)[1])
+                    for ln in text.splitlines() for f in both
+                    if ln.startswith(f + " ")}
+
+        before = _scrape(srv.http.port)
+        assert before.count(f"# TYPE {family} counter") == 1
+        (help_line,) = [ln for ln in before.splitlines()
+                        if ln.startswith(f"# HELP {family} ")]
+        assert says in help_line
+        assert set(values(before)) == set(both)
+        _ingest_and_wait(srv, "scan_metric")
+        at = values(_scrape(srv.http.port))
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{srv.http.port}/promql/timeseries/api/v1/"
+                f"query_range?query=sum(rate(scan_metric%5B1m%5D))"
+                f"&start={START}&end={START + 1500}&step=60") as r:
+            assert r.status == 200
+        now = values(_scrape(srv.http.port))
+        moved = {f: now[f] - at[f] for f in both}
+        assert moved[both[0]] == moved[both[1]] > 0
 
     def test_flush_and_query_counters_move(self, server):
         srv = server
