@@ -124,21 +124,36 @@ def _device_correction_ok(vals: np.ndarray) -> bool:
 
     if fdtype() == jnp.float64:
         return True
+    # the largest and the smallest value, NaN padding skipped, with no
+    # array made on the way (empty or all NaN: the initial values stand)
+    hi = np.fmax.reduce(vals, axis=None, initial=-np.inf)
+    lo = np.fmin.reduce(vals, axis=None, initial=np.inf)
+    if np.isfinite(hi) and np.isfinite(lo):
+        return bool(max(abs(hi), abs(lo)) < F32_SAFE_MAX)
+    # an infinity (or nothing finite at all) hides the largest finite
+    # magnitude from the two reductions: mask, as before
     finite = vals[np.isfinite(vals)]
     return finite.size == 0 or float(np.abs(finite).max()) < F32_SAFE_MAX
 
 
-def _placed_values(vals: np.ndarray) -> np.ndarray:
-    """The array that is placed for an f64 host value array: ONE allocation
-    in the device's float dtype (numpy's ``astype`` rounds as
-    ``device_put`` would), then the NaN that marks padding set to 0 — the
-    kernels mask by validity, not by NaN. Always a copy, and never from the
-    staging pool: ``vals`` may be ``delta_host``'s cached array."""
+def _placed_values(vals: np.ndarray, take, marks: np.ndarray) -> np.ndarray:
+    """The array that is placed for a host value array ``[P, S]``, or
+    ``[P, S, B]`` with the buckets flattened into the series axis
+    (``[P·B, S]``, row ``p·B + b``): ONE buffer from ``take`` in the device's
+    float dtype, written in one pass that transposes and rounds (numpy's
+    cast rounds as ``device_put`` would), then the NaN that marks padding
+    set to 0 — the kernels mask by validity, not by NaN. ``marks`` is a
+    bool buffer of the placed shape to find the NaN in. Always a copy:
+    ``vals`` may be ``delta_host``'s cached array and is only read; every
+    element of both buffers is overwritten, whatever they held."""
     from filodb_tpu.query.engine.batch import device_float
 
-    out = vals.astype(device_float())
-    np.putmask(out, np.isnan(out), 0)
+    src = vals if vals.ndim == 2 else vals.transpose(0, 2, 1)
+    out = take(marks.shape, device_float())
+    np.copyto(out.reshape(src.shape), src, casting="same_kind")
+    np.putmask(out, np.isnan(out, out=marks), 0)
     return out
+
 
 # range functions with associative mesh combines (dist_query kernels)
 MESH_FNS = ("rate", "increase", "delta", "sum_over_time", "count_over_time",
@@ -241,7 +256,8 @@ class MeshQueryEngine:
     _batch_cache: dict = field(default_factory=dict)
     _batch_cache_cap: int = 16
     # the [P, S] host arrays one placement needs (build_batch's ts/vals,
-    # the validity mask), taken back once the placed arrays are ready
+    # the validity mask, the split lane's converted copy, a histogram's
+    # bucket rows), taken back once the placed arrays are ready
     _staging: StagingPool = field(default_factory=StagingPool)
     # step-grid device arrays keyed by their bytes: repeated queries
     # re-upload identical grids every batch otherwise (a host→device
@@ -444,8 +460,9 @@ class MeshQueryEngine:
         or, where the device dtype cannot correct it, its host f64 pre-pass
         — tag ``copied_bytes``, 0 on the ``raw`` lane — the histogram
         flatten, in a child span ``hist-flatten`` of its own where the
-        batch has buckets, the validity mask, written into a staging buffer
-        too),
+        batch has buckets and converting in the same pass, the validity
+        mask: every array of the placed size it writes is a staging
+        buffer too, tag ``reused_bytes`` on both spans),
         ``mesh-place`` (the put: the batch's own ``ts``/``vals`` on the
         ``raw`` lane); a batch-cache hit opens none of these five and takes
         nothing from the pool. Then ``mesh-dispatch``, ``mesh-fetch`` — at
@@ -640,38 +657,44 @@ class MeshQueryEngine:
                         # extrapolate-to-zero clamp (heuristic-only reference;
                         # delta never clamps, even when reset-corrected)
                         raw_vals = built.vals
+                Pp_, S_ = ts_p.shape
+                # every [P·B, S] array made here is the lease's, to go back
+                # with the builder's. The mask's buffer first holds the NaN
+                # marks of each converted copy
+                valid = lease.take((Pp_ * B, S_), np.bool_)
+
+                def placed_values(vals):
+                    return None if vals is None \
+                        else _placed_values(vals, lease.take, valid)
+
                 if B > 1:
-                    Pp_, S_ = ts_p.shape
                     with span("hist-flatten", rows=Pp_ * B,
                               buckets=B) as fsp:
-                        host_vals = np.ascontiguousarray(
-                            host_vals.transpose(0, 2, 1)).reshape(
-                                Pp_ * B, S_)
-                        if raw_vals is not None:
-                            raw_vals = np.ascontiguousarray(
-                                raw_vals.transpose(0, 2, 1)).reshape(
-                                    Pp_ * B, S_)
-                        ts_p = np.repeat(ts_p, B, axis=0)
+                        vals_p = placed_values(host_vals)
+                        raw_p = placed_values(raw_vals)
+                        ts_p = lease.take(valid.shape, ts_p.dtype)
+                        np.copyto(ts_p.reshape(Pp_, B, S_),
+                                  built.ts[:, None, :])
                         counts_p = np.repeat(counts_p, B)
                         gid_p = (gid_p[:, None] * B + np.arange(
                             B, dtype=np.int32)[None, :]).reshape(-1)
                         if fsp is not None:
                             fsp.tags["bytes"] = sum(
-                                a.nbytes for a in (host_vals, raw_vals, ts_p,
+                                a.nbytes for a in (vals_p, raw_p, ts_p,
                                                    counts_p, gid_p)
                                 if a is not None)
-                # a scalar batch of the raw lane was built in the placed
-                # dtype with 0 padding: it is placed as it is
-                vals_p = host_vals if lane == "raw" and B == 1 \
-                    else _placed_values(host_vals)
-                raw_p = None if raw_vals is None \
-                    else _placed_values(raw_vals)
+                elif lane == "raw":
+                    # a scalar batch of the raw lane was built in the
+                    # placed dtype with 0 padding: it is placed as it is
+                    vals_p, raw_p = host_vals, None
+                else:
+                    vals_p = placed_values(host_vals)
+                    raw_p = placed_values(raw_vals)
                 # counts do not shard along the time axis: a mask does
                 # (an int32 range, as counts are: numpy would widen both
                 # sides to int64 first, three times the compare's time)
-                valid = np.less(
-                    np.arange(ts_p.shape[1], dtype=np.int32)[None, :],
-                    counts_p[:, None], out=lease.take(ts_p.shape, np.bool_))
+                np.less(np.arange(S_, dtype=np.int32)[None, :],
+                        counts_p[:, None], out=valid)
                 if sp is not None:
                     # the [P,S] arrays this phase made beside the mask:
                     # what is placed and is not the builder's own array

@@ -1,14 +1,18 @@
 """Host staging buffers of the mesh engine.
 
 A batch-cache miss builds ``[P, S]`` host arrays for ONE placement —
-``build_batch``'s ``ts`` and ``vals``, the validity mask — and nothing reads
-them once the device holds its copy. Arrays of that size (64 MB at the
-``[16384, 1024]`` of a 10,000-series query) come from ``mmap``, so an
-allocation a request pays a page fault for every 4 KiB it writes and gives
-the pages back when the arrays die. ``StagingPool`` keeps the arrays
-instead: a placement takes them through a :class:`Lease` and the engine
-gives them back when the placed arrays are ready, so the next build of the
-same shape writes into memory that is already mapped.
+``build_batch``'s ``ts`` and ``vals``, the validity mask, and what
+``mesh-pad`` makes of them: the ``split`` lane's copy in the device's float
+dtype and, for a histogram batch ``[P, S, B]``, the values and ``ts``
+flattened to bucket rows ``[P·B, S]`` — and nothing reads them once the
+device holds its copy. Arrays of that size (64 MB at the ``[16384, 1024]``
+of a 10,000-series query; 8-17 MB each at the ``[128, 256, 64]`` of a
+service's 100 latency histograms) come from ``mmap``, so an allocation a
+request pays a page fault for every 4 KiB it writes and gives the pages
+back when the arrays die. ``StagingPool`` keeps the arrays instead: a
+placement takes them through a :class:`Lease` and the engine gives them
+back when the placed arrays are ready, so the next build of the same shape
+writes into memory that is already mapped.
 
 A buffer is found by dtype and shape, which power-of-two bucketing keeps to
 a few values; a request no buffer fits allocates as before. The pool holds
@@ -27,7 +31,10 @@ from filodb_tpu.utils.metrics import BATCH_BUFFER_FRESH, BATCH_BUFFER_REUSED
 from filodb_tpu.utils.tracing import tag_add
 
 # two placements in flight at the largest shape a one-chip cell builds:
-# ts int32 + vals f32 + mask bool of [16384, 1024] = 64 + 64 + 16 MiB
+# ts int32 + vals f32 + mask bool of [16384, 1024] = 64 + 64 + 16 MiB.
+# A histogram placement of [128, 256, 64] leases ~36 MB (vals f64 16.9,
+# ts 0.1; the bucket rows [8192, 256]: values f32 8.4, ts 8.4, mask 2.1),
+# of one shape an extent: several fit beside the above
 POOL_CAP_BYTES = 2 * (64 + 64 + 16) << 20
 
 

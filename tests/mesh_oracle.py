@@ -176,6 +176,24 @@ def mismatches(got, want) -> list[str]:
     return bad
 
 
+def pad_leased(cap) -> tuple[int, int]:
+    """Bytes a capture's ``mesh-pad`` has to take from the staging pool:
+    (the validity mask, the placed ``[P·B, S]`` arrays — ``ts``, values,
+    raw values — that are not the builder's own). A histogram batch takes
+    the second inside ``hist-flatten``."""
+    made = [a for a in (cap.got[0], cap.got[1], cap.got[4])
+            if a is not None and a is not cap.batch.ts
+            and a is not cap.batch.vals]
+    return cap.got[2].nbytes, sum(a.nbytes for a in made)
+
+
+def pad_reused(cap) -> tuple[int, int]:
+    """``reused_bytes`` of a capture's (``mesh-pad``, ``hist-flatten``)
+    spans, 0 where the span was not opened."""
+    return (cap.tags["mesh-pad"]["reused_bytes"],
+            cap.tags.get("hist-flatten", {}).get("reused_bytes", 0))
+
+
 def placed_mismatches(cap) -> list[str]:
     """``mismatches`` of a capture, allowing the one shape that is not the
     parent's: a histogram on a mesh that divides no power of two rounds its

@@ -193,11 +193,13 @@ assert not jax.config.jax_enable_x64
 import json
 import numpy as np
 sys.path.insert(0, os.path.join(os.getcwd(), "tests"))
-from mesh_oracle import CASES, MESHES, placed_mismatches, run_case
+from mesh_oracle import (CASES, MESHES, pad_leased, pad_reused,
+                         placed_mismatches, run_case)
 
 stores, out = {}, {}
 POISONED = ("raw-avg", "raw-max-fused", "split-small", "split-big",
-            "histogram-split")
+            "histogram-split", "split-big-rate", "split-delta-counter",
+            "histogram-split-one-group")
 for case in CASES:
     for mesh_name in MESHES:
         cap = run_case(case, mesh_name, stores)
@@ -219,7 +221,8 @@ for case in CASES:
 
 # the same builds into POISONED staging buffers (an earlier run's, given
 # back and overwritten with garbage): f32 ``vals`` refilled with 0 on the
-# raw lane, f64 with NaN where a host pass follows
+# raw lane, f64 with NaN where a host pass follows, and mesh-pad's own
+# arrays (the mask, the f32 copies, a histogram's bucket rows)
 for case in POISONED:
     cap = run_case(case, "2x2", stores, poisoned=True)
     out[f"poisoned/{case}"] = {
@@ -228,8 +231,8 @@ for case in POISONED:
         "batch_dtype": str(cap.batch.vals.dtype),
         "reused": cap.tags["batch-stack"]["reused_bytes"],
         "built": cap.batch.ts.nbytes + cap.batch.vals.nbytes,
-        "mask_reused": cap.tags["mesh-pad"]["reused_bytes"],
-        "mask": cap.got[2].nbytes,
+        "pad_reused": pad_reused(cap),
+        "pad_leased": pad_leased(cap),
     }
 
 # the batch build itself: rows filled by the native shard cores against the
@@ -323,15 +326,22 @@ def test_f32_native_fill_is_the_per_series_builders_bits(rng, placed_f32):
 @pytest.mark.parametrize("case,batch_dtype", [
     ("raw-avg", "float32"), ("raw-max-fused", "float32"),
     ("split-small", "float64"), ("split-big", "float64"),
-    ("histogram-split", "float64")])
+    ("histogram-split", "float64"), ("split-big-rate", "float64"),
+    ("split-delta-counter", "float64"),
+    ("histogram-split-one-group", "float64")])
 def test_f32_poisoned_staging_buffers_place_the_parents_bits(
         case, batch_dtype, placed_f32):
     """x64 off: the raw lane's f32 ``vals`` (0 padding) and the split
     lane's f64 (NaN padding, with and without the host pre-pass) written
-    into staging buffers full of garbage — the device receives the
-    parent's bits."""
+    into staging buffers full of garbage, and after them the f32 copies
+    ``mesh-pad`` converts, a histogram's flattened into bucket rows — the
+    device receives the parent's bits."""
     cell = placed_f32[f"poisoned/{case}"]
     assert cell["bad"] == []
     assert (cell["dtype"], cell["batch_dtype"]) == ("float32", batch_dtype)
     assert cell["reused"] == cell["built"] > 0
-    assert cell["mask_reused"] == cell["mask"] > 0
+    mask, made = cell["pad_leased"]
+    assert mask > 0 and (made > 0) == (batch_dtype == "float64")
+    # a histogram takes its bucket rows inside hist-flatten
+    assert cell["pad_reused"] == ([mask, made] if "histogram" in case
+                                  else [mask + made, 0])

@@ -429,9 +429,9 @@ def test_hist_flatten_is_a_child_of_mesh_pad_on_a_miss_only(
         assert b == BUCKETS
         assert flat["tags"]["rows"] == p * b == pad["tags"]["shape"][0]
         assert flat["tags"]["buckets"] == b
-        # what the flatten made: the split lane's f64 values [P·B, S],
-        # the int32 time offsets repeated a bucket, counts and group ids
-        # a row
+        # what the flatten made: the placed values [P·B, S] (f64 under
+        # x64, as here; f32 in a server), the int32 time offsets repeated
+        # a bucket, counts and group ids a row
         assert flat["tags"]["bytes"] == p * b * s_ * (8 + 4) + p * b * (4 + 4)
         assert flat["duration_ms"] <= pad["duration_ms"]
         # mesh-pad's own tags are what they were
@@ -442,6 +442,51 @@ def test_hist_flatten_is_a_child_of_mesh_pad_on_a_miss_only(
     spans = recorded(front, panel_request(fleet, 1, 1, 6543))
     assert len(named(spans, "mesh-execute")) == len(engines)
     assert not named(spans, "hist-flatten") and not named(spans, "mesh-pad")
+
+
+def test_a_second_histogram_miss_writes_into_the_first_ones_buffers(
+        fleet, front, trace_everything):
+    """Another service's dashboard over the same hour builds batches of
+    the first one's shapes: every ``[P, S, B]``- and ``[P·B, S]``-sized
+    array of its misses — the builder's, the flattened values, ``ts`` a
+    bucket row, the mask — comes back out of the staging pool and
+    ``source="fresh"`` does not move."""
+    def buffer_bytes():
+        return {src: counter("filodb_batch_buffer_bytes_total", source=src)
+                for src in ("fresh", "reused")}
+
+    at0 = buffer_bytes()
+    first = recorded(front, panel_request(fleet, 0, 1, 6543))
+    at1 = buffer_bytes()
+    assert at1["fresh"] > at0["fresh"]
+    shapes = {tuple(s["tags"]["shape"]) for s in named(first, "decode")}
+    spans = recorded(front, panel_request(fleet, 0, 2, 6543))
+    at2 = buffer_bytes()
+    pads, flats = named(spans, "mesh-pad"), named(spans, "hist-flatten")
+    builds = named(spans, "decode")
+    assert len(pads) == len(flats) == len(builds) >= 2
+    assert {tuple(s["tags"]["shape"]) for s in builds} <= shapes
+    assert at2["fresh"] == at1["fresh"]
+    leased = 0
+    for pad, flat, build in zip(pads, flats, builds):
+        p, s_, b = build["tags"]["shape"]
+        assert b == BUCKETS
+        # both [P·B, S] arrays: the values in the placed dtype and ts
+        assert flat["tags"]["reused_bytes"] == p * b * s_ * (8 + 4)
+        assert flat["tags"]["bytes"] == \
+            flat["tags"]["reused_bytes"] + p * b * (4 + 4)
+        # mesh-pad's own: the mask alone; what is placed and not the
+        # builder's is what the flatten took
+        assert pad["tags"]["reused_bytes"] == p * b * s_
+        assert pad["tags"]["copied_bytes"] == flat["tags"]["reused_bytes"]
+        (stack,) = [s for s in named(spans, "batch-stack")
+                    if s["parent_id"] == build["span_id"]]
+        assert stack["tags"]["reused_bytes"] == p * s_ * (4 + 8 * b)
+        leased += p * s_ * (4 + 8 * b) + p * b * s_ * (8 + 4 + 1)
+    assert at2["reused"] - at1["reused"] == leased
+    # and the answer out of them is the reference's
+    request = panel_request(fleet, 2, 2, 6543)
+    held_to_reference(fleet, 2, request, front.get(request["path"]))
 
 
 def test_a_scalar_batch_opens_no_histogram_span(fleet, front,

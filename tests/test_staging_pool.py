@@ -1,10 +1,12 @@
 """The mesh engine's host staging pool (``parallel/staging.py``): the
 ``[P, S]`` arrays of one placement — ``build_batch``'s ``ts``/``vals``, the
-validity mask — are written into buffers an earlier placement gave back,
-and nothing of that shows in an answer: a build into POISONED buffers is
-the fresh build bit for bit, the placed arrays never alias host memory, a
-buffer returns only once the placed arrays are ready, and a cached entry
-keeps the header a hit reads and no host samples.
+validity mask, the ``split`` lane's converted copy, a histogram's flattened
+values and repeated ``ts`` — are written into buffers an earlier placement
+gave back, and nothing of that shows in an answer: a build into POISONED
+buffers is the fresh build bit for bit, the placed arrays never alias host
+memory, the arrays a placement only reads stay as they were, a buffer
+returns only once the placed arrays are ready, and a cached entry keeps
+the header a hit reads and no host samples.
 
 x64 is on here; the poisoned matrix runs with x64 off, as a server does, in
 ``test_f32_mode.py``."""
@@ -20,6 +22,8 @@ from mesh_oracle import (
     START,
     STORES,
     forget,
+    pad_leased,
+    pad_reused,
     placed_mismatches,
     poison,
     run_case,
@@ -27,11 +31,13 @@ from mesh_oracle import (
 
 from filodb_tpu.coordinator.query_service import QueryService
 from filodb_tpu.core.memstore.native_shard import native_available
-from filodb_tpu.parallel import dist_query, staging
+from filodb_tpu.parallel import dist_query, mesh_engine, staging
 from filodb_tpu.parallel.mesh_engine import (
     _M_BATCH,
     _M_EVAL,
+    F32_SAFE_MAX,
     MeshQueryEngine,
+    _device_correction_ok,
     make_query_mesh,
 )
 from filodb_tpu.parallel.staging import Lease, StagingPool
@@ -81,6 +87,21 @@ def buffer_bytes():
     return BATCH_BUFFER_FRESH.value, BATCH_BUFFER_REUSED.value
 
 
+@pytest.fixture
+def device_dtype(monkeypatch):
+    """Make the device's float dtype what a test names, as ``fdtype`` and
+    ``device_float`` report it (x64 is on in this process; a server's is
+    f32): decides the lane and the dtype that is placed, runs no program."""
+    import jax.numpy as jnp
+
+    from filodb_tpu.query.engine import kernels
+
+    def set_to(name):
+        monkeypatch.setattr(kernels, "fdtype", lambda: jnp.dtype(name))
+
+    return set_to
+
+
 # --- (a) a build into poisoned buffers is the fresh build, bit for bit ------
 
 @pytest.mark.skipif(not native_available(),
@@ -108,24 +129,116 @@ def test_native_and_fallback_rows_into_poisoned_buffers(rng, host_f64):
 
 
 MATRIX = ["raw-avg", "raw-max-fused", "raw-last-sample", "split-small",
-          "split-delta", "histogram-split", "histogram-raw"]
+          "split-delta", "histogram-split", "histogram-raw",
+          # since mesh-pad's own arrays are the pool's too: a histogram
+          # ``rate`` into one group, the scalar split lane over counters
+          "histogram-split-one-group", "split-delta-counter",
+          "split-big-rate"]
 
 
 @pytest.mark.parametrize("mesh_name", ["1x1", "2x2"])
 @pytest.mark.parametrize("case", MATRIX)
 def test_a_query_into_poisoned_buffers_places_the_parents_bits(
         case, mesh_name, stores):
-    """ts, vals, the mask: what the device receives from poisoned staging
-    buffers is what the parent's fresh arrays held, and the answer is the
-    exec tree's."""
+    """ts, vals, the mask, the group ids: what the device receives from
+    poisoned staging buffers is what the parent's fresh arrays held —
+    the split lane's converted copy and a histogram's flattened values and
+    repeated ``ts`` among them — and the answer is the exec tree's."""
     cap = run_case(case, mesh_name, stores, run=True, poisoned=True)
     assert placed_mismatches(cap) == []
     assert cap.tags["batch-stack"]["reused_bytes"] == \
         cap.batch.ts.nbytes + cap.batch.vals.nbytes
-    assert cap.tags["mesh-pad"]["reused_bytes"] == cap.got[2].nbytes
+    mask, made = pad_leased(cap)
+    assert (made > 0) == (CASES[case][2] == "split" or
+                          cap.batch.is_histogram)
+    assert pad_reused(cap) == ((mask, made) if cap.batch.is_histogram
+                               else (mask + made, 0))
     exec_svc = QueryService(stores[CASES[case][0]], DATASET, 4, spread=1)
     assert_same(exec_svc.query_range(CASES[case][1], *ARGS).result,
                 cap.result)
+
+
+# --- (a2) what mesh-pad itself writes: the converted copy, the flatten ------
+
+def parent_placed_values(vals):
+    """The parent's expressions for the placed value array of a host
+    ``[P, S]`` or ``[P, S, B]`` array: flatten, ``astype``, NaN to 0."""
+    from filodb_tpu.query.engine.batch import device_float
+
+    if vals.ndim == 3:
+        p, s_, b = vals.shape
+        vals = np.ascontiguousarray(vals.transpose(0, 2, 1)).reshape(p * b,
+                                                                     s_)
+    out = vals.astype(device_float())
+    np.putmask(out, np.isnan(out), 0)
+    return out
+
+
+@pytest.mark.parametrize("device", ["float32", "float64"])
+@pytest.mark.parametrize("source", ["float32", "float64"])
+@pytest.mark.parametrize("shape", [(8, 16), (8, 16, 5)],
+                         ids=["scalar", "histogram"])
+def test_placed_values_into_garbage_is_the_parents_array(
+        shape, source, device, device_dtype):
+    """One pass that transposes and rounds, into a buffer and a mark
+    buffer that hold the last build's bytes: the parent's flatten,
+    ``astype`` and NaN pass bit for bit, every element overwritten, the
+    input only read (it may be ``delta_host``'s cached array)."""
+    device_dtype(device)
+    rng = np.random.default_rng(3)
+    vals = (rng.normal(size=shape) * 10.0 ** rng.integers(
+        -3, 9, size=shape)).astype(source)
+    vals[rng.random(shape) < 0.2] = np.nan
+    vals[2] = np.nan                                    # an empty series
+    vals[:, 11:] = np.nan                               # the padding
+    vals[0, 0] = np.inf
+    kept = vals.copy()
+    want = parent_placed_values(vals)
+    pool = StagingPool()
+    first = pool.lease()
+    first.take(want.shape, want.dtype)
+    first.take(want.shape, np.bool_)
+    first.give_back()
+    assert poison(pool) == want.nbytes + want.size
+    lease = pool.lease()
+    marks = lease.take(want.shape, np.bool_)
+    got = mesh_engine._placed_values(vals, lease.take, marks)
+    assert pool.held_bytes == 0                 # both came from the pool
+    assert got.dtype == want.dtype and got.flags.c_contiguous
+    assert got.shape == want.shape and got.tobytes() == want.tobytes()
+    assert not np.shares_memory(got, vals)
+    assert vals.tobytes() == kept.tobytes()
+
+
+@pytest.mark.parametrize("case", ["split-big-rate", "split-big",
+                                  "split-small", "split-delta",
+                                  "histogram-split"])
+def test_a_placement_only_reads_built_vals_and_delta_hosts_array(
+        case, stores, device_dtype):
+    """The device dtype is f32 here, as in a server: counters over 2**20
+    take the host's f64 pre-pass, whose array ``delta_host`` caches on the
+    batch. Neither it nor ``built.vals`` (placed beside it for the
+    extrapolation clamp) is written by the conversion into the pool's
+    buffers, and the converted copies are arrays of their own."""
+    device_dtype("float32")
+    cap = run_case(case, "2x2", stores)
+    assert placed_mismatches(cap) == []
+    vals_p, raw_p = cap.got[1], cap.got[4]
+    assert vals_p.dtype == np.float32 and cap.batch.vals.dtype == np.float64
+    # the same series built again, untouched by any placement
+    assert cap.batch.vals.tobytes() == cap.f64.vals.tobytes()
+    assert not np.shares_memory(vals_p, cap.batch.vals)
+    cached = getattr(cap.batch, "_delta_host", {})
+    assert len(cached) == (1 if case.startswith("split-big") else 0)
+    for counter, rebased in cached.items():
+        assert rebased.tobytes() == cap.f64.delta_host(counter).tobytes()
+        assert cap.batch.delta_host(counter) is rebased
+        assert not np.shares_memory(vals_p, rebased)
+        assert np.isnan(rebased).any() and not np.isnan(vals_p).any()
+    assert (raw_p is not None) == (case in ("split-big-rate", "split-big"))
+    if raw_p is not None:
+        assert not np.shares_memory(raw_p, cap.batch.vals)
+        assert raw_p is not vals_p and raw_p.dtype == np.float32
 
 
 # --- (b) the placed arrays never alias the staging buffers ------------------
@@ -219,7 +332,8 @@ def test_eval_cache_hit_gives_back_after_the_placed_arrays_are_ready(
     assert [what for what, _ in order] == ["ready", "give"]
     (_, placed), (_, taken) = order
     entry = next(reversed(eng._batch_cache.values()))
-    assert placed is entry[5] and len(taken) == 3       # ts, vals, mask
+    # ts, vals, the mask, the split lane's converted copy
+    assert placed is entry[5] and len(taken) == 4
     assert_same(exec_svc.query_range(q2, *ARGS).result, got)
     monkeypatch.undo()
     # the buffers are anyone's now; both entries answer from the device
@@ -275,18 +389,60 @@ def test_the_histogram_early_return_drops_the_buffers(stores):
         return eng.execute_lowered_many([low], ms, DATASET)[0]
 
     want = run("sum(rate(http_req_latency[5m]))")
-    held = eng._staging.held_bytes
-    sizes = sorted(b.nbytes for b in free_buffers(eng._staging))
-    assert len(sizes) == 3
+    before = free_buffers(eng._staging)
+    # ts and vals as built; the mask, the values and ts a bucket row
+    assert len(before) == 5
     assert run("avg(rate(http_req_latency[5m]))") is None
-    # the build took ts and vals; the mask was never asked for
-    (mask,) = free_buffers(eng._staging)
-    assert mask.dtype == np.bool_ and eng._staging.held_bytes == mask.nbytes
-    assert mask.nbytes in sizes and mask.nbytes < held
+    # the build took ts and vals; mesh-pad's three were never asked for
+    left = free_buffers(eng._staging)
+    assert len(left) == 3 and {id(b) for b in left} < {id(b) for b in before}
+    assert len({b.shape for b in left}) == 1
+    assert sorted(b.dtype.str for b in left) == sorted(
+        np.dtype(t).str for t in (np.bool_, np.int32, np.float64))
+    assert eng._staging.held_bytes == sum(b.nbytes for b in left)
     forget(eng)
     again = run("sum(rate(http_req_latency[5m]))")
     np.testing.assert_array_equal(np.asarray(again.values),
                                   np.asarray(want.values))
+
+
+@pytest.mark.parametrize("case", ["split-small", "histogram-split"])
+def test_an_exception_inside_mesh_pad_drops_what_was_leased(
+        case, stores, monkeypatch):
+    """The conversion fails after it took its buffer: the builder's arrays,
+    the mask and that buffer are dropped with the lease; what the phase had
+    not asked for yet (a histogram's repeated ``ts``) stays in the pool."""
+    store_name, q, _ = CASES[case]
+    exec_svc, mesh_svc = services(store(stores, store_name))
+    eng = mesh_svc.mesh_engine
+    mesh_svc.query_range(q, *ARGS)
+    before = free_buffers(eng._staging)
+    held = eng._staging.held_bytes
+    histogram = case.startswith("histogram")
+    assert len(before) == (5 if histogram else 4) and held > 0
+    forget(eng)
+    real = mesh_engine._placed_values
+
+    def boom(vals, take, marks):
+        real(vals, take, marks)
+        raise RuntimeError("the copy failed")
+
+    monkeypatch.setattr(mesh_engine, "_placed_values", boom)
+    low = eng._lower(parse_query(q, TimeStepParams(*ARGS)))
+    with pytest.raises(RuntimeError, match="the copy failed"):
+        eng.execute_lowered_many([low], mesh_svc.memstore, DATASET)
+    left = free_buffers(eng._staging)
+    assert [b.dtype for b in left] == ([np.int32] if histogram else [])
+    assert eng._staging.held_bytes == sum(b.nbytes for b in left) < held
+    assert {id(b) for b in left} <= {id(b) for b in before}
+    monkeypatch.undo()
+    fresh0 = BATCH_BUFFER_FRESH.value
+    assert_same(exec_svc.query_range(q, *ARGS).result,
+                eng.execute_lowered_many([low], mesh_svc.memstore,
+                                         DATASET)[0])
+    assert BATCH_BUFFER_FRESH.value - fresh0 == \
+        held - sum(b.nbytes for b in left)
+    assert eng._staging.held_bytes == held
 
 
 def test_nothing_is_given_back_twice():
@@ -494,3 +650,93 @@ def test_the_cached_entry_keeps_the_header_and_the_placed_arrays(stores):
     (entry,) = scalar_svc.mesh_engine._batch_cache.values()
     assert not entry[1].is_histogram and entry[1].buckets == 1
     assert entry[1].les is None
+
+
+# --- (i) the lane decision makes no copy and is the masked form's ----------
+
+def masked_form(vals) -> bool:
+    """``_device_correction_ok`` as the parent had it where the device
+    dtype is f32: a boolean-mask copy of the finite values and its
+    ``abs``."""
+    finite = vals[np.isfinite(vals)]
+    return finite.size == 0 or float(np.abs(finite).max()) < F32_SAFE_MAX
+
+
+def _histogram_values(big=None):
+    vals = np.random.default_rng(9).random((4, 8, 3)) * 1000.0
+    vals[:, 5:] = np.nan
+    vals[1] = np.nan
+    if big is not None:
+        vals[3, 2, 1] = big
+    return vals
+
+
+NAN, INF = np.nan, np.inf
+GATE = {
+    "nan-padding": np.array([[1.0, 2.5, NAN, NAN], [3.0, NAN, NAN, NAN]]),
+    "nan-padding-beside-big": np.array([[1.0, 3.0e9, NAN, NAN]]),
+    "all-nan": np.full((4, 8), NAN),
+    "empty": np.empty((0, 8)),
+    "empty-histogram": np.empty((4, 0, 3)),
+    "inf-beside-small": np.array([[NAN, INF, -INF, 5.0]]),
+    "plus-inf-beside-small": np.array([[INF, 5.0, 7.0]]),
+    "minus-inf-beside-small": np.array([[-INF, 5.0, 7.0]]),
+    "inf-beside-big": np.array([[INF, 5.0, F32_SAFE_MAX + 1.0]]),
+    "minus-inf-beside-big": np.array([[-INF, 5.0], [NAN, 2.0 ** 21]]),
+    "inf-beside-big-negative": np.array([[INF, -INF, -3.0e9, 1.0]]),
+    "only-inf": np.array([[INF, -INF, NAN]]),
+    "exactly-2**20": np.array([[0.0, F32_SAFE_MAX]]),
+    "exactly-minus-2**20": np.array([[-F32_SAFE_MAX, 0.0]]),
+    "just-under-2**20": np.array([[np.nextafter(F32_SAFE_MAX, 0.0), 1.0]]),
+    "large-negative": np.array([[-3.0e9, 1.0, NAN]]),
+    "small-negative": np.array([[-1000.5, -1.0, NAN]]),
+    "zeros": np.zeros((3, 4)),
+    "histogram": _histogram_values(),
+    "histogram-one-big-bucket": _histogram_values(big=5.0e7),
+    "histogram-one-inf-bucket": _histogram_values(big=INF),
+    "not-contiguous": _histogram_values(big=-2.0 ** 20).transpose(0, 2, 1),
+    "float32": np.array([[1.0, 2.0 ** 20, NAN]], np.float32),
+}
+
+
+@pytest.mark.parametrize("device", ["float32", "float64"],
+                         ids=["f32", "x64"])
+@pytest.mark.parametrize("name", GATE)
+def test_the_lane_decision_is_the_masked_forms_boolean(name, device,
+                                                       device_dtype):
+    """Beside ``test_mesh_sharded.py::TestPrecisionGate``: the largest
+    finite magnitude from two reductions that skip NaN, the masked form
+    where an infinity hides it — the same boolean for every input, so the
+    lane a batch takes is the parent's. Under x64 the device corrects
+    whatever it is given."""
+    device_dtype(device)
+    vals = GATE[name]
+    kept = vals.copy()
+    got = _device_correction_ok(vals)
+    assert isinstance(got, bool)
+    assert got == (masked_form(vals) if device == "float32" else True)
+    assert np.array_equal(vals, kept, equal_nan=True)
+
+
+def test_the_lane_decision_allocates_no_array_of_the_batchs_size(
+        device_dtype):
+    """No boolean-mask copy, no ``abs``: peak extra memory of a decision
+    over 4 MB of finite values and NaN padding is numpy's 64 KiB iteration
+    buffer, whatever the batch's size, where the masked form makes two
+    arrays of the finite values' size and a mask."""
+    import tracemalloc
+
+    device_dtype("float32")
+    vals = np.random.default_rng(1).random((64, 128, 64)) * 1000.0
+    vals[:, 100:] = np.nan
+
+    def peak(fn):
+        tracemalloc.start()
+        try:
+            assert fn(vals)
+            return tracemalloc.get_traced_memory()[1]
+        finally:
+            tracemalloc.stop()
+
+    assert peak(masked_form) > vals.nbytes
+    assert peak(_device_correction_ok) < 128 << 10 < vals.nbytes // 16
