@@ -513,7 +513,7 @@ class FastHttpServer:
     def _render(req: _HotReq, result) -> bytes:
         with render_seconds.time():
             if req.kind == "range":
-                return promjson.matrix_json_str(result).encode()
+                return promjson.matrix_json_bytes(result)
             return promjson.vector_json_str(result).encode()
 
     def _run_single(self, req: _HotReq) -> tuple[int, dict, bytes]:

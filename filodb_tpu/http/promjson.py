@@ -11,11 +11,22 @@ from __future__ import annotations
 
 import json
 import math
+from itertools import chain
 
 import numpy as np
 
 from filodb_tpu.core.partkey import METRIC_LABEL
+from filodb_tpu.memory import native
 from filodb_tpu.query.model import QueryResult, StepMatrix
+from filodb_tpu.utils.metrics import Counter
+
+# the series of matrix answers, by the renderer that wrote them: "native"
+# where the library is loaded, "python" where it is absent
+_RENDER_SERIES_HELP = "series of Prom matrix answers rendered, by renderer"
+RENDER_SERIES_NATIVE = Counter("filodb_http_render_series", {"path": "native"},
+                               help=_RENDER_SERIES_HELP)
+RENDER_SERIES_PYTHON = Counter("filodb_http_render_series", {"path": "python"},
+                               help=_RENDER_SERIES_HELP)
 
 
 def _fmt(v: float) -> str:
@@ -147,19 +158,14 @@ def _value_strings(vals: np.ndarray) -> np.ndarray:
     return sv
 
 
-def matrix_json_str(result: QueryResult, full_stats: bool = False) -> str:
-    """Prom matrix response rendered straight to a JSON string — numpy
-    formats every sample value in one vectorized pass instead of a
-    per-value Python loop (the reference leans on Jackson streaming for the
-    same reason, ``PromCirceSupport``)."""
-    m = result.result
-    if m.is_histogram:
-        m = _flatten_histograms(m)
-    m.materialize()
-    vals = np.asarray(m.values, np.float64)
+def _series_python(m: StepMatrix, vals: np.ndarray,
+                   ts_str: list[str]) -> tuple[str, int]:
+    """The matrix's series joined into one JSON text, and how many — numpy
+    formats every sample value in one vectorized pass, the rest is a loop
+    over the series. The oracle of :func:`_series_native`, and the path
+    where the native library is absent."""
     ok = ~np.isnan(vals)
     sv = _value_strings(vals)
-    ts_str = [repr(t / 1000.0) for t in m.steps_ms.tolist()]
     parts = []
     for i, key in enumerate(m.keys):
         idx = np.flatnonzero(ok[i])
@@ -169,11 +175,64 @@ def matrix_json_str(result: QueryResult, full_stats: bool = False) -> str:
         body = ",".join(f'[{ts_str[k]},"{row[k]}"]' for k in idx.tolist())
         parts.append('{"metric":%s,"values":[%s]}'
                      % (_labels_json_str(key), body))
+    return ",".join(parts), len(parts)
+
+
+def _series_native(m: StepMatrix, vals: np.ndarray, ts_str: list[str]):
+    """:func:`_series_python`'s bytes in one native pass, or None where the
+    library is absent. Python only lays every key's label names and values
+    end to end; the writer applies ``_labels_json``'s rename and last-wins
+    itself. ``surrogatepass`` lets a lone surrogate through as the 3 bytes
+    the writer reads back to the code point ``json.dumps`` escapes."""
+    labels = [k.labels for k in m.keys]
+    counts = np.fromiter(map(len, labels), np.int64, len(labels))
+    strs = list(chain.from_iterable(chain.from_iterable(labels)))
+    text = "".join(strs)
+    blob = text.encode("utf-8", "surrogatepass")
+    lens = map(len, strs) if len(blob) == len(text) else \
+        (len(s.encode("utf-8", "surrogatepass")) for s in strs)
+    offs = np.zeros(len(strs) + 1, np.int64)
+    offs[1:] = np.fromiter(lens, np.int64, len(strs))
+    ts_offs = np.zeros(len(ts_str) + 1, np.int64)
+    ts_offs[1:] = np.fromiter(map(len, ts_str), np.int64, len(ts_str))
+    return native.render_matrix_json(
+        vals, "".join(ts_str).encode(), np.cumsum(ts_offs), blob,
+        np.cumsum(offs), counts)
+
+
+_MATRIX_HEAD = b'{"status":"success","data":{"resultType":"matrix","result":['
+
+
+def matrix_json_bytes(result: QueryResult, full_stats: bool = False) -> bytes:
+    """Prom matrix response as the bytes of its JSON text (ASCII): the
+    series from the native writer, with one copy, or from the Python
+    renderer where the library is absent — the same bytes either way (the
+    reference leans on Jackson streaming for the same reason,
+    ``PromCirceSupport``). Counts the series under the path that wrote
+    them."""
+    m = result.result
+    if m.is_histogram:
+        m = _flatten_histograms(m)
+    m.materialize()
+    vals = np.asarray(m.values, np.float64)
+    ts_str = [repr(t / 1000.0) for t in m.steps_ms.tolist()]
+    rendered = _series_native(m, vals, ts_str)
+    if rendered is None:
+        text, n = _series_python(m, vals, ts_str)
+        series = text.encode()
+        RENDER_SERIES_PYTHON.inc(n)
+    else:
+        series, n = rendered
+        RENDER_SERIES_NATIVE.inc(n)
     stats = json.dumps(_stats_json(result, full=full_stats),
                        separators=(",", ":"))
-    return ('{"status":"success","data":{"resultType":"matrix","result":[%s'
-            ']},"queryStats":%s%s}' % (",".join(parts), stats,
-                                       _partial_fields_str(result)))
+    tail = ']},"queryStats":%s%s}' % (stats, _partial_fields_str(result))
+    return b"".join((_MATRIX_HEAD, series, tail.encode()))
+
+
+def matrix_json_str(result: QueryResult, full_stats: bool = False) -> str:
+    """Prom matrix response rendered straight to a JSON string."""
+    return matrix_json_bytes(result, full_stats).decode()
 
 
 def vector_json_str(result: QueryResult, with_stats: bool = False) -> str:

@@ -5,6 +5,7 @@ differs from the one recorded beside the library) and exposes:
 - fast NibblePack pack/unpack, zigzag, XOR-double prep — byte-identical to
   the numpy reference implementations; used by the ingest/flush hot path.
 - the block arena (reference ``BlockManager`` semantics).
+- the Prom JSON writer of a matrix answer (``http/promjson.py``).
 
 Falls back gracefully (``HAVE_NATIVE = False``) when no compiler is present.
 """
@@ -262,6 +263,10 @@ def _load():
         lib.tagindex_export_label.argtypes = [vp, u32p, u8p, i64p, i32p]
         lib.tagindex_load_label.argtypes = [vp, cp, i64, u32p, i64, u8p, i64,
                                             i64p, i32p, i64]
+        # Prom JSON matrix writer (http/promjson.py:matrix_json_bytes)
+        lib.render_matrix_json.argtypes = [vp, i64, i64, cp, vp, cp, vp, vp,
+                                           vp, i64, i64p]
+        lib.render_matrix_json.restype = i64
         _lib = lib
         HAVE_NATIVE = True
         return lib
@@ -329,6 +334,43 @@ def xor_decode_native(xored: np.ndarray) -> np.ndarray | None:
     lib.xor_decode_f64(_as_ptr(x, ctypes.c_uint64),
                        _as_ptr(out, ctypes.c_double), len(x))
     return out
+
+
+def render_matrix_json(values: np.ndarray, ts: bytes, ts_offs: np.ndarray,
+                       labels: bytes, label_offs: np.ndarray,
+                       label_counts: np.ndarray) -> tuple[memoryview, int] | None:
+    """The series of a Prom matrix answer as JSON, in one native pass (see
+    ``render_matrix_json`` in the C++ source for the layout of the inputs):
+    the written bytes, a view into a fresh buffer, and how many series they
+    hold. None where the library is absent."""
+    lib = _load()
+    if not HAVE_NATIVE:
+        return None
+    vals = np.ascontiguousarray(values, np.float64)
+    p, k = vals.shape
+    ts_offs = np.ascontiguousarray(ts_offs, np.int64)
+    label_offs = np.ascontiguousarray(label_offs, np.int64)
+    label_counts = np.ascontiguousarray(label_counts, np.int64)
+    npairs = int(label_counts.sum())
+    if (len(ts_offs) != k + 1 or ts_offs[-1] > len(ts)
+            or len(label_counts) != p or len(label_offs) != 2 * npairs + 1
+            or label_offs[-1] > len(labels)):
+        raise ValueError("render_matrix_json: offsets do not fit the "
+                         "values and buffers")
+    ts_max = int(np.diff(ts_offs).max()) if k else 0
+    # the writer's widest case: 26 bytes a series, 6 a label pair plus six
+    # for each byte of its strings, and 30 around each point's step time
+    cap = 26 * p + 6 * npairs + 6 * len(labels) \
+        + p * k * (ts_max + 30)
+    out = np.empty(max(cap, 1), np.uint8)
+    nseries = ctypes.c_int64(0)
+    n = lib.render_matrix_json(vals.ctypes.data, p, k, ts, ts_offs.ctypes.data,
+                               labels, label_offs.ctypes.data,
+                               label_counts.ctypes.data, out.ctypes.data, cap,
+                               ctypes.byref(nseries))
+    if n < 0:
+        raise RuntimeError("render_matrix_json: output bound too small")
+    return memoryview(out)[:n], nseries.value
 
 
 class NativeArena:

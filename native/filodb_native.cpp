@@ -10,6 +10,7 @@
 // Build: make -C native   (produces libfilodb_native.so)
 
 #include <algorithm>
+#include <charconv>
 #include <cstdint>
 #include <cstring>
 #include <cstdlib>
@@ -1977,6 +1978,228 @@ int32_t shard_batch_fill(void* cp, const int32_t* pids, int32_t npids,
                              + row * vals_stride, caps[i]);
     }
     return 0;
+}
+
+}  // extern "C"
+
+
+// ---------------------------------------------------------------------------
+// Prom JSON matrix writer (filodb_tpu/http/promjson.py:matrix_json_bytes)
+//
+// The series of a query_range answer, byte for byte what the Python renderer
+// writes: a NaN step is left out, a series with no finite step is left out
+// whole, values are CPython's repr(float) (Prom's "+Inf" / "-Inf" for the
+// infinities), labels are json.dumps(ensure_ascii=True) of the dict
+// promjson._labels_json builds.
+
+namespace {
+
+const char JSON_HEX[] = "0123456789abcdef";
+
+// CPython repr of a finite double: the shortest digits that round-trip
+// (to_chars' scientific form gives them), in exponent form when the decimal
+// exponent is < -4 or >= 16, else positional with ".0" on an integral value
+inline char* put_float_repr(char* p, double v) {
+    char sci[32];
+    char* end = std::to_chars(sci, sci + sizeof sci, v,
+                              std::chars_format::scientific).ptr;
+    *end = '\0';
+    const char* e = static_cast<const char*>(memchr(sci, 'e', end - sci));
+    int exp = atoi(e + 1);
+    if (exp < -4 || exp >= 16) {
+        memcpy(p, sci, end - sci);
+        return p + (end - sci);
+    }
+    const char* s = sci;
+    if (*s == '-') *p++ = *s++;
+    char dig[24];
+    int nd = 0;
+    dig[nd++] = s[0];
+    if (s[1] == '.')
+        for (const char* q = s + 2; q < e; q++) dig[nd++] = *q;
+    if (exp < 0) {
+        *p++ = '0';
+        *p++ = '.';
+        for (int i = 0; i < -exp - 1; i++) *p++ = '0';
+        memcpy(p, dig, nd);
+        return p + nd;
+    }
+    if (nd <= exp + 1) {
+        memcpy(p, dig, nd);
+        p += nd;
+        for (int i = nd; i < exp + 1; i++) *p++ = '0';
+        *p++ = '.';
+        *p++ = '0';
+        return p;
+    }
+    memcpy(p, dig, exp + 1);
+    p += exp + 1;
+    *p++ = '.';
+    memcpy(p, dig + exp + 1, nd - exp - 1);
+    return p + nd - exp - 1;
+}
+
+inline char* put_json_u(char* p, uint32_t c) {
+    *p++ = '\\';
+    *p++ = 'u';
+    for (int sh = 12; sh >= 0; sh -= 4) *p++ = JSON_HEX[(c >> sh) & 15];
+    return p;
+}
+
+// a JSON string of UTF-8 bytes (surrogates as Python's "surrogatepass"
+// writes them), escaped as json.dumps(ensure_ascii=True) does; at most
+// 2 + 6 * n bytes
+char* put_json_str(char* p, const uint8_t* s, int64_t n) {
+    *p++ = '"';
+    for (int64_t i = 0; i < n;) {
+        uint8_t b = s[i];
+        if (b < 0x80) {
+            i++;
+            if (b >= 0x20 && b < 0x7f && b != '"' && b != '\\') {
+                *p++ = static_cast<char>(b);
+                continue;
+            }
+            char esc = 0;
+            switch (b) {
+                case '"': esc = '"'; break;
+                case '\\': esc = '\\'; break;
+                case '\n': esc = 'n'; break;
+                case '\r': esc = 'r'; break;
+                case '\t': esc = 't'; break;
+                case '\b': esc = 'b'; break;
+                case '\f': esc = 'f'; break;
+            }
+            if (esc) {
+                *p++ = '\\';
+                *p++ = esc;
+            } else {
+                p = put_json_u(p, b);
+            }
+            continue;
+        }
+        int len = b >= 0xf0 ? 4 : b >= 0xe0 ? 3 : 2;
+        uint32_t c = b & (0x7f >> len);
+        for (int k = 1; k < len && i + k < n; k++) c = (c << 6) | (s[i + k] & 63);
+        i += len;
+        if (c >= 0x10000) {
+            c -= 0x10000;
+            p = put_json_u(p, 0xd800 | (c >> 10));
+            p = put_json_u(p, 0xdc00 | (c & 0x3ff));
+        } else {
+            p = put_json_u(p, c);
+        }
+    }
+    *p++ = '"';
+    return p;
+}
+
+struct LabelStrs {
+    const uint8_t* buf;
+    const int64_t* offs;
+
+    // string j; the metric label's internal name reads as "__name__"
+    std::string_view name(int64_t j) const {
+        std::string_view s(reinterpret_cast<const char*>(buf) + offs[j],
+                           offs[j + 1] - offs[j]);
+        return s == "_metric_" ? std::string_view("__name__") : s;
+    }
+    std::string_view raw(int64_t j) const {
+        return std::string_view(reinterpret_cast<const char*>(buf) + offs[j],
+                                offs[j + 1] - offs[j]);
+    }
+};
+
+// the dict promjson._labels_json builds from n (name, value) pairs starting
+// at pair `first`: a name keeps the place of its first pair and the value
+// of its last
+char* put_labels(char* p, const LabelStrs& ls, int64_t first, int64_t n) {
+    *p++ = '{';
+    bool any = false;
+    for (int64_t j = first; j < first + n; j++) {
+        std::string_view nm = ls.name(2 * j);
+        bool seen = false;
+        for (int64_t q = first; q < j && !seen; q++) seen = ls.name(2 * q) == nm;
+        if (seen) continue;
+        int64_t last = j;
+        for (int64_t q = j + 1; q < first + n; q++)
+            if (ls.name(2 * q) == nm) last = q;
+        if (any) *p++ = ',';
+        any = true;
+        p = put_json_str(p, reinterpret_cast<const uint8_t*>(nm.data()),
+                         (int64_t)nm.size());
+        *p++ = ':';
+        std::string_view v = ls.raw(2 * last + 1);
+        p = put_json_str(p, reinterpret_cast<const uint8_t*>(v.data()),
+                         (int64_t)v.size());
+    }
+    *p++ = '}';
+    return p;
+}
+
+}  // namespace
+
+extern "C" {
+
+// vals: [P, K] row-major; ts / ts_offs[K + 1]: the K step times, already
+// formatted; labels / label_offs: every series' names and values in turn,
+// label_counts[i] (name, value) pairs for series i. Writes the series,
+// joined by ',', into out and the number written into *nseries. Returns the
+// bytes written, or -1 if cap could not hold them.
+int64_t render_matrix_json(const double* vals, int64_t P, int64_t K,
+                           const char* ts, const int64_t* ts_offs,
+                           const uint8_t* labels, const int64_t* label_offs,
+                           const int64_t* label_counts, char* out,
+                           int64_t cap, int64_t* nseries) {
+    int64_t ts_max = 0;
+    for (int64_t k = 0; k < K; k++)
+        ts_max = std::max(ts_max, ts_offs[k + 1] - ts_offs[k]);
+    LabelStrs ls{labels, label_offs};
+    char* p = out;
+    int64_t first = 0, written = 0;
+    for (int64_t i = 0; i < P; i++) {
+        const double* row = vals + i * K;
+        int64_t n = label_counts[i];
+        int64_t pair0 = first;
+        first += n;
+        int64_t k0 = 0;
+        while (k0 < K && row[k0] != row[k0]) k0++;
+        if (k0 == K) continue;
+        // "{"metric":{...},"values":[...]}," with every piece at its widest
+        int64_t need = 26 + 6 * n
+            + 6 * (label_offs[2 * first] - label_offs[2 * pair0])
+            + K * (ts_max + 30);
+        if ((p - out) + need > cap) return -1;
+        if (written++) *p++ = ',';
+        memcpy(p, "{\"metric\":", 10);
+        p = put_labels(p + 10, ls, pair0, n);
+        memcpy(p, ",\"values\":[", 11);
+        p += 11;
+        for (int64_t k = k0; k < K; k++) {
+            double v = row[k];
+            if (v != v) continue;
+            if (k != k0) *p++ = ',';
+            *p++ = '[';
+            memcpy(p, ts + ts_offs[k], ts_offs[k + 1] - ts_offs[k]);
+            p += ts_offs[k + 1] - ts_offs[k];
+            *p++ = ',';
+            *p++ = '"';
+            if (v == std::numeric_limits<double>::infinity()) {
+                memcpy(p, "+Inf", 4);
+                p += 4;
+            } else if (v == -std::numeric_limits<double>::infinity()) {
+                memcpy(p, "-Inf", 4);
+                p += 4;
+            } else {
+                p = put_float_repr(p, v);
+            }
+            *p++ = '"';
+            *p++ = ']';
+        }
+        *p++ = ']';
+        *p++ = '}';
+    }
+    *nseries = written;
+    return p - out;
 }
 
 }  // extern "C"

@@ -204,6 +204,9 @@ TRACING_NAMES = [
     "filodb_http_render_seconds_bucket",
     "filodb_http_render_seconds_count",
     "filodb_http_render_seconds_sum",
+    # the series of matrix answers, by the renderer that wrote them
+    # (http/promjson.py, registered at import)
+    "filodb_http_render_series_total",
     # the fast front's wait from a request's arrival at the socket to the
     # loop taking it up (http/server.py, registered at import)
     "filodb_http_queue_wait_seconds_bucket",
