@@ -77,6 +77,11 @@ _M_BUCKET_SAMPLES = get_counter(
     "evaluates the placed rows (an eval-cache miss, a fused dispatch), so "
     "a dispatch the eval cache answers, which runs the group reduce alone, "
     "moves filodb_mesh_samples_scanned_total and not this")
+_M_HIST_ROWS = {f: get_counter("filodb_hist_quantile_rows", {"form": f},
+                               help="bucket rows a histogram_quantile step "
+                               "is handed: groups x buckets of a first-class "
+                               "column, the series of classic le buckets")
+                for f in ("column", "le")}
 _M_COMPILE = {e: get_counter("filodb_mesh_compile_cache", {"event": e},
                              help="compiled mesh program cache hits/misses")
               for e in ("hit", "miss")}
@@ -966,11 +971,7 @@ class MeshQueryEngine:
             if op[0] == "instant":
                 mapper = InstantVectorFunctionMapper(op[1], op[2])
                 if op[1] == "histogram_quantile":
-                    with span("hist-quantile", groups=m.num_series,
-                              steps=m.num_steps,
-                              buckets=m.values.shape[2] if m.is_histogram
-                              else 0):
-                        m = mapper.apply(m)
+                    m = MeshQueryEngine._hist_quantile(mapper, m)
                 else:
                     m = mapper.apply(m)
             elif op[0] == "scalarop":
@@ -981,3 +982,26 @@ class MeshQueryEngine:
                 m = AggregateMapReduce(op=op[1], params=op[2], by=op[3],
                                        without=op[4]).apply(m)
         return m
+
+    @staticmethod
+    def _hist_quantile(mapper, m: StepMatrix) -> StepMatrix:
+        """``histogram_quantile`` under its ``hist-quantile`` span, in
+        either form: ``column`` over a first-class histogram's ``[n, K, B]``
+        rows, ``le`` over classic bucket series, each an ``le``-labelled row
+        that the mapper groups by its key without ``le``. ``groups`` and
+        ``buckets`` (bucket rows a group) are the answer's, tagged once it
+        is made; ``filodb_hist_quantile_rows_total{form}`` moves by the
+        bucket rows the step is handed (on the ``le`` form every row: one
+        without ``le`` is read and dropped, as Prometheus drops it)."""
+        if m.is_histogram:
+            form, rows = "column", m.num_series * m.values.shape[2]
+        else:
+            form, rows = "le", m.num_series
+        with span("hist-quantile", form=form, steps=m.num_steps) as s:
+            out = mapper.apply(m)
+            if s is not None:
+                s.tags["groups"] = out.num_series
+                s.tags["buckets"] = rows // out.num_series \
+                    if out.num_series else 0
+        _M_HIST_ROWS[form].inc(rows)
+        return out

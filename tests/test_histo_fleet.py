@@ -536,6 +536,24 @@ def test_hist_quantile_is_seen_once_where_it_runs(fleet, result_cache,
         front.close()
 
 
+@pytest.mark.parametrize("panel", range(2), ids=PANELS[:2])
+def test_hist_quantile_says_column_and_counts_the_bucket_rows(
+        fleet, front, panel, trace_everything):
+    """A first-class histogram's quantile is the ``column`` form: the span
+    says so, and ``filodb_hist_quantile_rows_total{form="column"}`` moves
+    by groups × buckets a quantile, the ``le`` form's not at all."""
+    rows = "filodb_hist_quantile_rows_total"
+    at = {f: counter(rows, form=f) for f in ("column", "le")}
+    spans = recorded(front, panel_request(fleet, panel, 2, 5013))
+    quantiles = named(spans, "hist-quantile")
+    assert len(quantiles) == len(named(spans, "mesh-execute")) >= 2
+    assert all(q["tags"]["form"] == "column" for q in quantiles)
+    assert counter(rows, form="column") - at["column"] == sum(
+        q["tags"]["groups"] * q["tags"]["buckets"] for q in quantiles) \
+        == len(quantiles) * BUCKETS
+    assert counter(rows, form="le") == at["le"]
+
+
 def test_the_new_spans_are_inside_their_parents_and_the_phases_tile(
         fleet, front, trace_everything):
     """``hist-flatten`` and ``hist-quantile`` are grandchildren: the
@@ -739,20 +757,22 @@ def test_the_configuration_is_the_draft_uncut(fleet):
 
 def test_the_benchmark_lists_one_deployment_one_cell_three_readers(fleet):
     bench = read_json(ROOT, "BENCHMARK.json")
-    entry = bench["configs"][-1]
-    assert entry["name"] == CONFIG and entry["reduced"] == []
+    (entry,) = [c for c in bench["configs"] if c["name"] == CONFIG]
+    assert entry["reduced"] == []
     assert entry["file"] == f"perf/configs/{CONFIG}.json"
     assert entry["source"] == fleet["config"]["source"]
     assert len(entry["source"]) <= 200 and len(entry["why"]) <= 200
-    cell = bench["workloads"][-1]
-    assert cell == {**cell, "name": CELL, "config": CONFIG,
-                    "traffic": "latency-panels", "chips": 1}
+    (cell,) = [w for w in bench["workloads"] if w["name"] == CELL]
+    assert cell == {**cell, "config": CONFIG, "traffic": "latency-panels",
+                    "chips": 1}
     assert len(cell["why"]) <= 200
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index(READERS[0])
     new = bench["per_layer"][at:at + 3]
     assert [m["name"] for m in new] == list(READERS)
-    assert all(m["workloads"] == [CELL] for m in new)
+    # written for this cell; the le-histo-fleet cell reads the roofline too
+    assert [m["workloads"] for m in new] == [
+        [CELL], [CELL, "le-histo-fleet.latency-panels"], [CELL]]
     assert [(m["unit"], m["better"], m["source"], m["layer"], m["moves"])
             for m in new] == [
         ("ms", "lower", "program_span", "mesh engine", "queries_per_s"),

@@ -324,6 +324,7 @@ MESH_NAMES = [
     "filodb_mesh_hit_rate",
     "filodb_mesh_samples_scanned_total",
     "filodb_mesh_bucket_samples_scanned_total",
+    "filodb_hist_quantile_rows_total",
 ]
 
 
