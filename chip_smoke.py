@@ -680,7 +680,9 @@ def phase_proof(svc, platform: str) -> dict:
     platforms = sorted({d.platform for placed in batches for a in placed
                         for d in a.devices()})
     assert platforms == [platform], platforms
-    held = sum(a.nbytes for placed in batches for a in placed)
+    # the groupings of one held selector share all but their group ids
+    arrays = {id(a): a for placed in batches for a in placed}
+    held = sum(a.nbytes for a in arrays.values())
     stats = [d.memory_stats() or {} for d in jax.local_devices()]
     return {"batch_platforms": platforms, "placed_batches": len(batches),
             "placed_batch_bytes": int(held),

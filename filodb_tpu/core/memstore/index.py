@@ -331,6 +331,12 @@ class PartKeyIndex:
     def end_time(self, part_id: int) -> int:
         return int(self._end[part_id])
 
+    def time_spans(self, part_ids: np.ndarray) -> tuple[np.ndarray,
+                                                        np.ndarray]:
+        """Start and end times of ``part_ids`` as the time predicate of
+        ``part_ids_from_filters`` reads them now."""
+        return self._start[part_ids], self._end[part_ids]
+
     def part_key(self, part_id: int) -> PartKey | None:
         if part_id >= len(self._part_keys):
             return None

@@ -609,3 +609,11 @@ def shard_batch_arrays(mesh: Mesh, ts, vals, valid, group_ids, raw=None):
                  for a, s in ((ts, s2), (vals, s2), (valid, s2),
                               (group_ids, s1), (raw, s2))
                  if a is not None)
+
+
+def shard_group_ids(mesh: Mesh, group_ids):
+    """Place group ids as ``shard_batch_arrays`` places them: another
+    grouping of rows that are placed already."""
+    keeps = mesh.devices.flat[0].platform == "cpu"
+    return jax.device_put(group_ids.copy() if keeps else group_ids,
+                          NamedSharding(mesh, P("shard")))

@@ -31,10 +31,15 @@ scatter-gather exec tree for every other plan shape.
 from __future__ import annotations
 
 import logging
+import threading
 from dataclasses import dataclass, field
 
 import numpy as np
 
+from filodb_tpu.core.memstore.index import INGESTING
+from filodb_tpu.core.memstore.native_shard import NativeBackedPartition
+from filodb_tpu.core.memstore.odp import needs_paging
+from filodb_tpu.core.schemas import ColumnType
 from filodb_tpu.parallel.dist_query import (
     MESH_AGG_OPS,
     SPLIT_FNS,
@@ -44,9 +49,11 @@ from filodb_tpu.parallel.dist_query import (
     make_mesh_eval_simple,
     make_mesh_group_reduce,
     make_mesh_prepare,
+    shard_group_ids,
 )
 from filodb_tpu.parallel.staging import StagingPool
 from filodb_tpu.query import logical as lp
+from filodb_tpu.query.engine.batch import BatchHeader
 from filodb_tpu.query.model import QueryStats, RangeVectorKey, StepMatrix
 from filodb_tpu.utils.metrics import GaugeFn, get_counter
 from filodb_tpu.utils.tracing import span, tag
@@ -88,6 +95,13 @@ _M_COMPILE = {e: get_counter("filodb_mesh_compile_cache", {"event": e},
 _M_BATCH = {e: get_counter("filodb_mesh_batch_cache", {"event": e},
                            help="decoded+placed batch cache hits/misses")
             for e in ("hit", "miss")}
+_M_BUILDS = {s: get_counter("filodb_mesh_batch_builds", {"span": s},
+                            help="placed batches built, by the span they "
+                            "cover: held (all the shards hold of a selector "
+                            "the native core reads whole, at most four "
+                            "ranges, kept across ranges) or range (one "
+                            "request's range)")
+             for s in ("held", "range")}
 _BOUNDS_HELP = ("cached window-bounds hits/misses on the split pipeline; "
                 "a miss runs the bounds program, in the form ``method``")
 _M_BOUNDS = {"hit": get_counter("filodb_mesh_bounds_cache", {"event": "hit"},
@@ -139,6 +153,122 @@ def _device_correction_ok(vals: np.ndarray) -> bool:
     # magnitude from the two reductions: mask, as before
     finite = vals[np.isfinite(vals)]
     return finite.size == 0 or float(np.abs(finite).max()) < F32_SAFE_MAX
+
+
+_I32 = np.iinfo(np.int32)
+
+
+def _fits_i32(base: int, lo: int, hi: int) -> bool:
+    """Are the times of [lo, hi] int32 ms from ``base``, as a batch's
+    samples and a grid's steps and window starts are placed? The largest
+    int32 is the padding's timestamp, so ``hi`` stays below it."""
+    return _I32.min <= lo - base and hi - base < _I32.max
+
+
+def _native_scalar(p) -> bool:
+    """Does a native core read this partition's value column: the series
+    lives in a core, and the column is not a histogram (which
+    ``NativeShardCore.batch_count`` declines)."""
+    if not isinstance(p, NativeBackedPartition):
+        return False
+    data = p.schema.data
+    return data.columns[data.value_column].ctype is not ColumnType.HISTOGRAM
+
+
+# a selector's batch is held only where the span the shards hold of it is
+# at most this many times the asked range: its build then reads and places
+# at most that many times what a build of the range alone would, and a
+# store that holds far more (long retention) keeps the range build. An
+# hour at a 60-s step is asked as two or three result-cache extents of 32
+# steps; four such ranges span the two hours one held batch serves them from
+_HELD_RANGES = 4
+
+
+def _holds(start: int, end: int, lo: int, hi: int) -> bool:
+    """May a batch of [start, end] be held for, and answer, [lo, hi]: the
+    span is at most ``_HELD_RANGES`` times the range, and both are int32
+    ms from ``start``."""
+    return end - start <= _HELD_RANGES * (hi - lo) \
+        and _fits_i32(start, start, end) and _fits_i32(start, lo, hi)
+
+
+@dataclass
+class HeldHeader(BatchHeader):
+    """The header of a held batch: its span, from ``base_ts`` to ``end``,
+    and where its rows come from."""
+
+    end: int = 0
+    # for each shard: its index, the part ids it holds and the batch row of
+    # each, ((PartKeyIndex, pids int64 [n], rows int64 [n]), ...)
+    owners: tuple = ()
+
+    def answers(self, lo: int, hi: int) -> bool:
+        return _holds(self.base_ts, self.end, lo, hi)
+
+    def in_range(self, lo: int, hi: int) -> np.ndarray:
+        """bool [series]: the series a lookup of [lo, hi] finds, by the
+        index's time predicate as the index stands now."""
+        mask = np.zeros(len(self.part_ids), bool)
+        for index, pids, rows in self.owners:
+            start, end = index.time_spans(pids)
+            mask[rows] = (start <= hi) & (end >= lo)
+        return mask
+
+
+def _held_build(shards, filters, lo: int, hi: int):
+    """The one rule that picks a held build over a range build, read off
+    the input alone. A selector's batch is built over the span the shards
+    hold of it, from its partitions' first sample (the index's start time
+    of each; samples out of order are dropped) to the newest sample
+    ingested, and kept across ranges, only where ``_holds`` says the span
+    answers [lo, hi] and the native core fills every row of it: every
+    partition lives in a native core, its value column is not a histogram,
+    and memory holds all the index knows of it, so nothing would page.
+    Every row holds all its series' samples, so an answer does not depend
+    on which request built the batch. Returns (partitions, start, end,
+    ``HeldHeader.owners``), or None: build [lo, hi] alone, as its own
+    lookup and paging find it."""
+    end = max(s.max_ingested_ts for s in shards)
+    held, owners, start = [], [], end
+    for shard in shards:
+        pids = np.asarray(shard.lookup_partitions(list(filters), 0,
+                                                  INGESTING), np.int64)
+        if not len(pids):
+            continue
+        live = shard.partitions
+        sparts = [live[pid] if pid < len(live) else None for pid in pids]
+        if not all(map(_native_scalar, sparts)):
+            return None
+        starts, _ = shard.index.time_spans(pids)
+        start = min(start, int(starts.min()))
+        if end - start > _HELD_RANGES * (hi - lo):
+            return None     # the span only grows with the next shard
+        # a read from the first sample the index knows of would page
+        if shard.config.demand_paging_enabled and any(
+                needs_paging(p, s, s) for p, s in zip(sparts, starts)):
+            return None
+        owners.append((shard.index, pids,
+                       np.arange(len(held), len(held) + len(pids))))
+        held.extend(sparts)
+    if not held or not _holds(start, end, lo, hi):
+        return None
+    return held, start, end, tuple(owners)
+
+
+def _range_rows(held: HeldHeader, gids: np.ndarray, grouped: bool, lo: int,
+                hi: int) -> np.ndarray | None:
+    """The rows of a held batch's answer that a build of [lo, hi] alone
+    answers with: the series its lookup finds, or (``grouped``) the groups
+    that hold one of them, in the order of their first such series. A
+    group or series with no sample in [lo, hi] then leaves the answer as
+    the range build leaves it. None: every row, in order."""
+    found = held.in_range(lo, hi)
+    if found.all():
+        return None
+    if not grouped:
+        return np.flatnonzero(found)
+    groups, first = np.unique(gids[:len(found)][found], return_index=True)
+    return groups[np.argsort(first)]
 
 
 def _placed_values(vals: np.ndarray, take, marks: np.ndarray) -> np.ndarray:
@@ -257,9 +387,15 @@ class MeshQueryEngine:
     # (the mesh analog of the exec path's per-shard batch cache). An entry
     # is (version, BatchHeader, keys, gids, out_keys, placed, is_counter):
     # the device arrays and what a hit reads beside them — never the host
-    # ``ts``/``vals``, whose memory went back to ``_staging``
+    # ``ts``/``vals``, whose memory went back to ``_staging``. A held
+    # batch's key has no range; the groupings of one selector and lane
+    # share its arrays but the group ids (``_held_entry``)
     _batch_cache: dict = field(default_factory=dict)
     _batch_cache_cap: int = 16
+    # held over each change of the batch cache's entries, and over a copy
+    # of them: request threads of the front door share one engine
+    _cache_lock: threading.Lock = field(default_factory=threading.Lock,
+                                        repr=False, compare=False)
     # the [P, S] host arrays one placement needs (build_batch's ts/vals,
     # the validity mask, the split lane's converted copy, a histogram's
     # bucket rows), taken back once the placed arrays are ready
@@ -455,8 +591,15 @@ class MeshQueryEngine:
         list aligned with ``lows`` — every query in the group scanned the
         whole shared batch, so each gets the full scan counts.
 
+        A batch is built over the request's range, or, where
+        ``_held_build`` says so, over all the shards hold of the selector
+        and kept across ranges
+        (``filodb_mesh_batch_builds_total{span}``).
+
         Phase spans, in order, tiling the caller's ``mesh-execute``:
-        ``mesh-lookup`` (posting lists, paging), ``decode`` (``build_batch``:
+        ``mesh-lookup`` (the held rule's lookup; else the range's posting
+        lists and paging),
+        ``decode`` (tag ``span``, "held" or "range"; ``build_batch``:
         every sample written once into arrays of the placed shape — and, on
         the ``raw`` lane, the placed dtype — that come from ``_staging``,
         tag ``reused_bytes`` on ``batch-stack``), ``mesh-group`` (keys and
@@ -470,14 +613,15 @@ class MeshQueryEngine:
         buffer too, tag ``reused_bytes`` on both spans),
         ``mesh-place`` (the put: the batch's own ``ts``/``vals`` on the
         ``raw`` lane); a batch-cache hit opens none of these five and takes
-        nothing from the pool. Then ``mesh-dispatch``, ``mesh-fetch`` — at
-        whose end a miss waits for its placed arrays and gives the staging
-        buffers back, the one point where they return — ``mesh-assemble``
-        (tag ``buckets`` on a histogram batch; a ``histogram_quantile``
-        post-transform runs under ``hist-quantile`` inside it)."""
+        nothing from the pool, but for ``mesh-group`` (tag ``shared``)
+        where it lends another grouping's held placement. Then
+        ``mesh-dispatch``, ``mesh-fetch`` — at whose end a miss waits for
+        its placed arrays and gives the staging buffers back, the one point
+        where they return — ``mesh-assemble`` (tag ``buckets`` on a
+        histogram batch; a ``histogram_quantile`` post-transform runs under
+        ``hist-quantile`` inside it)."""
         stats_objs = stats if isinstance(stats, list) \
             else ([stats] if stats is not None else [])
-        from filodb_tpu.core.memstore.odp import page_partitions
         from filodb_tpu.parallel.dist_query import (
             make_distributed_range_agg,
             shard_batch_arrays,
@@ -512,18 +656,22 @@ class MeshQueryEngine:
         # the agg NAME is part of the key (not just agg-vs-none): a
         # histogram batch cached under sum must not satisfy a later
         # min/max/avg over the same selector — those fall back to the
-        # exec path, and the cache-hit branch must re-make that decision
-        ckey = (dataset, str(low0.filters), chunk_start, chunk_end,
-                low0.by, low0.without, low0.agg, lane)
-        # split-pipeline device caches (prepare/bounds/eval) consume only
-        # the data tensors, never the grouping — keyed WITHOUT agg/by so
-        # e.g. sum() and avg() over the same rate() share one evaluation
-        dkey = (dataset, str(low0.filters), chunk_start, chunk_end, lane)
-        cached = self._batch_cache.get(ckey)
-        _M_BATCH["hit" if cached is not None and cached[0] == version
-                 else "miss"].inc()
+        # exec path, and the cache-hit branch must re-make that decision.
+        # A held batch (``_held_build``) is kept under ``hkey``, which has
+        # no range; any other batch under ``ckey``, which has the range
+        filters = str(low0.filters)
+        hkey = (dataset, filters, low0.by, low0.without, low0.agg, lane)
+        ckey = (dataset, filters, chunk_start, chunk_end, low0.by,
+                low0.without, low0.agg, lane)
+        cached = self._held_entry(hkey, version, chunk_start, chunk_end,
+                                  low0, mesh)
+        if cached is None:
+            cached = self._batch_cache.get(ckey)
+            if cached is not None and cached[0] != version:
+                cached = None
+        _M_BATCH["miss" if cached is None else "hit"].inc()
         lease = None
-        if cached is not None and cached[0] == version:
+        if cached is not None:
             _, batch, keys, gids, out_keys, placed, is_counter = cached
             if batch is None:
                 return [StepMatrix.empty(steps_array(lo.start, lo.step,
@@ -541,30 +689,18 @@ class MeshQueryEngine:
             # the host arrays of THIS placement; dropped, not given back,
             # by any return or exception before the end of mesh-fetch
             lease = self._staging.lease()
-            parts = []
             extra_by_obj: dict[int, list] = {}
             with span("mesh-lookup", shards=len(shards)) as sp:
-                for shard in shards:
-                    sparts = []
-                    for pid in shard.lookup_partitions(
-                            list(low0.filters), chunk_start, chunk_end):
-                        p = shard.partition(pid)
-                        if p is not None:
-                            sparts.append(p)
-                    # on-demand paging: cold chunks (flushed + evicted-from-
-                    # RAM, or pre-restart data recovered only to the column
-                    # store) are merged exactly like the exec path does
-                    # (plan.py) — keyed by object identity because part_ids
-                    # repeat across shards
-                    if sparts and shard.config.demand_paging_enabled:
-                        extra = page_partitions(shard, sparts, chunk_start,
-                                                chunk_end, shard.odp_cache)
-                        if extra:
-                            for p in sparts:
-                                ec = extra.get(p.part_id)
-                                if ec:
-                                    extra_by_obj[id(p)] = ec
-                    parts.extend(sparts)
+                held = _held_build(shards, low0.filters, chunk_start,
+                                   chunk_end)
+                if held is not None:
+                    parts, build_start, build_end, owners = held
+                else:
+                    build_start, build_end, owners = (chunk_start,
+                                                      chunk_end, None)
+                    parts = self._range_lookup(shards, low0.filters,
+                                               chunk_start, chunk_end,
+                                               extra_by_obj)
                 if sp is not None:
                     sp.tags.update(partitions=len(parts),
                                    paged=len(extra_by_obj))
@@ -574,16 +710,21 @@ class MeshQueryEngine:
                 return [StepMatrix.empty(steps_array(lo.start, lo.step,
                                                      lo.end))
                         for lo in lows]
-            with span("decode", partitions=len(parts)) as sp:
+            build = "range" if owners is None else "held"
+            _M_BUILDS[build].inc()
+            with span("decode", partitions=len(parts), span=build) as sp:
                 # the batch is built at the shape that is placed, and on
                 # the raw lane (no host f64 pass follows) in the dtype too
                 built = build_batch(
-                    parts, chunk_start, chunk_end,
+                    parts, build_start, build_end,
                     extra_by_obj=extra_by_obj or None,
                     mesh_multiples=(mesh.shape["shard"], mesh.shape["time"]),
                     host_f64=lane != "raw", alloc=lease.take)
                 # what the cached entry keeps, and what a hit reads
                 batch = built.header()
+                if owners is not None:
+                    batch = HeldHeader(**vars(batch), end=build_end,
+                                       owners=owners)
                 samples = int(batch.counts.sum())
                 if sp is not None:
                     sp.tags.update(samples=samples,
@@ -599,25 +740,30 @@ class MeshQueryEngine:
             for st in stats_objs:
                 st.series_scanned += len(parts)
                 st.samples_scanned += samples
-            # label grouping (first-occurrence order, like
-            # AggregateMapReduce). The metric label is dropped first — the
-            # exec path drops it in range-function output keys before
-            # grouping, so `by (_metric_)` must group on nothing there too.
             with span("mesh-group") as sp:
                 keys = [p.part_key.range_vector_key for p in parts]
-                # one id a padded row: padding series join group 0 and
-                # contribute nothing (no valid samples)
-                gids = np.zeros(built.ts.shape[0], np.int32)
-                if low0.agg is None:
-                    out_keys = []
-                else:
-                    gkeys = [self._group_key(k, low0) for k in keys]
-                    uniq: dict[RangeVectorKey, int] = {}
-                    for i, gk in enumerate(gkeys):
-                        gids[i] = uniq.setdefault(gk, len(uniq))
-                    out_keys = list(uniq.keys())
+                gids, out_keys = self._group_ids(keys, built.ts.shape[0],
+                                                 low0)
                 if sp is not None:
                     sp.tags["groups"] = len(out_keys)
+        # split-pipeline device caches (prepare/bounds/eval) consume only
+        # the data tensors, never the grouping — keyed WITHOUT agg/by so
+        # e.g. sum() and avg() over the same rate() share one evaluation;
+        # a held batch's without the range too, so every range and
+        # grouping over one placement shares them
+        pick = None
+        if not isinstance(batch, HeldHeader):
+            dkey = (dataset, filters, chunk_start, chunk_end, lane)
+        else:
+            dkey = (dataset, filters, lane, batch.base_ts)
+            # the rows a build of [chunk_start, chunk_end] would answer
+            # with; none: the answer a range build with no series gives
+            pick = _range_rows(batch, gids, low0.agg is not None,
+                               chunk_start, chunk_end)
+            if pick is not None and not len(pick):
+                return [StepMatrix.empty(steps_array(lo.start, lo.step,
+                                                     lo.end))
+                        for lo in lows]
         # histogram batches flatten buckets into the series axis: every
         # (series, bucket) pair becomes one scalar row, group ids become
         # g*B + b, and the same associative kernels/combines apply. The
@@ -716,8 +862,9 @@ class MeshQueryEngine:
                     sp.tags["bytes"] = sum(
                         a.nbytes for a in (ts_p, vals_p, valid, gid_p,
                                            raw_p) if a is not None)
-            self._cache_put(ckey, (version, batch, keys, gids, out_keys,
-                                   placed, is_counter))
+            self._cache_put(hkey if isinstance(batch, HeldHeader) else ckey,
+                            (version, batch, keys, gids, out_keys, placed,
+                             is_counter))
 
         with span("mesh-dispatch",
                   form="split" if use_split else "fused") as sp:
@@ -847,15 +994,99 @@ class MeshQueryEngine:
                             else [k.drop_metric() for k in keys]
                     else:
                         rkeys = out_keys
+                    if pick is not None:
+                        vals = vals[pick]
+                        rkeys = [rkeys[r] for r in pick]
                     m = StepMatrix(list(rkeys), vals, steps_ms,
                                    batch.les if B > 1 else None)
                     results[i] = self._apply_post(m, lo)
         return results
 
+    @staticmethod
+    def _range_lookup(shards, filters, start: int, end: int,
+                      extra_by_obj: dict) -> list:
+        """The partitions of [start, end] on every shard, with the chunks
+        on-demand paging brings for them put in ``extra_by_obj``."""
+        from filodb_tpu.core.memstore.odp import page_partitions
+
+        parts = []
+        for shard in shards:
+            sparts = []
+            for pid in shard.lookup_partitions(list(filters), start, end):
+                p = shard.partition(pid)
+                if p is not None:
+                    sparts.append(p)
+            # on-demand paging: cold chunks (flushed + evicted-from-RAM, or
+            # pre-restart data recovered only to the column store) are
+            # merged exactly like the exec path does (plan.py) — keyed by
+            # object identity because part_ids repeat across shards
+            if sparts and shard.config.demand_paging_enabled:
+                extra = page_partitions(shard, sparts, start, end,
+                                        shard.odp_cache)
+                if extra:
+                    for p in sparts:
+                        ec = extra.get(p.part_id)
+                        if ec:
+                            extra_by_obj[id(p)] = ec
+            parts.extend(sparts)
+        return parts
+
     def _cache_put(self, ckey, entry):
-        if len(self._batch_cache) >= self._batch_cache_cap:
-            self._batch_cache.pop(next(iter(self._batch_cache)))
-        self._batch_cache[ckey] = entry
+        with self._cache_lock:
+            # a key put again (a new version) is the newest, and evicts none
+            self._batch_cache.pop(ckey, None)
+            if len(self._batch_cache) >= self._batch_cache_cap:
+                self._batch_cache.pop(next(iter(self._batch_cache)))
+            self._batch_cache[ckey] = entry
+
+    def _held_entry(self, hkey, version, lo, hi, low, mesh):
+        """The held entry that answers [lo, hi] under ``hkey`` at
+        ``version``, or None. Where ``hkey`` has none, a held entry of
+        another grouping of the same selector and lane lends its placement:
+        only this grouping's group ids are made, under ``mesh-group``, and
+        placed, and the entry is kept under ``hkey``."""
+
+        def answers(entry):
+            return entry is not None and entry[0] == version \
+                and isinstance(entry[1], HeldHeader) \
+                and entry[1].answers(lo, hi)
+
+        entry = self._batch_cache.get(hkey)
+        if answers(entry):
+            return entry
+        with self._cache_lock:      # other request threads put entries
+            entries = list(self._batch_cache.items())
+        for key, other in entries:
+            if key[:2] == hkey[:2] and key[-1] == hkey[-1] \
+                    and answers(other):
+                break
+        else:
+            return None
+        _, batch, keys, lender_gids, _, placed, is_counter = other
+        with span("mesh-group", shared=True) as sp:
+            gids, out_keys = self._group_ids(keys, len(lender_gids), low)
+            placed = placed[:3] + (shard_group_ids(mesh, gids),) \
+                + placed[4:]
+            if sp is not None:
+                sp.tags["groups"] = len(out_keys)
+        entry = (version, batch, keys, gids, out_keys, placed, is_counter)
+        self._cache_put(hkey, entry)
+        return entry
+
+    def _group_ids(self, keys, rows: int, low: _Lowered):
+        """(group id of each of ``rows`` batch rows, the groups' keys) in
+        first-occurrence order, like AggregateMapReduce. The metric label
+        is dropped first — the exec path drops it in range-function output
+        keys before grouping, so `by (_metric_)` must group on nothing
+        there too. Padding rows join group 0 and add nothing (no valid
+        samples); without an aggregate every row is group 0."""
+        gids = np.zeros(rows, np.int32)
+        if low.agg is None:
+            return gids, []
+        uniq: dict[RangeVectorKey, int] = {}
+        for i, k in enumerate(keys):
+            gids[i] = uniq.setdefault(self._group_key(k, low), len(uniq))
+        return gids, list(uniq)
 
     def _get_fn(self, key, builder):
         """Compiled-program cache with hit/miss accounting."""

@@ -5,6 +5,7 @@ against ``QueryService.query_range_many`` and ``FastHttpServer`` — what the
 cell ``fleet-110k.dash-unaligned`` drives on the chip. Also the counters
 that cell's per-layer metrics read, and their readers on canned inputs."""
 
+import collections
 import http.client
 import importlib.util
 import json
@@ -279,17 +280,34 @@ def test_counters_of_a_six_member_call_and_of_a_batch_cache_hit(fleet):
     assert counter("filodb_query_batch_members_total") - m0 == 12
 
 
-def test_samples_scanned_under_the_result_cache_is_a_sum_over_extents(fleet):
+def test_samples_scanned_under_the_result_cache_is_a_sum_over_extents(
+        fleet, monkeypatch):
     # the default layout: each member's missed extents are evaluated one
-    # by one, a dispatch and a scan each
+    # by one, a dispatch and a scan each. The native core reads every row,
+    # so a selector is built once, over a span that holds every extent,
+    # and each extent's dispatch scans that whole held batch
     svc = service(fleet)
+    engine = svc.mesh_engine
+    calls = collections.Counter()
+    run = engine.execute_lowered_many
+
+    def counted(lows, *args, **kwargs):
+        calls[str(lows[0].filters)] += 1
+        return run(lows, *args, **kwargs)
+
+    monkeypatch.setattr(engine, "execute_lowered_many", counted)
     s0 = counter("filodb_mesh_samples_scanned_total")
     d0 = counter("filodb_mesh_dispatch_total")
+    h0 = counter("filodb_mesh_batch_builds_total", span="held")
     svc.query_range_many(members(fleet, 2, keys=(3, 4), offsets=(3607, 7001)))
     dispatches = counter("filodb_mesh_dispatch_total") - d0
-    placed = placed_samples(svc)
-    assert dispatches == len(placed) >= 2
-    assert counter("filodb_mesh_samples_scanned_total") - s0 == sum(placed)
+    placed = {key[1]: int(entry[1].counts.sum())
+              for key, entry in engine._batch_cache.items()}
+    assert counter("filodb_mesh_batch_builds_total", span="held") - h0 \
+        == len(placed) == 2
+    assert sum(calls.values()) == dispatches > len(placed)
+    assert counter("filodb_mesh_samples_scanned_total") - s0 \
+        == sum(n * placed[sel] for sel, n in calls.items())
 
 
 # ---------------------------------------------------------------------------

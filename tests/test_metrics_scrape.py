@@ -318,6 +318,7 @@ MESH_NAMES = [
     "filodb_mesh_dispatch_total",
     "filodb_mesh_compile_cache_total",
     "filodb_mesh_batch_cache_total",
+    "filodb_mesh_batch_builds_total",
     "filodb_mesh_bounds_cache_total",
     "filodb_mesh_eval_cache_total",
     "filodb_mesh_fallback_total",
@@ -641,7 +642,7 @@ class TestMetricsScrape:
                                                               source):
         """``filodb_batch_buffer_bytes_total{source=...}`` renders before
         any query under one HELP/TYPE header; a server's mesh queries over
-        new chunk ranges move it by whole ``[P, S]`` arrays — ``fresh`` on
+        new selectors move it by whole ``[P, S]`` arrays — ``fresh`` on
         the first build of a shape, ``reused`` after."""
         srv = server
         family = "filodb_batch_buffer_bytes_total"
@@ -672,14 +673,15 @@ class TestMetricsScrape:
                 break
             time.sleep(0.3)
         moved = []
-        # the aggregation is in the batch cache's key: two misses that
-        # build the same shapes, the second into the first's arrays
-        for agg in ("sum", "max"):
+        # two selectors of the same series, each a batch-cache key of its
+        # own: two misses that build the same shapes, the second into the
+        # first's arrays
+        for sel in ("", "%7Bhost%21%3D%22%22%7D"):   # {host!=""}
             at = values(_scrape(srv.http.port))
             with urllib.request.urlopen(
                     f"http://127.0.0.1:{srv.http.port}/promql/timeseries/"
-                    f"api/v1/query_range?query={agg}(max_over_time("
-                    f"buffer_metric_{source}%5B1m%5D))"
+                    f"api/v1/query_range?query=sum(max_over_time("
+                    f"buffer_metric_{source}{sel}%5B1m%5D))"
                     f"&start={START}&end={START + 1500}&step=60") as r:
                 assert r.status == 200
             now = values(_scrape(srv.http.port))
@@ -687,6 +689,45 @@ class TestMetricsScrape:
         first, second = moved
         assert sum(first.values()) == sum(second.values()) > 0
         assert second == {"fresh": 0, "reused": sum(first.values())}
+
+    @pytest.mark.parametrize("span", ["held", "range"])
+    def test_batch_builds_family_is_scraped_with_both_spans(self, server,
+                                                            span):
+        """``filodb_mesh_batch_builds_total{span=...}`` renders before any
+        query under one HELP/TYPE header that says what each span covers.
+        Where the native core reads every row, a selector is built once,
+        over all it holds, and a query at another range finds that batch:
+        the family moves by one, under ``held``; else each build moves
+        ``range``."""
+        from filodb_tpu.core.memstore.native_shard import native_available
+
+        srv = server
+        family = "filodb_mesh_batch_builds_total"
+        line = f'{family}{{span="{span}"}}'
+        before = _scrape(srv.http.port)
+        assert before.count(f"# TYPE {family} counter") == 1
+        (help_line,) = [ln for ln in before.splitlines()
+                        if ln.startswith(f"# HELP {family} ")]
+        assert "held" in help_line and "range" in help_line
+
+        def value(text):
+            (ln,) = [ln for ln in text.splitlines() if ln.startswith(line)]
+            return float(ln.rsplit(" ", 1)[1])
+
+        _ingest_and_wait(srv, f"builds_metric_{span}")
+        for start, end in ((START, START + 1500), (START + 30, START + 1410)):
+            with urllib.request.urlopen(
+                    f"http://127.0.0.1:{srv.http.port}/promql/timeseries/"
+                    f"api/v1/query_range?query=sum(rate("
+                    f"builds_metric_{span}%5B1m%5D))"
+                    f"&start={start}&end={end}&step=60") as r:
+                assert r.status == 200
+        moved = value(_scrape(srv.http.port)) - value(before)
+        engaged = "held" if native_available() else "range"
+        if span != engaged:
+            assert moved == 0
+        else:
+            assert moved == 1 if span == "held" else moved >= 2
 
     @pytest.mark.parametrize("family,says", [
         ("filodb_mesh_samples_scanned_total", "samples of the placed batch"),

@@ -252,7 +252,8 @@ def test_overwriting_returned_buffers_changes_no_answer(mesh, stores):
     exec_svc, mesh_svc = services(store(stores, "gauge"), mesh)
     eng = mesh_svc.mesh_engine
     qa = "avg by (host)(avg_over_time(gauge_metric[5m]))"
-    qb = "sum(sum_over_time(gauge_metric[5m]))"         # same shapes
+    # the same series under another selector: a build of the same shapes
+    qb = 'sum(sum_over_time(gauge_metric{host!=""}[5m]))'
     want_a = exec_svc.query_range(qa, *ARGS).result
     assert_same(want_a, mesh_svc.query_range(qa, *ARGS).result)
     assert poison(eng._staging) > 0
@@ -297,10 +298,11 @@ def test_shard_batch_arrays_copies_on_the_cpu_mesh():
 
 def test_eval_cache_hit_gives_back_after_the_placed_arrays_are_ready(
         stores, monkeypatch):
-    """``sum(rate(sel))`` then ``avg(rate(sel))``: the second is a
-    batch-cache miss (the agg is in the key) whose evaluated windows come
-    from the eval cache, so no program reads its new arrays and only
-    ``block_until_ready`` knows the puts are done."""
+    """``sum(rate(sel))`` then ``avg(rate(sel))`` once the batch cache
+    dropped the first's held batch, as an eviction would: the second is a
+    batch-cache miss that builds the same held batch again, whose
+    evaluated windows come from the eval cache, so no program reads its
+    new arrays and only ``block_until_ready`` knows the puts are done."""
     import jax
 
     exec_svc, mesh_svc = services(store(stores, "counter"))
@@ -322,6 +324,7 @@ def test_eval_cache_hit_gives_back_after_the_placed_arrays_are_ready(
 
     monkeypatch.setattr(jax, "block_until_ready", ready)
     monkeypatch.setattr(Lease, "give_back", give)
+    eng._batch_cache.clear()
     hit0 = _M_EVAL["hit"].value
     with tracing.start_trace() as trace:
         got = mesh_svc.query_range(q2, *ARGS).result
@@ -614,8 +617,10 @@ def test_first_build_fresh_second_reused_and_a_hit_moves_neither(stores):
     tags, fresh, reused = traced(ARGS)              # a batch-cache hit
     assert "batch-stack" not in tags and "mesh-pad" not in tags
     assert (fresh, reused) == (0, 0)
-    # another chunk range: a miss that writes into the first build's arrays
-    tags, fresh, reused = traced((START + 600, 60, START + 2460))
+    # another selector of the same series (another chunk range finds the
+    # held batch): a miss that writes into the first build's arrays
+    q = 'avg by (host)(avg_over_time(gauge_metric{host!=""}[5m]))'
+    tags, fresh, reused = traced(ARGS)
     assert tags["batch-stack"]["shape"] == shape
     assert (fresh, reused) == (0, placed + mask)
     assert tags["batch-stack"]["reused_bytes"] == placed
